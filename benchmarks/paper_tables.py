@@ -855,7 +855,6 @@ def axis_planner(fast: bool = True, repeats: int = 3) -> Dict:
     from repro.kernels import ops
     from repro.launch.mesh import make_host_mesh
     from repro.serverless.backends import make_sharded_compiler
-    from repro.sharding.compat import shard_map_compat
     from repro.sharding.gram import data_parallel_gram, feature_parallel_gram
 
     mesh = make_host_mesh()
@@ -926,9 +925,9 @@ def axis_planner(fast: bool = True, repeats: int = 3) -> Dict:
         k = 128 if fast else 256
         a = jnp.asarray(rng.standard_normal((m, k, k)), jnp.float32)
         seq = jax.jit(lambda a: jnp.einsum("mij,mjk->mik", a, a))
-        par = jax.jit(shard_map_compat(
+        par = jax.jit(jax.shard_map(
             lambda a: jnp.einsum("mij,mjk->mik", a, a), mesh=mesh,
-            in_specs=(P("data"),), out_specs=P("data")))
+            in_specs=(P("data"),), out_specs=P("data"), check_vma=False))
         headroom = timeit(lambda: seq(a)) / max(timeit(lambda: par(a)),
                                                 1e-12)
 

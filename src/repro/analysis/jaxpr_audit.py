@@ -354,7 +354,6 @@ def audit_axis_programs() -> List[Finding]:
     structural axis pins plus the PRNG/shape audit on each."""
     from repro.launch.mesh import make_host_mesh
     from repro.launch.roofline import GRAM_FAMILIES
-    from repro.sharding.compat import shard_map_compat
     from repro.sharding.gram import _data_fit_body, _feature_fit_body
     from jax.sharding import PartitionSpec as P
 
@@ -364,21 +363,21 @@ def audit_axis_programs() -> List[Finding]:
     for family in GRAM_FAMILIES:
         params = tuple(sorted(resolve_params(
             family, None, n_obs=_N, dim_x=_P).items()))
-        data_fn = shard_map_compat(
+        data_fn = jax.shard_map(
             _data_fit_body("data", family, params), mesh=mesh,
             in_specs=(P(None, "data", None), P(None), P(None, "data"),
                       P(None, "data"), P(None, "data"), P(None, None)),
-            out_specs=P(None, "data"))
+            out_specs=P(None, "data"), check_vma=False)
         data = jax.make_jaxpr(data_fn)(*avals)
         findings.extend(audit_data_axis(data, f"{family}/data-axis"))
         _taint_jaxpr(data.jaxpr, _data_key_marks(data.jaxpr),
                      f"{family}/data-axis", findings)
 
-        feat_fn = shard_map_compat(
+        feat_fn = jax.shard_map(
             _feature_fit_body("data", family, params), mesh=mesh,
             in_specs=(P(None, None, "data"), P(None), P(None, None),
                       P(None, None), P(None, None), P(None, None)),
-            out_specs=P(None, None))
+            out_specs=P(None, None), check_vma=False)
         feat = jax.make_jaxpr(feat_fn)(*avals)
         findings.extend(audit_feature_axis(feat,
                                            f"{family}/feature-axis"))
@@ -448,11 +447,11 @@ def audit_family(family: str) -> List[Finding]:
 
     # the partitioned (ShardedBackend) form: shard_map over "data"
     from repro.launch.mesh import make_host_mesh
-    from repro.sharding.compat import shard_map_compat
     from repro.sharding.policy import megabatch_specs
     in_specs, out_specs = megabatch_specs("data")
-    sharded_fn = shard_map_compat(run, mesh=make_host_mesh(),
-                                  in_specs=in_specs, out_specs=out_specs)
+    sharded_fn = jax.shard_map(run, mesh=make_host_mesh(),
+                               in_specs=in_specs, out_specs=out_specs,
+                               check_vma=False)
     sharded = jax.make_jaxpr(sharded_fn)(*_probe_avals(fused=False))
     tops = _prim_seq(sharded.jaxpr)
     if "shard_map" not in tops:
@@ -467,9 +466,9 @@ def audit_family(family: str) -> List[Finding]:
     # fused body, task axis sharded, pages replicated — the form
     # ProgramCache.sharded_fused_program jits for partitioned buckets
     fin_specs, fout_specs = megabatch_specs("data", fused=True)
-    sharded_fused_fn = shard_map_compat(
+    sharded_fused_fn = jax.shard_map(
         run_fused, mesh=make_host_mesh(),
-        in_specs=fin_specs, out_specs=fout_specs)
+        in_specs=fin_specs, out_specs=fout_specs, check_vma=False)
     sharded_fused = jax.make_jaxpr(sharded_fused_fn)(
         *_probe_avals(fused=True))
     findings.extend(audit_sharded_fused(single, sharded_fused,

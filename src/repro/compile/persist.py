@@ -10,9 +10,11 @@ module persists the *compiled executables* across processes:
     operand shape is a pure function of the bucket key and the padded
     batch shape), serialized via ``jax.experimental.serialize_executable``
     and written to ``REPRO_PROGRAM_CACHE_DIR``;
-  * JAX's own XLA compilation cache (``jax_compilation_cache_dir``) is
-    pointed at a subdirectory as belt-and-braces for any residual
-    tracing path (partitioned programs, probe traces).
+  * JAX's own XLA compilation cache covers every other tracing path
+    (partitioned programs, probe traces).  It stays where
+    ``JAX_COMPILATION_CACHE_DIR`` puts it; without that variable it
+    lives at one fixed path in the checkout
+    (``configure_compilation_cache``), never under this store.
 
 Deserializing an executable is ~14x cheaper than compiling it on this
 backend, which is what flips the BENCH_fusion cold gate: a disk-warm
@@ -52,6 +54,7 @@ import hashlib
 import os
 import pickle
 import tempfile
+from pathlib import Path
 from typing import Optional, Tuple
 
 import jax
@@ -63,6 +66,26 @@ from repro.analysis.registry import warm_cache
 # program persistence.  Unset (the default) keeps the compile layer
 # purely in-memory — zero behavior change for existing callers.
 ENV_CACHE_DIR = "REPRO_PROGRAM_CACHE_DIR"
+
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+# unset: one fixed directory in the checkout (git-ignored).  The path is
+# part of what makes an entry found again, so it never holds a
+# temporary name, a pid or a time.
+XLA_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def configure_compilation_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` (read by JAX itself at import) wins,
+    as does a directory the caller already configured; otherwise the
+    cache goes to ``XLA_CACHE_DIR``.  Called at compiler set-up
+    (``ProgramCache``), before the first program compiles.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+            and not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", XLA_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
 
 
 def _key_tail() -> Tuple[int, ...]:
@@ -122,21 +145,13 @@ def pin_executable(prog) -> _PinnedExecutable:
 
 def jax_build() -> str:
     """The jax build a serialized executable is valid for."""
-    try:
-        import jaxlib
-        lib = getattr(jaxlib, "__version__", "?")
-    except Exception:                              # pragma: no cover
-        lib = "?"
-    return f"jax-{jax.__version__}+jaxlib-{lib}"
+    import jaxlib
+    return f"jax-{jax.__version__}+jaxlib-{jaxlib.__version__}"
 
 
 def backend_platform() -> str:
     """The backend platform (and device kind) executables target."""
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:                              # pragma: no cover
-        kind = "?"
-    return f"{jax.default_backend()}:{kind}"
+    return f"{jax.default_backend()}:{jax.devices()[0].device_kind}"
 
 
 def program_fingerprint(key, b_pad: int, d_pad: int,
@@ -176,20 +191,6 @@ def program_avals(key, b_pad: int, d_pad: int,
     return tuple(jax.ShapeDtypeStruct(s, d) for s, d in zip(shapes, dtypes))
 
 
-def _configure_jax_cache(cache_dir: str):
-    """Point JAX's own persistent compilation cache at a subdirectory —
-    covers any tracing path that bypasses the AOT store (partitioned
-    programs, audit probes).  Best-effort: unsupported backends fall
-    back to the AOT store alone."""
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(cache_dir, "xla"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:                              # pragma: no cover
-        pass
-
-
 class PersistentProgramCache:
     """Directory of AOT-serialized megabatch executables.
 
@@ -216,7 +217,6 @@ class PersistentProgramCache:
         self.errors = 0                 # unreadable / unserializable entries
         self.skipped_unportable = 0     # custom-call programs not persisted
         os.makedirs(cache_dir, exist_ok=True)
-        _configure_jax_cache(cache_dir)
 
     def _path(self, build: str, platform: str, fingerprint: Tuple) -> str:
         h = hashlib.sha1(

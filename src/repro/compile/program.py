@@ -65,6 +65,7 @@ from repro.compile.buckets import (BucketKey, Entry, MegabatchPlan,
                                    pack_tail_blocks)
 from repro.compile.pages import PagePool
 from repro.compile.persist import (PersistentProgramCache, backend_platform,
+                                   configure_compilation_cache,
                                    default_persist, jax_build, pin_executable,
                                    program_avals, program_fingerprint)
 from repro.learners import as_batched, get_batched_learner
@@ -152,6 +153,7 @@ class ProgramCache:
                  persist: object = "auto",
                  partition_fused: Optional[Callable] = None,
                  partition_axes: Optional[Tuple] = None):
+        configure_compilation_cache()
         self._programs: Dict[Tuple, Callable] = {}
         self.partition = partition
         # ISSUE 8: shard_map transform for the *fused* calling convention

@@ -274,17 +274,15 @@ class DMLSession:
                  pool: Optional[PoolConfig] = None,
                  session_dir: Optional[str] = None):
         # calibrate roofline launch-overhead and shard-overhead pricing
-        # on THIS runtime (memoized no-op dispatch probes; constant
-        # fallbacks on failure) — the analytic SHARD_OVERHEAD_FRAC
-        # mispriced 1-device meshes (ISSUE 9)
-        try:
-            from repro.launch.roofline import (
-                measure_launch_overhead_s, measure_shard_overhead_frac,
-            )
-            measure_launch_overhead_s()
-            measure_shard_overhead_frac()
-        except Exception:
-            pass
+        # on THIS runtime (memoized no-op dispatch probes) — the
+        # analytic SHARD_OVERHEAD_FRAC mispriced 1-device meshes
+        # (ISSUE 9).  A probe that fails raises: pricing must not run on
+        # a device it could not reach.
+        from repro.launch.roofline import (
+            measure_launch_overhead_s, measure_shard_overhead_frac,
+        )
+        measure_launch_overhead_s()
+        measure_shard_overhead_frac()
         self.backend = make_backend(backend, pool)
         self.session_dir = session_dir
         if session_dir is not None:

@@ -11,16 +11,18 @@ the TPU adaptation structural rather than concurrency-based (DESIGN.md §2).
 Tiling: grid (task_blocks, n_blocks); X tile (bn, P), mask/target tiles
 (bt, bn) live in VMEM; the (bt, P, P) f32 accumulator persists in the output
 block across the inner n-block loop.  P is padded to a multiple of 128
-(lane width) by the wrapper; bn is a multiple of 8 (sublanes).
+(lane width) by the wrapper, and N to a multiple of bn, which is a
+multiple of 128 because bn is the lane dim of the (bt, bn) mask tiles.
 """
 from __future__ import annotations
-
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 F32 = jnp.float32
+# f32 contractions on the MXU: without it a dot may run as one bf16 pass
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(x_ref, w_ref, y_ref, g_ref, b_ref):
@@ -36,9 +38,9 @@ def _kernel(x_ref, w_ref, y_ref, g_ref, b_ref):
     y = y_ref[...].astype(F32)                     # (bt, bn)
     wx = w[:, :, None] * x[None, :, :]             # (bt, bn, P)
     # batched MXU contraction over the bn axis
-    g_ref[...] += jnp.einsum("tnp,nq->tpq", wx, x,
+    g_ref[...] += jnp.einsum("tnp,nq->tpq", wx, x, precision=HIGHEST,
                              preferred_element_type=F32)
-    b_ref[...] += jnp.einsum("tn,np->tp", w * y, x,
+    b_ref[...] += jnp.einsum("tn,np->tp", w * y, x, precision=HIGHEST,
                              preferred_element_type=F32)
 
 

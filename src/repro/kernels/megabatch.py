@@ -17,21 +17,19 @@ path:
                             per-row predictions, zeroing padding lanes.
 ``batched_gram_blocked_pallas``
                             the streaming variant (ISSUE 8): the N axis
-                            arrives pre-chunked as (B, C, Nc, P) and the
-                            kernel accumulates across a compile-time
-                            (chunk, n_block) grid, so one task's N never
-                            has to fit a single device page.  The (c, j)
-                            accumulation order equals the unblocked
-                            kernel's j order over the merged N axis, so
-                            results are bitwise-identical when the
-                            chunks tile N exactly; ragged tails carry
-                            w == 0 rows whose FMA terms are exact zeros.
+                            arrives pre-chunked as (B, C, Nc, P) and is
+                            streamed chunk by chunk through the Gram
+                            kernel's n-block loop, so results are
+                            bitwise-identical to the unblocked kernel
+                            when the chunks tile N exactly; ragged tails
+                            carry w == 0 rows whose FMA terms are exact
+                            zeros.
 
 Tiling mirrors crossfit_gram.py: grid (task_blocks, n_blocks); per-task X
 tiles (bb, bn, P) live in VMEM; the (bb, P, P) f32 accumulator persists in
-the output block across the inner n-block loop.  P is padded to a
-multiple of 128 (lanes) by the ops.py wrapper; bn is a multiple of 8
-(sublanes).
+the output block across the inner n-block loop.  The ops.py wrapper pads
+P to a multiple of 128 (lanes) and N to a multiple of bn, which is itself
+a multiple of 128 because bn is the lane dim of the (bb, bn) w/y tiles.
 """
 from __future__ import annotations
 
@@ -40,26 +38,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 F32 = jnp.float32
+# f32 contractions on the MXU: without it a dot may run as one bf16 pass
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _accumulate_gram(x_ref, w_ref, y_ref, g_ref, b_ref):
+    """Add one (bb, bn) tile's masked moments into the output block.
+
+    One 2-D MXU matmul per task lane: Mosaic lowers no batched
+    ``dot_general`` that contracts a non-minor lhs dim, so the lane loop
+    is unrolled at trace time (bb is static).  The weights are moved to
+    a column once per tile (``w.T``) so each lane scales its rows by a
+    lane-broadcast, and the b row comes out of an aligned (bb, bn) x
+    (bn, P) matmul whose row t is task t's moment vector.
+    """
+    w = w_ref[...].astype(F32)                     # (bb, bn)
+    wy = w * y_ref[...].astype(F32)                # (bb, bn)
+    wt = w.T                                       # (bn, bb)
+    for t in range(x_ref.shape[0]):
+        x = x_ref[t].astype(F32)                   # (bn, P)
+        wx = wt[:, t:t + 1] * x
+        g_ref[t] += jax.lax.dot_general(
+            wx, x, (((0,), (0,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=F32)
+        b_ref[t:t + 1, :] += jax.lax.dot_general(
+            wy, x, (((1,), (0,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=F32)[t:t + 1, :]
 
 
 def _gram_kernel(x_ref, w_ref, y_ref, g_ref, b_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         g_ref[...] = jnp.zeros_like(g_ref)
         b_ref[...] = jnp.zeros_like(b_ref)
 
-    x = x_ref[...].astype(F32)                     # (bb, bn, P)
-    w = w_ref[...].astype(F32)                     # (bb, bn)
-    y = y_ref[...].astype(F32)                     # (bb, bn)
-    wx = w[:, :, None] * x                         # (bb, bn, P)
-    # batched MXU contraction over the bn axis, one matmul per task lane
-    g_ref[...] += jax.lax.dot_general(
-        wx, x, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=F32)
-    b_ref[...] += jnp.einsum("bn,bnp->bp", w * y, x,
-                             preferred_element_type=F32)
+    _accumulate_gram(x_ref, w_ref, y_ref, g_ref, b_ref)
 
 
 def batched_gram_pallas(xs, w, y, *, block_b: int = 8, block_n: int = 256,
@@ -93,26 +106,6 @@ def batched_gram_pallas(xs, w, y, *, block_b: int = 8, block_n: int = 256,
     return g, bv
 
 
-def _gram_blocked_kernel(x_ref, w_ref, y_ref, g_ref, b_ref):
-    c = pl.program_id(1)
-    j = pl.program_id(2)
-
-    @pl.when((c == 0) & (j == 0))
-    def _init():
-        g_ref[...] = jnp.zeros_like(g_ref)
-        b_ref[...] = jnp.zeros_like(b_ref)
-
-    x = x_ref[...].astype(F32)[:, 0]               # (bb, 1, bn, P) -> 3D
-    w = w_ref[...].astype(F32)[:, 0]               # (bb, bn)
-    y = y_ref[...].astype(F32)[:, 0]               # (bb, bn)
-    wx = w[:, :, None] * x
-    g_ref[...] += jax.lax.dot_general(
-        wx, x, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=F32)
-    b_ref[...] += jnp.einsum("bn,bnp->bp", w * y, x,
-                             preferred_element_type=F32)
-
-
 def batched_gram_blocked_pallas(xc, w, y, *, block_b: int = 8,
                                 block_n: int = 256,
                                 interpret: bool = False):
@@ -121,36 +114,20 @@ def batched_gram_blocked_pallas(xc, w, y, *, block_b: int = 8,
     xc: (B, C, Nc, P) — the N axis pre-chunked into C streamed pieces of
     Nc rows each; w, y: (B, C, Nc).  Returns (G (B,P,P) f32, b (B,P) f32).
 
-    The accumulator persists in the output block across the (c, j) grid,
-    so partial sums land in the same order as the unblocked kernel's
-    n-block loop over the merged (B, C*Nc, P) tensor — bitwise-equal by
-    construction when Nc is a multiple of block_n.  Nc must be a
-    multiple of block_n and B of block_b (wrapper pads).
+    With Nc a multiple of block_n, merging (C, Nc) into one N axis is a
+    free relayout, and the unblocked kernel's n-block loop over it walks
+    chunk c's blocks in order before chunk c+1's: the (c, j) stream,
+    accumulated in one output block.  So the result is bitwise-equal to
+    ``batched_gram_pallas`` on the merged tensor by construction.  Nc
+    must be a multiple of block_n and B of block_b (wrapper pads).
     """
     b_dim, c_dim, nc, p = xc.shape
     assert nc % block_n == 0 and b_dim % block_b == 0, \
         (b_dim, c_dim, nc, block_b, block_n)
-    grid = (b_dim // block_b, c_dim, nc // block_n)
-    g, bv = pl.pallas_call(
-        _gram_blocked_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, 1, block_n, p),
-                         lambda i, c, j: (i, c, j, 0)),
-            pl.BlockSpec((block_b, 1, block_n), lambda i, c, j: (i, c, j)),
-            pl.BlockSpec((block_b, 1, block_n), lambda i, c, j: (i, c, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b, p, p), lambda i, c, j: (i, 0, 0)),
-            pl.BlockSpec((block_b, p), lambda i, c, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b_dim, p, p), F32),
-            jax.ShapeDtypeStruct((b_dim, p), F32),
-        ],
-        interpret=interpret,
-    )(xc, w, y)
-    return g, bv
+    n = c_dim * nc
+    return batched_gram_pallas(
+        xc.reshape(b_dim, n, p), w.reshape(b_dim, n), y.reshape(b_dim, n),
+        block_b=block_b, block_n=block_n, interpret=interpret)
 
 
 def _predict_kernel(x_ref, beta_ref, v_ref, o_ref):
@@ -160,7 +137,7 @@ def _predict_kernel(x_ref, beta_ref, v_ref, o_ref):
     # per-task GEMV on the MXU: (bb, bn, P) x (bb, P) -> (bb, bn)
     pred = jax.lax.dot_general(
         x, beta, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=F32)
+        precision=HIGHEST, preferred_element_type=F32)
     o_ref[...] = pred * v                          # mask padding lanes
 
 

@@ -1,9 +1,15 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Routing: on TPU backends the Pallas kernel runs natively; on CPU (this
-container) the wrappers route to the jnp oracle so XLA HLO (and hence the
-dry-run roofline) reflects real math, unless ``repro.runtime.force_pallas``
-is set ("interpret") — used by the kernel test-suite.
+Routing: on TPU backends the Pallas kernel runs natively, compiled by
+Mosaic.  On any other backend the wrappers route to the jnp oracle, so
+XLA HLO (and hence the dry-run roofline) reflects real math, unless
+``repro.runtime.force_pallas`` is set: then the kernel runs in Pallas
+interpret mode, which the kernel test-suite uses on CPU.  Interpret mode
+is never chosen on a TPU.
+
+Tiling: every w/y/mask tile is (8, block_n) with block_n in the lane
+dim, so the wrappers pad N to a multiple of a 128-row block (256 or 512
+on tall inputs, for the MXU) and P to 128 lanes.
 """
 from __future__ import annotations
 
@@ -35,6 +41,12 @@ def _interpret() -> bool:
     return _backend() != "tpu"
 
 
+def _block_rows(n: int, tall: int) -> int:
+    """Rows per N-block: ``tall`` once N reaches it, else 128 (the
+    smallest block Mosaic accepts as the lane dim of a (8, bn) tile)."""
+    return tall if n >= tall else 128
+
+
 def _pad_to(x, axis: int, mult: int):
     size = x.shape[axis]
     pad = (-size) % mult
@@ -55,7 +67,7 @@ def crossfit_gram(x, w, y, reg: float = 0.0):
     if not _use_pallas():
         return ref.crossfit_gram_ref(x, w, y, reg)
     n, p = x.shape
-    block_n = 512 if n >= 512 else 8
+    block_n = _block_rows(n, 512)
     xp, p0 = _pad_to(x, 1, 128)          # lane-align features
     xp, _ = _pad_to(xp, 0, block_n)      # N to a block multiple
     padn = xp.shape[0] - n
@@ -84,7 +96,7 @@ def batched_gram(xs, w, y, reg: float = 0.0):
     if not _use_pallas():
         return ref.batched_gram_ref(xs, w, y, reg)
     b_dim, n, p = xs.shape
-    block_n = 256 if n >= 256 else 8
+    block_n = _block_rows(n, 256)
     xp, _ = _pad_to(xs, 2, 128)          # lane-align features
     p0 = p
     xp, _ = _pad_to(xp, 1, block_n)      # N to a block multiple
@@ -152,9 +164,9 @@ def batched_gram_blocked(xc, w, y, reg: float = 0.0):
     b_dim, c_dim, nc, p = xc.shape
     # prefer the 256-row MXU block only when it tiles Nc exactly: an
     # exactly-tiled chunk grid keeps partial-sum order identical to the
-    # unblocked kernel (bitwise); a ragged Nc falls back to 8-row blocks
-    # plus zero-weight padding (tolerance tier)
-    block_n = 256 if nc % 256 == 0 and nc >= 256 else 8
+    # unblocked kernel (bitwise); a ragged Nc falls back to 128-row
+    # blocks plus zero-weight padding (tolerance tier)
+    block_n = 256 if nc % 256 == 0 else 128
     xp, _ = _pad_to(xc, 3, 128)          # lane-align features
     p0 = p
     xp, _ = _pad_to(xp, 2, block_n)      # Nc to a block multiple
@@ -185,7 +197,7 @@ def batched_predict(xs, beta, valid):
     if not _use_pallas():
         return ref.batched_predict_ref(xs, beta, valid)
     b_dim, n, p = xs.shape
-    block_n = 256 if n >= 256 else 8
+    block_n = _block_rows(n, 256)
     xp, _ = _pad_to(xs, 2, 128)
     bp, _ = _pad_to(beta, 1, 128)
     xp, n0 = _pad_to(xp, 1, block_n)

@@ -16,21 +16,21 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from repro.sharding.compat import make_mesh_compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Whatever this host offers (tests / examples)."""
     n = jax.device_count()
     assert n % model_parallel == 0
-    return make_mesh_compat((n // model_parallel, model_parallel),
-                            ("data", "model"))
+    return jax.make_mesh((n // model_parallel, model_parallel),
+                         ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def split_pod_meshes(mesh):

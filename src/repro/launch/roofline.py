@@ -1,12 +1,13 @@
 """Roofline accounting from compiled dry-run artifacts (spec: ROOFLINE
 ANALYSIS).
 
-Hardware target: TPU v5e-like — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware model: one row of ``DEVICE_PEAKS`` per ``device_kind``, with its
+source; a device that has no row is an error.  The dry-run target is a
+TPU v5e chip (``TARGET_KIND``):
 
-  compute_term_s    = HLO_FLOPs_per_device / PEAK_FLOPS
-  memory_term_s     = HLO_bytes_per_device / HBM_BW
-  collective_term_s = collective_bytes_per_device / ICI_BW
+  compute_term_s    = HLO_FLOPs_per_device / peak flops
+  memory_term_s     = HLO_bytes_per_device / HBM bandwidth
+  collective_term_s = collective_bytes_per_device / interconnect bandwidth
 
 ``cost_analysis()`` counts a while-loop (lax.scan) body ONCE (verified
 empirically), so per-cell costs are measured on small probe configs with
@@ -25,9 +26,47 @@ import numpy as np
 
 from repro.configs.base import ArchConfig, ShapeConfig
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
+
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks the pricing divides by."""
+    flops: float           # dense bf16 matmul FLOP/s
+    hbm_bw: float          # HBM bytes/s
+    hbm_bytes: float       # HBM capacity
+    ici_bw: float          # chip-to-chip interconnect bytes/s
+    source: str
+
+
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        flops=197e12, hbm_bw=819e9, hbm_bytes=16e9, ici_bw=1600e9 / 8,
+        source="Google Cloud TPU documentation, 'TPU v5e': 197 TFLOP/s "
+               "bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip"),
+    # XLA's CPU backend has no published peak.  The row keeps the values
+    # the planner has always priced with, so its decisions on a CPU host
+    # are the pinned ones; no CPU number is a device measurement.
+    "cpu": DevicePeaks(
+        flops=197e12, hbm_bw=819e9, hbm_bytes=16e9, ici_bw=50e9,
+        source="no published peak: the planner's historical constants"),
+}
+
+#: the chip the dry-run roofline prices a compiled program for
+TARGET_KIND = "TPU v5 lite"
+
+
+def device_peaks(kind: Optional[str] = None) -> DevicePeaks:
+    """The peak row of ``kind`` (default: this process's first device).
+    A kind with no row raises: pricing never guesses a device."""
+    if kind is None:
+        import jax
+        kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak row for device kind {kind!r}; known kinds: "
+            f"{sorted(DEVICE_PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -254,30 +293,27 @@ def measure_launch_overhead_s(repeats: int = 30) -> float:
     and take the median.  Memoized module-globally — sessions call this
     at init so autoscaler pricing uses the runtime actually underneath
     us instead of the serving-host constant.  Clamped to a sane band
-    (10 us .. 10 ms); any failure falls back to ``LAUNCH_OVERHEAD_S``.
+    (10 us .. 10 ms).  A probe that fails raises.
     """
     global _MEASURED_LAUNCH_OVERHEAD_S
     if _MEASURED_LAUNCH_OVERHEAD_S is not None:
         return _MEASURED_LAUNCH_OVERHEAD_S
-    try:
-        import time
+    import time
 
-        import jax
-        import jax.numpy as jnp
+    import jax
+    import jax.numpy as jnp
 
-        noop = jax.jit(lambda x: x + 1.0)
-        x = jnp.zeros((8,), jnp.float32)
-        noop(x).block_until_ready()            # compile outside the timer
-        samples = []
-        for _ in range(max(int(repeats), 3)):
-            t0 = time.perf_counter()
-            noop(x).block_until_ready()
-            samples.append(time.perf_counter() - t0)
-        samples.sort()
-        measured = samples[len(samples) // 2]
-        _MEASURED_LAUNCH_OVERHEAD_S = min(max(measured, 1e-5), 1e-2)
-    except Exception:
-        _MEASURED_LAUNCH_OVERHEAD_S = LAUNCH_OVERHEAD_S
+    noop = jax.jit(lambda x: x + 1.0)
+    x = jnp.zeros((8,), jnp.float32)
+    noop(x).block_until_ready()                # compile outside the timer
+    samples = []
+    for _ in range(max(int(repeats), 3)):
+        t0 = time.perf_counter()
+        noop(x).block_until_ready()
+        samples.append(time.perf_counter() - t0)
+    samples.sort()
+    measured = samples[len(samples) // 2]
+    _MEASURED_LAUNCH_OVERHEAD_S = min(max(measured, 1e-5), 1e-2)
     return _MEASURED_LAUNCH_OVERHEAD_S
 
 
@@ -296,7 +332,8 @@ def invocation_roofline_s(learner: str, params, tasks_per_invocation: int,
     t = max(int(tasks_per_invocation), 1)
     flops = t * megabatch_task_flops(learner, n_pad, p_pad, params)
     byts = t * megabatch_task_bytes(n_pad, p_pad)
-    return max(flops / PEAK_FLOPS, byts / HBM_BW) \
+    peaks = device_peaks()
+    return max(flops / peaks.flops, byts / peaks.hbm_bw) \
         + amortized_launches * launch_overhead_s()
 
 
@@ -368,46 +405,43 @@ def measure_shard_overhead_frac(repeats: int = 20) -> float:
     shard — the exact ``launch_cost`` model ``axis_candidate_costs``
     charges.  A 1-device mesh still measures the wrapper's own tax
     (attributed to one "extra shard" so data@1 rescue pricing stays
-    honest).  Memoized module-globally; clamped to [0.02, 2.0]; any
-    failure falls back to ``SHARD_OVERHEAD_FRAC``."""
+    honest).  Memoized module-globally; clamped to [0.02, 2.0].  A probe
+    that fails raises."""
     global _MEASURED_SHARD_OVERHEAD_FRAC
     if _MEASURED_SHARD_OVERHEAD_FRAC is not None:
         return _MEASURED_SHARD_OVERHEAD_FRAC
-    try:
-        import time
+    import time
 
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
 
-        from repro.launch.mesh import make_host_mesh
-        from repro.sharding.compat import shard_map_compat
+    from repro.launch.mesh import make_host_mesh
 
-        mesh = make_host_mesh()
-        m = int(mesh.shape["data"])
-        body = lambda x: x + 1.0
-        plain = jax.jit(body)
-        sharded = jax.jit(shard_map_compat(
-            body, mesh=mesh, in_specs=(P("data"),), out_specs=P("data")))
-        x = jnp.zeros((8 * m,), jnp.float32)
+    mesh = make_host_mesh()
+    m = int(mesh.shape["data"])
+    body = lambda x: x + 1.0
+    plain = jax.jit(body)
+    sharded = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
+        check_vma=False))
+    x = jnp.zeros((8 * m,), jnp.float32)
 
-        def median_s(fn):
-            fn(x).block_until_ready()      # compile outside the timer
-            samples = []
-            for _ in range(max(int(repeats), 3)):
-                t0 = time.perf_counter()
-                fn(x).block_until_ready()
-                samples.append(time.perf_counter() - t0)
-            samples.sort()
-            return samples[len(samples) // 2]
+    def median_s(fn):
+        fn(x).block_until_ready()          # compile outside the timer
+        samples = []
+        for _ in range(max(int(repeats), 3)):
+            t0 = time.perf_counter()
+            fn(x).block_until_ready()
+            samples.append(time.perf_counter() - t0)
+        samples.sort()
+        return samples[len(samples) // 2]
 
-        t_plain = max(median_s(plain), 1e-7)
-        t_sharded = median_s(sharded)
-        extra = max(t_sharded - t_plain, 0.0)
-        frac = extra / (t_plain * max(m - 1, 1))
-        _MEASURED_SHARD_OVERHEAD_FRAC = min(max(frac, 0.02), 2.0)
-    except Exception:
-        _MEASURED_SHARD_OVERHEAD_FRAC = SHARD_OVERHEAD_FRAC
+    t_plain = max(median_s(plain), 1e-7)
+    t_sharded = median_s(sharded)
+    extra = max(t_sharded - t_plain, 0.0)
+    frac = extra / (t_plain * max(m - 1, 1))
+    _MEASURED_SHARD_OVERHEAD_FRAC = min(max(frac, 0.02), 2.0)
     return _MEASURED_SHARD_OVERHEAD_FRAC
 
 #: families whose fit is a pure function of (X'X, X'y) — the data-
@@ -462,8 +496,9 @@ def axis_candidate_costs(learner: str, params, n_tasks: int, n_pad: int,
     ``executable`` marks candidates the current launch layer can
     actually run (task always; data/feature only for GRAM_FAMILIES,
     through the standalone in-mesh executors in sharding/gram.py).
-    Pure pricing — no jax, no device access — so planner decisions are
-    deterministic and unit-testable.
+    Pure pricing over the peak row of this process's device kind
+    (``device_peaks``), so planner decisions are deterministic and
+    unit-testable.
     """
     params = dict(params or ())
     b = max(int(n_tasks), 1)
@@ -475,6 +510,7 @@ def axis_candidate_costs(learner: str, params, n_tasks: int, n_pad: int,
     fits_page = n_pad <= DEVICE_PAGE_ROWS
 
     frac = shard_overhead_frac()
+    peaks = device_peaks()
 
     def launch_cost(shards: int) -> float:
         return lo * (1.0 + frac * (shards - 1))
@@ -483,8 +519,8 @@ def axis_candidate_costs(learner: str, params, n_tasks: int, n_pad: int,
     # ---- task axis: ceil(b/m) whole tasks per shard, no collectives
     for shards in sorted({1, m}):
         per_dev = float(int(np.ceil(b / shards)))
-        est = max(per_dev * f1 / PEAK_FLOPS, per_dev * by1 / HBM_BW) \
-            + launch_cost(shards)
+        est = max(per_dev * f1 / peaks.flops,
+                  per_dev * by1 / peaks.hbm_bw) + launch_cost(shards)
         out.append(("task", shards, est, fits_page))
     if m == 1:
         # chunk-streamed data@1: the page-overflow rescue path — the
@@ -498,8 +534,8 @@ def axis_candidate_costs(learner: str, params, n_tasks: int, n_pad: int,
             gram_dev = b * chunked_gram_flops(n_pad, p_pad,
                                               DEVICE_PAGE_ROWS)
             tail = b * _solve_flops(learner, n_pad, p_pad, params)
-            est = max((gram_dev + tail) / PEAK_FLOPS, by1 * b / HBM_BW) \
-                + lo * (1.0 + frac)
+            est = max((gram_dev + tail) / peaks.flops,
+                      by1 * b / peaks.hbm_bw) + lo * (1.0 + frac)
             out.append(("data", 1, est, not fits_page))
         return out
 
@@ -511,9 +547,9 @@ def axis_candidate_costs(learner: str, params, n_tasks: int, n_pad: int,
         psum_rounds = 1.0 if learner != "logistic" \
             else float(params.get("n_iter", 32))
         psum_bytes = b * (p_pad * p_pad + p_pad) * 4.0 * psum_rounds
-        coll = psum_bytes * 2.0 * (m - 1) / m / ICI_BW
-        est = max((gram_dev + tail) / PEAK_FLOPS, by1 * b / m / HBM_BW) \
-            + coll + launch_cost(m)
+        coll = psum_bytes * 2.0 * (m - 1) / m / peaks.ici_bw
+        est = max((gram_dev + tail) / peaks.flops,
+                  by1 * b / m / peaks.hbm_bw) + coll + launch_cost(m)
         out.append(("data", m, est, gram_ok))
     else:
         # no analytic data-parallel decomposition for this family
@@ -524,8 +560,8 @@ def axis_candidate_costs(learner: str, params, n_tasks: int, n_pad: int,
         sweeps = float(params.get("n_iter", 200)) \
             if learner == "lasso" else 1.0
         gather_bytes = b * (n_pad * p_pad / m + sweeps * p_pad) * 4.0
-        coll = gather_bytes * (m - 1) / m / ICI_BW
-        est = max(f1 * b / m / PEAK_FLOPS, by1 * b / m / HBM_BW) \
+        coll = gather_bytes * (m - 1) / m / peaks.ici_bw
+        est = max(f1 * b / m / peaks.flops, by1 * b / m / peaks.hbm_bw) \
             + coll + launch_cost(m)
         out.append(("feature", m, est, fits_page))
     else:
@@ -544,15 +580,15 @@ class RooflineTerms:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_dev / PEAK_FLOPS
+        return self.flops_per_dev / DEVICE_PEAKS[TARGET_KIND].flops
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_dev / HBM_BW
+        return self.bytes_per_dev / DEVICE_PEAKS[TARGET_KIND].hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes_per_dev / ICI_BW
+        return self.coll_bytes_per_dev / DEVICE_PEAKS[TARGET_KIND].ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -573,7 +609,8 @@ class RooflineTerms:
     @property
     def mfu_bound(self) -> float:
         """Model-FLOPs utilization at the roofline bound."""
-        denom = self.step_s * self.n_devices * PEAK_FLOPS
+        denom = self.step_s * self.n_devices \
+            * DEVICE_PEAKS[TARGET_KIND].flops
         return self.model_flops_total / denom if denom else 0.0
 
     def to_dict(self) -> Dict:

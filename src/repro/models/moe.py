@@ -29,8 +29,6 @@ from repro.models.param import PDecl
 from repro.models.layers import act_fn, mlp_decls, mlp_forward
 from repro.sharding.axes import LogicalRules
 
-from repro.sharding.compat import shard_map_compat as _shard_map
-
 F32 = jnp.float32
 
 
@@ -176,8 +174,8 @@ def _moe_a2a(p, cfg: ArchConfig, x, e_pad: int, mesh, ep_axis: str,
         P(ep_axis, None, None),           # wo
     )
     out_specs = (P(dp_axes, ep_axis, None), P())
-    fn = _shard_map(block, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs)
+    fn = jax.shard_map(block, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(x, p["router"], p["wi"], p["wo"])
 
 
@@ -214,8 +212,8 @@ def _moe_dense_ep(p, cfg: ArchConfig, x, e_pad: int, mesh, ep_axis: str,
     in_specs = (P(dp_axes, None, None), P(None, None),
                 P(ep_axis, None, None, None), P(ep_axis, None, None))
     out_specs = (P(dp_axes, None, None), P())
-    fn = _shard_map(block, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs)
+    fn = jax.shard_map(block, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(x, p["router"], p["wi"], p["wo"])
 
 

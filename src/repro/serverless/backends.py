@@ -955,18 +955,17 @@ def make_sharded_compiler(mesh) -> "ProgramCache":
     mesh axes (names + sizes) become the cache's ``partition_axes`` —
     part of every sharded-fused program's cache key.
     """
-    from repro.sharding.compat import shard_map_compat
     from repro.sharding.policy import megabatch_specs
     in_specs, out_specs = megabatch_specs("data")
     fin_specs, fout_specs = megabatch_specs("data", fused=True)
 
     def partition(fn):
-        return shard_map_compat(fn, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def partition_fused(fn):
-        return shard_map_compat(fn, mesh=mesh, in_specs=fin_specs,
-                                out_specs=fout_specs)
+        return jax.shard_map(fn, mesh=mesh, in_specs=fin_specs,
+                             out_specs=fout_specs, check_vma=False)
 
     axes = tuple((str(a), int(mesh.shape[a])) for a in mesh.axis_names)
     return _compile().ProgramCache(partition=partition,

@@ -50,7 +50,6 @@ import jax.numpy as jnp
 
 from repro.analysis.registry import warm_cache
 from repro.runtime import bounded_put
-from repro.sharding.compat import shard_map_compat
 
 F32 = jnp.float32
 
@@ -200,18 +199,18 @@ def _data_gram_fn(mesh, mesh_axis: str, family: Optional[str] = None,
             b = jax.lax.psum(b, mesh_axis)
             return g, b
 
-        prog = jax.jit(shard_map_compat(
+        prog = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, mesh_axis), P(None, mesh_axis),
                       P(None, mesh_axis)),
-            out_specs=(P(), P())))
+            out_specs=(P(), P()), check_vma=False))
     else:
-        prog = jax.jit(shard_map_compat(
+        prog = jax.jit(jax.shard_map(
             _data_fit_body(mesh_axis, family, params), mesh=mesh,
             in_specs=(P(None, mesh_axis, None), P(None),
                       P(None, mesh_axis), P(None, mesh_axis),
                       P(None, mesh_axis), P(None, None)),
-            out_specs=P(None, mesh_axis)))
+            out_specs=P(None, mesh_axis), check_vma=False))
     bounded_put(_DATA_GRAM_PROGRAMS, ck, prog, _GRAM_PROGRAM_CACHE_MAX)
     return prog
 
@@ -257,18 +256,19 @@ def _feature_gram_fn(mesh, mesh_axis: str, family: Optional[str] = None,
             b_blk = jnp.einsum("bn,bnp->bp", wf * yf, xf)
             return g_blk, b_blk
 
-        prog = jax.jit(shard_map_compat(
+        prog = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, None, mesh_axis), P(None, None),
                       P(None, None)),
-            out_specs=(P(None, None, mesh_axis), P(None, mesh_axis))))
+            out_specs=(P(None, None, mesh_axis), P(None, mesh_axis)),
+            check_vma=False))
     else:
-        prog = jax.jit(shard_map_compat(
+        prog = jax.jit(jax.shard_map(
             _feature_fit_body(mesh_axis, family, params), mesh=mesh,
             in_specs=(P(None, None, mesh_axis), P(None),
                       P(None, None), P(None, None), P(None, None),
                       P(None, None)),
-            out_specs=P(None, None)))
+            out_specs=P(None, None), check_vma=False))
     bounded_put(_FEATURE_GRAM_PROGRAMS, ck, prog,
                 _GRAM_PROGRAM_CACHE_MAX)
     return prog

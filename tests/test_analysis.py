@@ -166,7 +166,6 @@ def test_vmap_sharded_fused_fails_jaxpr_audit():
     a vmap-built body inside the shard must still be rejected."""
     from repro.analysis import jaxpr_audit as ja
     from repro.launch.mesh import make_host_mesh
-    from repro.sharding.compat import shard_map_compat
     from repro.sharding.policy import megabatch_specs
 
     run, run_fused = ja._program_pair("ols")
@@ -178,15 +177,15 @@ def test_vmap_sharded_fused_fails_jaxpr_audit():
         return jax.vmap(lambda *t: run(pages, *t))(
             data_idx, y, w, valid, key_data)
 
-    bad_fn = shard_map_compat(run_vmapped, mesh=mesh,
-                              in_specs=in_specs, out_specs=out_specs)
+    bad_fn = jax.shard_map(run_vmapped, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     bad = jax.make_jaxpr(bad_fn)(*ja._probe_avals(fused=True))
     rules = {f.rule for f in ja.audit_sharded_fused(single, bad,
                                                     "ols/mut")}
     assert "sharded-fused-wraps-scan" in rules
     # and the real shard_map(lax.map) build passes the same check
-    good_fn = shard_map_compat(run_fused, mesh=mesh,
-                               in_specs=in_specs, out_specs=out_specs)
+    good_fn = jax.shard_map(run_fused, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     good = jax.make_jaxpr(good_fn)(*ja._probe_avals(fused=True))
     assert ja.audit_sharded_fused(single, good, "ols/sf") == []
     # a bare (unsharded) fused program must also be rejected: the
@@ -228,7 +227,6 @@ def test_mutated_axis_programs_fail_jaxpr_audit():
     from repro.kernels import ops
     from repro.launch.mesh import make_host_mesh
     from repro.learners.linear import _augment_b
-    from repro.sharding.compat import shard_map_compat
     from repro.sharding.gram import (
         _data_fit_body, _feature_fit_body, gram_solve,
     )
@@ -246,13 +244,13 @@ def test_mutated_axis_programs_fail_jaxpr_audit():
         out_specs=P(None, None))
 
     # the real lowered forms pass their pins
-    good_d = jax.make_jaxpr(shard_map_compat(
+    good_d = jax.make_jaxpr(jax.shard_map(
         _data_fit_body("data", "ridge", params), mesh=mesh,
-        **data_specs))(*avals)
+        **data_specs, check_vma=False))(*avals)
     assert ja.audit_data_axis(good_d, "ridge/data") == []
-    good_f = jax.make_jaxpr(shard_map_compat(
+    good_f = jax.make_jaxpr(jax.shard_map(
         _feature_fit_body("data", "ridge", params), mesh=mesh,
-        **feat_specs))(*avals)
+        **feat_specs, check_vma=False))(*avals)
     assert ja.audit_feature_axis(good_f, "ridge/feature") == []
 
     # mutation: shard-local statistics, no psum reassembly
@@ -261,14 +259,14 @@ def test_mutated_axis_programs_fail_jaxpr_audit():
         g, b = ops.batched_gram(xa, w, y, 1.0)
         return ops.batched_predict(xa, gram_solve(g, b), valid)
 
-    bad_d = jax.make_jaxpr(shard_map_compat(
-        local_fit, mesh=mesh, **data_specs))(*avals)
+    bad_d = jax.make_jaxpr(jax.shard_map(
+        local_fit, mesh=mesh, **data_specs, check_vma=False))(*avals)
     assert {f.rule for f in ja.audit_data_axis(bad_d, "ridge/mut")} \
         == {"data-axis-psums-moments"}
 
     # mutation: column-local Gram, no row all-gather
-    bad_f = jax.make_jaxpr(shard_map_compat(
-        local_fit, mesh=mesh, **feat_specs))(*avals)
+    bad_f = jax.make_jaxpr(jax.shard_map(
+        local_fit, mesh=mesh, **feat_specs, check_vma=False))(*avals)
     assert {f.rule for f in ja.audit_feature_axis(bad_f, "ridge/mut")} \
         == {"feature-axis-gathers-rows"}
 

@@ -439,6 +439,22 @@ def test_sharded_fused_launch_bitwise_parity(name, params):
 # ---------------------------------------------------------------------------
 # per-bucket parallelization-axis planner (ISSUE 8)
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kind,flops", [("TPU v5 lite", 197e12),
+                                        ("cpu", 197e12), ("TPU v4", None)])
+def test_device_peaks_table(kind, flops):
+    """Pricing reads one peak row per device kind; a kind with no row
+    is an error, never a default."""
+    from repro.launch import roofline
+    if flops is None:
+        with pytest.raises(ValueError, match="no peak row"):
+            roofline.device_peaks(kind)
+    else:
+        assert roofline.device_peaks(kind).flops == flops
+    # this process prices with its own device's row
+    assert roofline.device_peaks() is roofline.DEVICE_PEAKS[
+        jax.devices()[0].device_kind]
+
+
 def test_axis_planner_pinned_decisions(monkeypatch):
     """The roofline planner's choices on the canonical shapes, pinned so
     a pricing-model edit that flips a layout is a visible diff:

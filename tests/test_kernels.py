@@ -211,6 +211,88 @@ def test_blocked_gram_masked_padding_rows_inert():
     np.testing.assert_array_equal(np.asarray(b1), np.asarray(b2))
 
 
+# ---------------------------------------------------------------------------
+# ops wrappers on their Pallas branch (interpret mode): the N tiling the
+# wrappers pick (128-row blocks below 256 rows, ragged chunks padded)
+# ---------------------------------------------------------------------------
+def _interpret_wrapper(fn, **static):
+    """An ops wrapper traced with Pallas forced on.  JAX caches traces
+    per Python function, and the wrapper may already hold a trace of
+    its oracle branch for the same shapes: a new function object gets a
+    trace of its own."""
+    from repro import runtime
+    inner = jax.jit(lambda *a: fn.__wrapped__(*a, **static))
+
+    def call(*args):
+        with runtime.flags(force_pallas="interpret"):
+            return inner(*args)
+    return call
+
+
+def _assert_close(got, want, tol=2e-4):
+    scale = max(float(jnp.max(jnp.abs(want))), 1.0)
+    assert float(jnp.max(jnp.abs(got - want))) / scale < tol
+
+
+@pytest.mark.parametrize("n", [104, 200])
+def test_ops_pallas_small_n_matches_ref(n):
+    from repro.kernels import ops
+    b, p = 12, 17
+    xs, w, y = _tall_case(b, n, p, seed=n)
+    g, bv = _interpret_wrapper(ops.batched_gram, reg=0.5)(xs, w, y)
+    g0, b0 = ref.batched_gram_ref(xs, w, y, reg=0.5)
+    _assert_close(g, g0)
+    _assert_close(bv, b0)
+    beta = jax.random.normal(jax.random.key(n), (b, p), jnp.float32)
+    o = _interpret_wrapper(ops.batched_predict)(xs, beta, w)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(
+        ref.batched_predict_ref(xs, beta, w)), rtol=1e-4, atol=1e-4)
+    gc, bc = _interpret_wrapper(ops.crossfit_gram, reg=0.5)(xs[0], w, y)
+    gc0, bc0 = ref.crossfit_gram_ref(xs[0], w, y, reg=0.5)
+    _assert_close(gc, gc0)
+    _assert_close(bc, bc0)
+
+
+@pytest.mark.parametrize("n,chunk", [(1000, 300), (700, 256)])
+def test_ops_pallas_blocked_ragged_chunks_match_ref(n, chunk):
+    """Ragged Nc (not a multiple of 256) runs 128-row blocks over each
+    chunk padded with w == 0 rows: the oracle's statistics within the
+    tolerance tier."""
+    from repro.kernels import ops
+    xs, w, y = _tall_case(4, n, 12, seed=n)
+    xc, wc, yc = ops.chunk_tall_n(xs, w, y, chunk)
+    g, bv = _interpret_wrapper(ops.batched_gram_blocked)(xc, wc, yc)
+    g0, b0 = ref.batched_gram_ref(xs, w, y)
+    _assert_close(g, g0, 1e-3)
+    _assert_close(bv, b0, 1e-3)
+
+
+@pytest.mark.parametrize("n,chunk", [(1024, 256), (768, 768)])
+def test_ops_pallas_blocked_bitwise_on_exact_tiling(n, chunk):
+    """On the wrappers' Pallas branch, chunks that tile N in 256-row
+    blocks reproduce the unblocked kernel bitwise."""
+    from repro.kernels import ops
+    xs, w, y = _tall_case(4, n, 12, seed=n + 1)
+    g0, b0 = _interpret_wrapper(ops.batched_gram, reg=0.5)(xs, w, y)
+    xc, wc, yc = ops.chunk_tall_n(xs, w, y, chunk)
+    g, bv = _interpret_wrapper(ops.batched_gram_blocked, reg=0.5)(
+        xc, wc, yc)
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(g0))
+    np.testing.assert_array_equal(np.asarray(bv), np.asarray(b0))
+
+
+def test_ops_never_interpret_on_tpu(monkeypatch):
+    """On a TPU backend the wrappers take the Pallas branch compiled by
+    Mosaic, whatever ``force_pallas`` says: interpret mode is a CPU
+    test device only."""
+    from repro import runtime
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_backend", lambda: "tpu")
+    for forced in ("", "interpret"):
+        with runtime.flags(force_pallas=forced):
+            assert ops._use_pallas() and not ops._interpret()
+
+
 def test_data_and_feature_parallel_gram_executors():
     """The in-mesh executors for the planner's non-task axes agree with
     the single-device statistics to the documented tolerance tier:

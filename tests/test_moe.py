@@ -48,8 +48,8 @@ from repro.models.moe import moe_forward, moe_decls, _moe_local, padded_experts
 from repro.models.param import init_tree
 from repro.sharding.axes import MEGATRON_FSDP
 
-from repro.sharding.compat import make_mesh_compat
-mesh = make_mesh_compat((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 runtime.mesh_axes = ("data", "model")
 cfg = get_arch("deepseek-v2-lite-16b", reduced=True)
 decls = moe_decls(cfg, ep_size=2)

@@ -212,3 +212,40 @@ def test_aot_calls_pin_host_operands(tmp_path):
     pinned(np.zeros(1, np.float32))              # landed (numpy: always
     ((_, args2),) = pinned._inflight             # ready) -> released
     assert args2[0] is not y
+
+
+def test_compilation_cache_placement(tmp_path, monkeypatch):
+    """JAX's persistent compilation cache is placed from outside: with
+    JAX_COMPILATION_CACHE_DIR set, neither a session nor an AOT program
+    store moves it; without it, it resolves to one fixed path in the
+    checkout, the same on every call."""
+    import tempfile
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.compile import persist
+    from repro.compile.persist import PersistentProgramCache
+    from repro.core import DMLSession
+
+    was = jax.config.jax_compilation_cache_dir
+    env_dir = str(tmp_path / "from_env")
+    try:
+        # what JAX reads from the variable when it is imported
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        jax.config.update("jax_compilation_cache_dir", env_dir)
+        DMLSession(backend="wave")
+        PersistentProgramCache(str(tmp_path / "aot"))
+        assert jax.config.jax_compilation_cache_dir == env_dir
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        jax.config.update("jax_compilation_cache_dir", None)
+        first = persist.configure_compilation_cache()
+        second = persist.configure_compilation_cache()
+        assert first == second == persist.XLA_CACHE_DIR
+        assert Path(first).parent == Path(__file__).resolve().parent.parent
+        assert not first.startswith(tempfile.gettempdir())
+        assert str(os.getpid()) not in first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        cc.reset_cache()

@@ -53,8 +53,8 @@ from repro.configs.base import ShapeConfig
 from repro.launch.dryrun import lower_cell
 from repro.launch.roofline import parse_collective_bytes
 
-from repro.sharding.compat import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 runtime.mesh_axes = ("data", "model")
 cfg = get_arch("{arch}", reduced=True)
 shape = ShapeConfig("t", seq_len=64, global_batch=8, kind="{kind}")

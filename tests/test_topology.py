@@ -291,8 +291,8 @@ def test_topology_from_pod_mesh():
     pod, each pinned to its own device set."""
     if jax.device_count() < 8:
         pytest.skip("needs the forced 8-device host platform")
-    from repro.sharding.compat import make_mesh_compat
-    mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     topo = Topology.from_mesh(mesh)
     assert len(topo) == 2
     assert topo.hosts[0].n_devices == 4
