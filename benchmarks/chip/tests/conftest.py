@@ -1,0 +1,8 @@
+import sys
+from pathlib import Path
+
+BENCH_DIR = Path(__file__).resolve().parents[1]
+ROOT = BENCH_DIR.parents[1]
+for p in (str(BENCH_DIR), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
