@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.analysis.registry import warm_cache
 from repro.core.crossfit import aligned_bucket, pow2_bucket
 from repro.learners import FEATURE_PAD_SAFE
@@ -143,11 +144,13 @@ class MegabatchPlan:
         ``exclude`` is the dispatched-but-unharvested entry set of the
         caller's in-flight queue: those invocations are on device already
         and must not be re-dispatched while their launch is pending."""
-        entries: List[Entry] = []
-        for ri, req in enumerate(self.requests):
-            entries.extend(e for inv in req.ledger.pending()
-                           if (e := (ri, int(inv))) not in (exclude or ()))
-        return self.group_entries(entries)
+        with obs.span("planner.fill") as sp:
+            entries: List[Entry] = []
+            for ri, req in enumerate(self.requests):
+                entries.extend(e for inv in req.ledger.pending()
+                               if (e := (ri, int(inv))) not in (exclude or ()))
+            sp.set(entries=len(entries))
+            return self.group_entries(entries)
 
 def pack_tail_blocks(lane_counts: Sequence[int], b_block: int,
                      quantum: int = 8, b_align: int = 1,

@@ -52,6 +52,7 @@ programs never fuse (the specs map one block's operands).
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -59,6 +60,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro import obs
 from repro.analysis.registry import warm_cache
 from repro.core.crossfit import PaddingStats, aligned_bucket, pow2_bucket
 from repro.compile.buckets import (BucketKey, Entry, MegabatchPlan,
@@ -214,28 +216,30 @@ class ProgramCache:
         if prog is not None:
             self.stats.hits += 1
             return prog
-        fp = program_fingerprint(key, b_pad, d_pad) \
-            if self._disk(key) is not None else None
-        if fp is not None:
-            prog = self._disk_lookup(fp)
-            if prog is not None:
-                self._programs[pkey] = prog
-                return prog
-        self.stats.misses += 1
-        batched_fn = fn_thunk()
+        with obs.span("program.build", tier="disk") as sp:
+            fp = program_fingerprint(key, b_pad, d_pad) \
+                if self._disk(key) is not None else None
+            if fp is not None:
+                prog = self._disk_lookup(fp)
+                if prog is not None:
+                    self._programs[pkey] = prog
+                    return prog
+            sp.set(tier="compile")
+            self.stats.misses += 1
+            batched_fn = fn_thunk()
 
-        def run(pages, data_idx, y, w, valid, key_data):
-            xb = pages[data_idx]                       # (B, N_pad, P_pad)
-            keys = jax.random.wrap_key_data(key_data)  # (B,) typed keys
-            return batched_fn(xb, y, w, valid, keys)
+            def run(pages, data_idx, y, w, valid, key_data):
+                xb = pages[data_idx]                       # (B, N_pad, P_pad)
+                keys = jax.random.wrap_key_data(key_data)  # (B,) typed keys
+                return batched_fn(xb, y, w, valid, keys)
 
-        if self.partition is not None:
-            prog = jax.jit(self.partition(run))
-        elif fp is not None:
-            prog = self._compile_persistable(run, fp, key, b_pad, d_pad)
-        else:
-            prog = jax.jit(run, donate_argnums=(2,))
-        self._programs[pkey] = prog
+            if self.partition is not None:
+                prog = jax.jit(self.partition(run))
+            elif fp is not None:
+                prog = self._compile_persistable(run, fp, key, b_pad, d_pad)
+            else:
+                prog = jax.jit(run, donate_argnums=(2,))
+            self._programs[pkey] = prog
         return prog
 
     @warm_cache(name="fused_program_cache",
@@ -254,31 +258,33 @@ class ProgramCache:
         if prog is not None:
             self.stats.hits += 1
             return prog
-        fp = program_fingerprint(key, b_pad, d_pad, g) \
-            if self._disk(key) is not None else None
-        if fp is not None:
-            prog = self._disk_lookup(fp)
-            if prog is not None:
-                self._programs[pkey] = prog
-                return prog
-        self.stats.misses += 1
-        batched_fn = fn_thunk()
+        with obs.span("program.build", tier="disk") as sp:
+            fp = program_fingerprint(key, b_pad, d_pad, g) \
+                if self._disk(key) is not None else None
+            if fp is not None:
+                prog = self._disk_lookup(fp)
+                if prog is not None:
+                    self._programs[pkey] = prog
+                    return prog
+            sp.set(tier="compile")
+            self.stats.misses += 1
+            batched_fn = fn_thunk()
 
-        def run_one(pages, data_idx, y, w, valid, key_data):
-            xb = pages[data_idx]
-            keys = jax.random.wrap_key_data(key_data)
-            return batched_fn(xb, y, w, valid, keys)
+            def run_one(pages, data_idx, y, w, valid, key_data):
+                xb = pages[data_idx]
+                keys = jax.random.wrap_key_data(key_data)
+                return batched_fn(xb, y, w, valid, keys)
 
-        def run_fused(pages, data_idx, y, w, valid, key_data):
-            return jax.lax.map(lambda t: run_one(pages, *t),
-                               (data_idx, y, w, valid, key_data))
+            def run_fused(pages, data_idx, y, w, valid, key_data):
+                return jax.lax.map(lambda t: run_one(pages, *t),
+                                   (data_idx, y, w, valid, key_data))
 
-        if fp is not None:
-            prog = self._compile_persistable(run_fused, fp, key, b_pad,
-                                             d_pad, g)
-        else:
-            prog = jax.jit(run_fused, donate_argnums=(2,))
-        self._programs[pkey] = prog
+            if fp is not None:
+                prog = self._compile_persistable(run_fused, fp, key, b_pad,
+                                                 d_pad, g)
+            else:
+                prog = jax.jit(run_fused, donate_argnums=(2,))
+            self._programs[pkey] = prog
         return prog
 
     # The sharded-fused program closes over the mesh the partition_fused
@@ -314,20 +320,21 @@ class ProgramCache:
         if prog is not None:
             self.stats.hits += 1
             return prog
-        self.stats.misses += 1
-        batched_fn = fn_thunk()
+        with obs.span("program.build", tier="compile"):
+            self.stats.misses += 1
+            batched_fn = fn_thunk()
 
-        def run_one(pages, data_idx, y, w, valid, key_data):
-            xb = pages[data_idx]
-            keys = jax.random.wrap_key_data(key_data)
-            return batched_fn(xb, y, w, valid, keys)
+            def run_one(pages, data_idx, y, w, valid, key_data):
+                xb = pages[data_idx]
+                keys = jax.random.wrap_key_data(key_data)
+                return batched_fn(xb, y, w, valid, keys)
 
-        def run_fused(pages, data_idx, y, w, valid, key_data):
-            return jax.lax.map(lambda t: run_one(pages, *t),
-                               (data_idx, y, w, valid, key_data))
+            def run_fused(pages, data_idx, y, w, valid, key_data):
+                return jax.lax.map(lambda t: run_one(pages, *t),
+                                   (data_idx, y, w, valid, key_data))
 
-        prog = jax.jit(self.partition_fused(run_fused))
-        self._programs[pkey] = prog
+            prog = jax.jit(self.partition_fused(run_fused))
+            self._programs[pkey] = prog
         return prog
 
 
@@ -526,17 +533,23 @@ class BucketDispatch:
         from repro.serverless.sanitize import check_harvest_once
         check_harvest_once(self)
         results: Dict[Entry, np.ndarray] = {}
-        for launch in self.launches:
-            out = np.asarray(jax.block_until_ready(launch.out), np.float32)
-            outs = out if launch.fused else out[None]
-            for g, lb in enumerate(launch.blocks):
-                for blk, ofs in zip(lb.parts, lb.offsets):
-                    for lane, (_, inv, row) in enumerate(blk.members):
-                        buf = results.get((blk.ri, inv))
-                        if buf is None:
-                            buf = results[(blk.ri, inv)] = \
-                                np.empty((blk.tpi, blk.n), np.float32)
-                        buf[row] = outs[g, ofs + lane, :blk.n]
+        with obs.span("program.harvest", launches=len(self.launches)) as sp:
+            d2h = 0
+            for launch in self.launches:
+                with obs.span("program.wait"):
+                    jax.block_until_ready(launch.out)
+                out = np.asarray(launch.out, np.float32)
+                d2h += out.nbytes
+                outs = out if launch.fused else out[None]
+                for g, lb in enumerate(launch.blocks):
+                    for blk, ofs in zip(lb.parts, lb.offsets):
+                        for lane, (_, inv, row) in enumerate(blk.members):
+                            buf = results.get((blk.ri, inv))
+                            if buf is None:
+                                buf = results[(blk.ri, inv)] = \
+                                    np.empty((blk.tpi, blk.n), np.float32)
+                            buf[row] = outs[g, ofs + lane, :blk.n]
+            sp.set(d2h_bytes=d2h)
         return results
 
     def discard(self) -> None:
@@ -809,6 +822,23 @@ def _launch_didx(plan: MegabatchPlan, pages: Optional[PagePool],
     return didx
 
 
+def _nbytes(*arrays) -> int:
+    """Host bytes a launch stages as operands (pages excluded: they
+    come from the device-resident pool, or are counted by PageStats)."""
+    return sum(int(a.nbytes) for a in arrays)
+
+
+def _launch_rids(plan: MegabatchPlan, lbs: List[_LaunchBlock]) -> List:
+    """Request ids (the session's tags, else plan indices) riding one
+    launch, in first-appearance order."""
+    out: Dict[object, None] = {}
+    for lb in lbs:
+        for blk in lb.parts:
+            tag = plan.requests[blk.ri].tag
+            out.setdefault(blk.ri if tag is None else tag)
+    return list(out)
+
+
 def _axis_to_execute(key: BucketKey, axis_decision, mesh
                      ) -> Optional[Tuple[str, int]]:
     """(axis, shards) the drain can actually lower for this bucket, or
@@ -875,18 +905,22 @@ def _dispatch_axis_bucket(plan: MegabatchPlan, cache: ProgramCache,
     from jax.sharding import NamedSharding, PartitionSpec
     repl = NamedSharding(mesh, PartitionSpec())
     for lb in lblocks:
-        pages_arr, lane_of = _launch_pages(plan, pages, key, [lb],
-                                           n_pad, p_pad)
-        y, w, valid, kd = _launch_tensors(plan, lb, n_pad)
-        didx = _launch_didx(plan, pages, lb, lane_of, n_pad, p_pad)
-        pages_arr, didx, y, w, valid, kd = jax.device_put(
-            (pages_arr, didx, y, w, valid, kd), repl)
+        with obs.span("program.stage", b_pad=lb.b_pad, g=1) as st:
+            pages_arr, lane_of = _launch_pages(plan, pages, key, [lb],
+                                               n_pad, p_pad)
+            y, w, valid, kd = _launch_tensors(plan, lb, n_pad)
+            didx = _launch_didx(plan, pages, lb, lane_of, n_pad, p_pad)
+            st.set(staged_bytes=_nbytes(y, w, valid, kd, didx))
+            pages_arr, didx, y, w, valid, kd = jax.device_put(
+                (pages_arr, didx, y, w, valid, kd), repl)
         if axis_fit_program_cached(mesh, axis, family, params):
             cache.stats.hits += 1
         else:
             cache.stats.misses += 1
         prog = axis_fit_program(mesh, axis, family, params)
-        out = prog(pages_arr, didx, y, w, valid, kd)
+        with obs.span("program.launch", b_pad=lb.b_pad, g=1,
+                      rids=_launch_rids(plan, [lb])):
+            out = prog(pages_arr, didx, y, w, valid, kd)
         launches.append(Launch(out=out, blocks=[lb], fused=False))
         cache.stats.launches += 1
         cache.stats.blocks += len(lb.parts)
@@ -906,6 +940,18 @@ def _dispatch_axis_bucket(plan: MegabatchPlan, cache: ProgramCache,
                           entries=list(entries), n_tasks=total_tasks)
 
 
+def _dispatch_span(dispatch):
+    """Run a bucket dispatch inside its ``program.dispatch`` span."""
+    @functools.wraps(dispatch)
+    def traced(plan, cache, key: BucketKey, entries: Sequence[Entry],
+               **opts) -> BucketDispatch:
+        with obs.span("program.dispatch", n_pad=key.n_pad, p_pad=key.p_pad,
+                      entries=len(entries)):
+            return dispatch(plan, cache, key, entries, **opts)
+    return traced
+
+
+@_dispatch_span
 def dispatch_bucket(plan: MegabatchPlan, cache: ProgramCache,
                     key: BucketKey, entries: Sequence[Entry], *,
                     b_align: int = 1, pages: Optional[PagePool] = None,
@@ -973,15 +1019,20 @@ def dispatch_bucket(plan: MegabatchPlan, cache: ProgramCache,
         seg = requests[lead.ri].segments[lead.si]
         if not fuse or len(group) == 1:
             for lb in group:
-                pages_arr, lane_of = _launch_pages(plan, pages, key, [lb],
-                                                   n_pad, p_pad)
-                y, w, valid, kd = _launch_tensors(plan, lb, n_pad)
-                didx = _launch_didx(plan, pages, lb, lane_of, n_pad, p_pad)
+                with obs.span("program.stage", b_pad=b_pad, g=1) as st:
+                    pages_arr, lane_of = _launch_pages(
+                        plan, pages, key, [lb], n_pad, p_pad)
+                    y, w, valid, kd = _launch_tensors(plan, lb, n_pad)
+                    didx = _launch_didx(plan, pages, lb, lane_of,
+                                        n_pad, p_pad)
+                    st.set(staged_bytes=_nbytes(y, w, valid, kd, didx))
                 blk_seg = requests[lb.parts[0].ri].segments[lb.parts[0].si]
                 prog = cache.program(
                     key, b_pad, int(pages_arr.shape[0]),
                     lambda: segment_batched_fn(blk_seg))
-                out = prog(pages_arr, didx, y, w, valid, kd)
+                with obs.span("program.launch", b_pad=b_pad, g=1,
+                              rids=_launch_rids(plan, [lb])):
+                    out = prog(pages_arr, didx, y, w, valid, kd)
                 launches.append(Launch(out=out, blocks=[lb], fused=False))
                 cache.stats.launches += 1
                 cache.stats.blocks += len(lb.parts)
@@ -998,28 +1049,31 @@ def dispatch_bucket(plan: MegabatchPlan, cache: ProgramCache,
             continue
 
         # ---- fused launch: G same-shape launch blocks, one union stack
-        pages_arr, lane_of = _launch_pages(plan, pages, key, group,
-                                           n_pad, p_pad)
         g = len(group)
-        ys = np.empty((g, b_pad, n_pad), np.float32)
-        ws = np.empty((g, b_pad, n_pad), np.float32)
-        valids = np.empty((g, b_pad, n_pad), np.float32)
-        didx = np.empty((g, b_pad), np.int32)
-        kds = None
-        for gi, lb in enumerate(group):
-            y, w, valid, kd = _launch_tensors(plan, lb, n_pad)
-            if kds is None:
-                kds = np.empty((g,) + kd.shape, kd.dtype)
-            ys[gi], ws[gi], valids[gi], kds[gi] = y, w, valid, kd
-            didx[gi] = _launch_didx(plan, pages, lb, lane_of, n_pad, p_pad)
-            cache.stats.blocks += len(lb.parts)
-            if len(lb.parts) > 1:
-                cache.stats.coalesced_blocks += len(lb.parts)
-            for blk in lb.parts:
-                pad_acc.book_part(
-                    key, blk,
-                    requests[blk.ri].segments[blk.si].learner is None)
-            pad_acc.book_launch(key, lb)
+        with obs.span("program.stage", b_pad=b_pad, g=g) as st:
+            pages_arr, lane_of = _launch_pages(plan, pages, key, group,
+                                               n_pad, p_pad)
+            ys = np.empty((g, b_pad, n_pad), np.float32)
+            ws = np.empty((g, b_pad, n_pad), np.float32)
+            valids = np.empty((g, b_pad, n_pad), np.float32)
+            didx = np.empty((g, b_pad), np.int32)
+            kds = None
+            for gi, lb in enumerate(group):
+                y, w, valid, kd = _launch_tensors(plan, lb, n_pad)
+                if kds is None:
+                    kds = np.empty((g,) + kd.shape, kd.dtype)
+                ys[gi], ws[gi], valids[gi], kds[gi] = y, w, valid, kd
+                didx[gi] = _launch_didx(plan, pages, lb, lane_of,
+                                        n_pad, p_pad)
+                cache.stats.blocks += len(lb.parts)
+                if len(lb.parts) > 1:
+                    cache.stats.coalesced_blocks += len(lb.parts)
+                for blk in lb.parts:
+                    pad_acc.book_part(
+                        key, blk,
+                        requests[blk.ri].segments[blk.si].learner is None)
+                pad_acc.book_launch(key, lb)
+            st.set(staged_bytes=_nbytes(ys, ws, valids, kds, didx))
         if cache.partition_fused is not None:
             prog = cache.sharded_fused_program(
                 key, b_pad, int(pages_arr.shape[0]), g,
@@ -1028,7 +1082,9 @@ def dispatch_bucket(plan: MegabatchPlan, cache: ProgramCache,
             prog = cache.fused_program(
                 key, b_pad, int(pages_arr.shape[0]), g,
                 lambda: segment_batched_fn(seg))
-        out = prog(pages_arr, didx, ys, ws, valids, kds)
+        with obs.span("program.launch", b_pad=b_pad, g=g,
+                      rids=_launch_rids(plan, group)):
+            out = prog(pages_arr, didx, ys, ws, valids, kds)
         launches.append(Launch(out=out, blocks=list(group), fused=True))
         cache.stats.launches += 1
         cache.stats.fused_launches += 1

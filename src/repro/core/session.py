@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import msgpack
 import numpy as np
 
+from repro import obs
 from repro.core.aggregation import aggregate_thetas, confint
 from repro.core.bootstrap import boot_confint, multiplier_bootstrap
 from repro.core.crossfit import (
@@ -305,13 +306,14 @@ class DMLSession:
         ``on_complete(result)`` fires the moment the request's ledger
         completes — possibly waves before the whole drain finishes.
         """
-        data = DMLData.from_dict(data)
-        rid = self._next_id
-        self._next_id += 1
-        self._queue.append(_Pending(rid, plan, data, ledger,
-                                    on_complete=on_complete))
-        if self.session_dir is not None:
-            self._persist_spec(rid, plan, data)
+        with obs.span("session.submit", rid=self._next_id):
+            data = DMLData.from_dict(data)
+            rid = self._next_id
+            self._next_id += 1
+            self._queue.append(_Pending(rid, plan, data, ledger,
+                                        on_complete=on_complete))
+            if self.session_dir is not None:
+                self._persist_spec(rid, plan, data)
         return rid
 
     # ---- durability ---------------------------------------------------
@@ -374,40 +376,48 @@ class DMLSession:
     def _admit_queued(self):
         if not self._queue and self._state is None:
             return                          # idle: keep last drain's info
-        state = self._drain_state()
-        for p in self._queue:
-            if p.admitted:
-                continue
-            req = compile_request(p.plan, p.data, ledger=p.ledger,
-                                  tag=p.request_id)
-            p.ledger = req.ledger           # keep completed rows on failure
-            p.req = req
-            if self.session_dir is not None and req.ledger.path is None:
-                # bind the durable checkpoint file: backends call
-                # ledger.checkpoint() after every booking wave
-                req.ledger.path = self._ledger_path(p.request_id)
-                req.ledger.checkpoint()
-            self.backend.admit(state, req)
-            p.admitted = True
-        self.last_run_info = state.info
+        with obs.span("session.admit", queued=len(self._queue)) as sp:
+            state = self._drain_state()
+            admitted = 0
+            for p in self._queue:
+                if p.admitted:
+                    continue
+                with obs.span("session.compile_request", rid=p.request_id):
+                    req = compile_request(p.plan, p.data, ledger=p.ledger,
+                                          tag=p.request_id)
+                p.ledger = req.ledger       # keep completed rows on failure
+                p.req = req
+                if self.session_dir is not None and req.ledger.path is None:
+                    # bind the durable checkpoint file: backends call
+                    # ledger.checkpoint() after every booking wave
+                    req.ledger.path = self._ledger_path(p.request_id)
+                    req.ledger.checkpoint()
+                self.backend.admit(state, req)
+                p.admitted = True
+                admitted += 1
+            sp.set(admitted=admitted)
+            self.last_run_info = state.info
 
     # ---- the event loop -----------------------------------------------
     def _harvest(self) -> List[int]:
         """Assemble results for every admitted request whose ledger just
         completed; fires callbacks; removes them from the queue."""
         finished: List[int] = []
-        for p in list(self._queue):
-            if not (p.admitted and p.req.ledger.complete):
-                continue
-            res = assemble_result(p.plan, p.data, p.req,
-                                  request_id=p.request_id)
-            self._results[p.request_id] = res
-            self._requests[p.request_id] = p.req
-            self.completion_order.append(p.request_id)
-            self._queue.remove(p)
-            finished.append(p.request_id)
-            if p.on_complete is not None:
-                p.on_complete(res)
+        with obs.span("session.harvest") as sp:
+            for p in list(self._queue):
+                if not (p.admitted and p.req.ledger.complete):
+                    continue
+                with obs.span("session.assemble", rid=p.request_id):
+                    res = assemble_result(p.plan, p.data, p.req,
+                                          request_id=p.request_id)
+                self._results[p.request_id] = res
+                self._requests[p.request_id] = p.req
+                self.completion_order.append(p.request_id)
+                self._queue.remove(p)
+                finished.append(p.request_id)
+                if p.on_complete is not None:
+                    p.on_complete(res)
+            sp.set(completed=len(finished))
         return finished
 
     def _retire_idle_state(self):
@@ -425,12 +435,13 @@ class DMLSession:
         any landed in-flight buckets, then dispatch the next wave
         without blocking), and return the ids of requests that completed
         in that step."""
-        if not self._queue and self._state is None:
-            return []
-        self._admit_queued()
-        self.backend.step(self._drain_state())
-        done = self._harvest()
-        self._retire_idle_state()
+        with obs.span("session.poll"):
+            if not self._queue and self._state is None:
+                return []
+            self._admit_queued()
+            self.backend.step(self._drain_state())
+            done = self._harvest()
+            self._retire_idle_state()
         return done
 
     def wait(self, request_id: int) -> DMLResult:
@@ -482,7 +493,8 @@ class DMLSession:
         return None if info is None else info.topology
 
     def result(self, request_id: int) -> DMLResult:
-        return self._results[request_id]
+        with obs.span("session.result", rid=request_id):
+            return self._results[request_id]
 
     def request(self, request_id: int) -> WorkRequest:
         """The compiled WorkRequest of a completed request (its

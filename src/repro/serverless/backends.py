@@ -55,6 +55,7 @@ including the key-consuming ones (mlp, kernel_ridge).
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import time
 from dataclasses import dataclass, field
@@ -66,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.analysis.registry import warm_cache
 from repro.runtime import bounded_put
 from repro.serverless import sanitize
@@ -540,6 +542,23 @@ def roofline_pending_inv_s(requests, groups) -> Optional[float]:
     return total / n if n else None
 
 
+def traced_step(step):
+    """Run a backend's ``step`` inside its ``backend.step`` span: the
+    waves so far, the requests the live drain holds, and the buckets the
+    step dispatched."""
+    @functools.wraps(step)
+    def traced(self, state: DrainState) -> bool:
+        with obs.span("backend.step", wave=state.info.waves,
+                      drain_requests=len(state.requests)) as sp:
+            stats = state.info.dispatch
+            before = stats.dispatched if stats is not None else 0
+            progressed = step(self, state)
+            if stats is not None:
+                sp.set(buckets=stats.dispatched - before)
+            return progressed
+    return traced
+
+
 def _fill_rows(req: WorkRequest, inv_ids: np.ndarray, wall: float,
                pool: PoolConfig):
     """Record successful rows with measured billing (non-wave backends)."""
@@ -587,8 +606,9 @@ class _StreamBackend:
         (slot, invocation, attempt), so no schedule — bucket-coherent
         fill, pipelining, hedges, host loss, resume — can perturb the
         fault pattern."""
-        ri = state.plan.admit(req)
-        self._finalize_request(state, ri)   # resumed-complete ledgers
+        with obs.span("planner.admit", rid=req.tag):
+            ri = state.plan.admit(req)
+            self._finalize_request(state, ri)   # resumed-complete ledgers
         return ri
 
     def run_requests(self, requests: Sequence[WorkRequest]) -> BackendRunInfo:
@@ -872,6 +892,7 @@ class _BucketStreamBackend(_StreamBackend):
                 "axis_decision": self._plan_axis(state, bkey, entries),
                 "mesh": self._axis_mesh()}
 
+    @traced_step
     def step(self, state: DrainState) -> bool:
         q = state.queue
         book = lambda pb, res, el: self._book_harvest(state, pb, res, el)
@@ -1182,6 +1203,7 @@ class WaveBackend(_StreamBackend):
                 break
         return batch
 
+    @traced_step
     def step(self, state: DrainState) -> bool:
         """Dispatch one wave and pipeline it: the wave's buckets stay in
         flight while the next step fills and stacks wave k+1, up to
@@ -1243,7 +1265,9 @@ class WaveBackend(_StreamBackend):
         capacity = max(1, n_workers * pool.lanes_per_worker())
 
         # ---- fill the wave (whole-bucket units, ISSUE 8) ----------------
-        batch = self._fill_bucket_coherent(state, pendings, capacity)
+        with obs.span("planner.fill") as fill:
+            batch = self._fill_bucket_coherent(state, pendings, capacity)
+            fill.set(entries=len(batch))
         dispatch = list(batch)
 
         # ---- execute: one compiled launch per bucket in the wave --------

@@ -41,6 +41,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
+from repro import obs
 from repro.serverless import sanitize
 
 # (request index, invocation id) — compile/buckets.py::Entry, redeclared
@@ -255,59 +256,62 @@ class DispatchQueue:
 
     def _harvest(self, pb: PendingBucket, book: Optional[BookFn],
                  blocked: bool):
-        if pb.state == "CANCELLED":
-            # The losing leg of a hedge race: discard without booking.
-            # Its wall-clock span (beyond the attribution frontier) is
-            # charged to hedge_waste_s, NOT to the request bill — the
-            # winner already carried the bucket's one billable span, so
-            # billing the loser too would double-charge GB-seconds and
-            # skew the autoscaler EMA.
+        with obs.span("dispatch.harvest", blocked=blocked,
+                      entries=len(pb.entries)):
+            if pb.state == "CANCELLED":
+                # The losing leg of a hedge race: discard without booking.
+                # Its wall-clock span (beyond the attribution frontier) is
+                # charged to hedge_waste_s, NOT to the request bill — the
+                # winner already carried the bucket's one billable span, so
+                # billing the loser too would double-charge GB-seconds and
+                # skew the autoscaler EMA.
+                t0 = time.perf_counter()
+                pb.dispatch.discard()
+                t1 = time.perf_counter()
+                if blocked:
+                    self.stats.wait_s += t1 - t0
+                self._mark = t1
+                sanitize.check_attribution(t1, self._t_attr)
+                waste = t1 - max(pb.t_dispatch, self._t_attr)
+                self._t_attr = t1
+                self.stats.hedge_waste_s += max(waste, 0.0)
+                self.stats.cancelled += 1
+                return
             t0 = time.perf_counter()
-            pb.dispatch.discard()
+            if blocked and pb.not_ready_before:
+                # blocking harvest of a held (synthetic-straggler) bucket:
+                # the long tail is part of the wall we are waiting out
+                hold = pb.not_ready_before - t0
+                if hold > 0:
+                    time.sleep(hold)
+            results = pb.dispatch.harvest()
             t1 = time.perf_counter()
             if blocked:
                 self.stats.wait_s += t1 - t0
+            self.stats.harvested += 1
             self._mark = t1
+            # NON-OVERLAPPING duration attribution: concurrent in-flight
+            # buckets share one wall-clock span, so billing each of them
+            # (dispatch -> harvest) would charge the span k times over —
+            # inflating GB-seconds, the autoscaler EMA, and the timeout
+            # check.  Each bucket is billed only the span beyond the
+            # frontier already attributed to earlier harvests; summed
+            # durations then equal the true elapsed wall, matching the old
+            # synchronous per-bucket accounting.
             sanitize.check_attribution(t1, self._t_attr)
-            waste = t1 - max(pb.t_dispatch, self._t_attr)
+            elapsed = t1 - max(pb.t_dispatch, self._t_attr)
             self._t_attr = t1
-            self.stats.hedge_waste_s += max(waste, 0.0)
-            self.stats.cancelled += 1
-            return
-        t0 = time.perf_counter()
-        if blocked and pb.not_ready_before:
-            # blocking harvest of a held (synthetic-straggler) bucket:
-            # the long tail is part of the wall we are waiting out
-            hold = pb.not_ready_before - t0
-            if hold > 0:
-                time.sleep(hold)
-        results = pb.dispatch.harvest()
-        t1 = time.perf_counter()
-        if blocked:
-            self.stats.wait_s += t1 - t0
-        self.stats.harvested += 1
-        self._mark = t1
-        # NON-OVERLAPPING duration attribution: concurrent in-flight
-        # buckets share one wall-clock span, so billing each of them
-        # (dispatch -> harvest) would charge the span k times over —
-        # inflating GB-seconds, the autoscaler EMA, and the timeout
-        # check.  Each bucket is billed only the span beyond the
-        # frontier already attributed to earlier harvests; summed
-        # durations then equal the true elapsed wall, matching the old
-        # synchronous per-bucket accounting.
-        sanitize.check_attribution(t1, self._t_attr)
-        elapsed = t1 - max(pb.t_dispatch, self._t_attr)
-        self._t_attr = t1
-        sanitize.check_bucket_bookable(pb)
-        pb.state = "HARVESTED"
-        fn = pb.book if pb.book is not None else book
-        fn(pb, results, max(elapsed, 0.0))
-        if pb.pair is not None:
-            # this leg won the race: record the outcome and cancel the
-            # loser (HedgePair.settle — the sole cancel performer)
-            if pb.is_hedge:
-                self.stats.hedge_wins += 1
-            pb.pair.settle(pb)
+            sanitize.check_bucket_bookable(pb)
+            pb.state = "HARVESTED"
+            fn = pb.book if pb.book is not None else book
+            with obs.span("ledger.book", entries=len(pb.entries)):
+                fn(pb, results, max(elapsed, 0.0))
+            if pb.pair is not None:
+                # this leg won the race: record the outcome and cancel the
+                # loser (HedgePair.settle — the sole cancel performer)
+                if pb.is_hedge:
+                    self.stats.hedge_wins += 1
+                pb.pair.settle(pb)
 
     def harvest_ready(self, book: Optional[BookFn] = None) -> int:
         """Book every bucket whose launches all report ready — the

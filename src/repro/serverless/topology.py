@@ -77,7 +77,7 @@ from repro.compile.pages import PageDirectory, PagePool, PageStats
 from repro.serverless.autoscale import TopologyAutoscaler
 from repro.serverless.backends import (
     BackendRunInfo, DrainState, PoolConfig, _compile, _StreamBackend,
-    make_sharded_compiler, roofline_pending_inv_s,
+    make_sharded_compiler, roofline_pending_inv_s, traced_step,
 )
 from repro.serverless.chaos import chaos_plan
 from repro.serverless.dispatch import (
@@ -558,6 +558,7 @@ class TopologyBackend(_StreamBackend):
         return len(orphans)
 
     # ---- the stream scheduler -----------------------------------------
+    @traced_step
     def step(self, state: TopologyDrainState) -> bool:
         """Advance ONE host stream by one wave (round-robin); False once
         no host has pending or in-flight work.  Every step first books
