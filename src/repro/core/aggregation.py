@@ -9,22 +9,23 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax.numpy as jnp
+from repro.core.scores import _xp
 from repro.scipy_free_stats import norm_ppf
 
 
 def aggregate_thetas(thetas, ses, method: str = "median") -> Tuple[float, float]:
-    thetas = jnp.asarray(thetas)
-    ses = jnp.asarray(ses)
+    xp = _xp(thetas, ses)
+    thetas = xp.asarray(thetas)
+    ses = xp.asarray(ses)
     if method == "median":
-        theta = jnp.median(thetas)
-        var = jnp.median(ses**2 + (thetas - theta) ** 2)
+        theta = xp.median(thetas)
+        var = xp.median(ses**2 + (thetas - theta) ** 2)
     elif method == "mean":
-        theta = jnp.mean(thetas)
-        var = jnp.mean(ses**2 + (thetas - theta) ** 2)
+        theta = xp.mean(thetas)
+        var = xp.mean(ses**2 + (thetas - theta) ** 2)
     else:
         raise ValueError(method)
-    return float(theta), float(jnp.sqrt(var))
+    return float(theta), float(xp.sqrt(var))
 
 
 def confint(theta: float, se: float, level: float = 0.95):

@@ -248,9 +248,23 @@ class PaddingStats:
 def stitch_predictions(fold_masks: np.ndarray, fold_preds: np.ndarray):
     """Combine per-fold test predictions into full-N cross-fitted vectors.
 
-    fold_masks: (M, K, N) bool; fold_preds: (M, K, N) where entry [m,k,:]
-    is the prediction vector of task (m,k) (only fold rows are used).
-    Returns (M, N).
+    fold_masks: (M, K, N) bool partitions; fold_preds: (M, K, ..., N)
+    where entry [m, k, ...] is a prediction vector of task (m, k) (only
+    fold rows are used).  Returns (M, ..., N): each row's prediction from
+    the task that held it out, gathered through the row's fold index.
+    Since every row lies in exactly one fold, this is bit for bit the
+    masked sum over folds on finite predictions, and it never reads a
+    task's predictions outside its fold.
     """
-    return np.einsum("mkn,mkn->mn", fold_masks.astype(fold_preds.dtype),
-                     fold_preds)
+    m, k, n = fold_masks.shape
+    kt = np.min_scalar_type(k - 1)
+    # a narrow-int contraction: argmax over the strided K axis is far
+    # slower at M=100, N=5099
+    fold = np.einsum("mkn,k->mn", fold_masks.astype(kt, copy=False),
+                     np.arange(k, dtype=kt))
+    preds = np.ascontiguousarray(fold_preds)
+    inner = preds.shape[2:-1]
+    r = int(np.prod(inner))
+    base = (np.arange(m)[:, None] * k + fold) * (r * n) + np.arange(n)
+    idx = base[:, None, :] + (np.arange(r) * n)[:, None]
+    return preds.reshape(-1).take(idx).reshape(m, *inner, n)

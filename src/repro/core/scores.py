@@ -16,8 +16,11 @@ Implemented model classes (the four from Chernozhukov et al. 2018 §4-5):
 (*we follow the DoubleML package: p(Z) estimated plus g(d,X), m(z,X) — the
 task grid size per split is ``n_nuisance``.)
 
-All functions are pure jnp and vmap/vectorize over leading axes, so M
-repetitions evaluate in one shot.
+All functions vectorize over leading axes, so M repetitions evaluate in
+one shot.  They compute in numpy when every array input is a host
+``np.ndarray`` (result assembly, where the predictions already are) and
+in ``jax.numpy`` otherwise, so traced and jitted callers keep the jnp
+path.
 """
 from __future__ import annotations
 
@@ -25,8 +28,17 @@ from dataclasses import dataclass
 from typing import Dict, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 F32 = jnp.float32
+
+
+def _xp(*arrays):
+    """``numpy`` when every array input is a host array, else
+    ``jax.numpy``: host inputs then make no device dispatch or transfer."""
+    if all(isinstance(a, (np.ndarray, np.generic)) for a in arrays):
+        return np
+    return jnp
 
 
 @dataclass(frozen=True)
@@ -58,7 +70,7 @@ SPECS: Dict[str, ScoreSpec] = {s.name: s for s in (PLR, PLIV, IRM, IIVM)}
 
 
 def _clip_propensity(p, eps=0.01):
-    return jnp.clip(p, eps, 1.0 - eps)
+    return _xp(p).clip(p, eps, 1.0 - eps)
 
 
 def plr_score(data, preds, score: str = "partialling out"):
@@ -93,15 +105,16 @@ def pliv_score(data, preds):
 def irm_score(data, preds, score: str = "ATE"):
     y, d = data["y"], data["d"]
     g0, g1 = preds["ml_g0"], preds["ml_g1"]
+    xp = _xp(y, d, g0, g1, preds["ml_m"])
     m = _clip_propensity(preds["ml_m"])
     u0 = y - g0
     u1 = y - g1
     if score == "ATTE":
-        p = jnp.mean(d)
+        p = xp.mean(d)
         psi_a = -d / p
         psi_b = d * u0 / p - m * (1 - d) * u0 / (p * (1 - m))
     else:
-        psi_a = -jnp.ones_like(y)
+        psi_a = -xp.ones_like(y)
         psi_b = g1 - g0 + d * u1 / m - (1 - d) * u0 / (1 - m)
     return psi_a.astype(F32), psi_b.astype(F32)
 
@@ -132,13 +145,15 @@ def evaluate_score(model: str, data, preds, score: str = "default"):
 
 def solve_theta(psi_a, psi_b, axis=-1):
     """theta = -sum(psi_b)/sum(psi_a) along the observation axis."""
-    return -jnp.sum(psi_b, axis=axis) / jnp.sum(psi_a, axis=axis)
+    xp = _xp(psi_a, psi_b)
+    return -xp.sum(psi_b, axis=axis) / xp.sum(psi_a, axis=axis)
 
 
 def score_se(psi_a, psi_b, theta, axis=-1):
     """Sandwich standard error from the evaluated score (CCDDHNR18 Thm 3.2)."""
+    xp = _xp(psi_a, psi_b, theta)
     n = psi_a.shape[axis]
-    psi = psi_a * jnp.expand_dims(theta, axis) + psi_b
-    j = jnp.mean(psi_a, axis=axis)
-    var = jnp.mean(psi * psi, axis=axis) / (j * j)
-    return jnp.sqrt(var / n)
+    psi = psi_a * xp.expand_dims(theta, axis) + psi_b
+    j = xp.mean(psi_a, axis=axis)
+    var = xp.mean(psi * psi, axis=axis) / (j * j)
+    return xp.sqrt(var / n)

@@ -52,7 +52,6 @@ from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Union
 
 import jax
-import jax.numpy as jnp
 import msgpack
 import numpy as np
 
@@ -181,34 +180,41 @@ def compile_raw_request(grid: TaskGrid, scaling: str, x, targets, train_w,
 
 def assemble_result(plan: DMLPlan, data: DMLData, req: WorkRequest,
                     request_id: Optional[int] = None) -> DMLResult:
-    """Stitch fold predictions, evaluate the score, run local inference."""
-    data = DMLData.from_dict(data)
-    preds = req.gathered_preds()                 # (M, K, L, N)
-    masks = req.fold_masks
+    """Stitch fold predictions, evaluate the score, run local inference.
 
-    fitted = {ns.name: stitch_predictions(masks, preds[:, :, l])
-              for l, ns in enumerate(plan.nuisances)}
-    dml_data = {k: jnp.asarray(v)[None] for k, v in
-                data.score_arrays().items()}
-    pred_tree = {k: jnp.asarray(v) for k, v in fitted.items()}
-    psi_a, psi_b = evaluate_score(plan.model, dml_data, pred_tree, plan.score)
-    thetas = solve_theta(psi_a, psi_b)                  # (M,)
-    ses = score_se(psi_a, psi_b, thetas)
-    theta, se = aggregate_thetas(thetas, ses, plan.inference.aggregation)
-    ci = confint(theta, se, plan.inference.level)
+    Everything but the bootstrap runs in numpy float32 on the host, where
+    the predictions already are: a few thousand flops a repetition cost
+    less there than the dispatches and transfers of eager device ops.
+    Without a bootstrap, assembly touches no device array (span arg
+    ``on_host``).
+    """
+    n_boot = plan.inference.n_boot
+    with obs.span("session.assemble", rid=request_id,
+                  on_host=int(not n_boot)):
+        data = DMLData.from_dict(data)
+        # (M, L, N); DMLData and the ledger hold float32
+        fitted = stitch_predictions(req.fold_masks, req.gathered_preds())
+        pred_tree = {ns.name: fitted[:, l]
+                     for l, ns in enumerate(plan.nuisances)}
+        dml_data = {k: v[None] for k, v in data.score_arrays().items()}
+        psi_a, psi_b = evaluate_score(plan.model, dml_data, pred_tree,
+                                      plan.score)
+        thetas = solve_theta(psi_a, psi_b)                  # (M,)
+        ses = score_se(psi_a, psi_b, thetas)
+        theta, se = aggregate_thetas(thetas, ses, plan.inference.aggregation)
+        ci = confint(theta, se, plan.inference.level)
 
-    boot_ci = None
-    if plan.inference.n_boot:
-        bt, se1 = multiplier_bootstrap(
-            psi_a[0], psi_b[0], float(thetas[0]),
-            jax.random.key(plan.resampling.seed + 99),
-            n_boot=plan.inference.n_boot)
-        boot_ci = boot_confint(float(thetas[0]), se1, bt)
+        boot_ci = None
+        if n_boot:
+            bt, se1 = multiplier_bootstrap(
+                psi_a[0], psi_b[0], float(thetas[0]),
+                jax.random.key(plan.resampling.seed + 99), n_boot=n_boot)
+            boot_ci = boot_confint(float(thetas[0]), se1, bt)
 
-    res = DMLResult(theta=theta, se=se, ci=ci, thetas=np.asarray(thetas),
-                    ses=np.asarray(ses), report=req.report, boot_ci=boot_ci,
-                    request_id=request_id)
-    res.psi = (np.asarray(psi_a), np.asarray(psi_b))
+        res = DMLResult(theta=theta, se=se, ci=ci, thetas=thetas, ses=ses,
+                        report=req.report, boot_ci=boot_ci,
+                        request_id=request_id)
+        res.psi = (psi_a, psi_b)
     return res
 
 
@@ -407,9 +413,8 @@ class DMLSession:
             for p in list(self._queue):
                 if not (p.admitted and p.req.ledger.complete):
                     continue
-                with obs.span("session.assemble", rid=p.request_id):
-                    res = assemble_result(p.plan, p.data, p.req,
-                                          request_id=p.request_id)
+                res = assemble_result(p.plan, p.data, p.req,
+                                      request_id=p.request_id)
                 self._results[p.request_id] = res
                 self._requests[p.request_id] = p.req
                 self.completion_order.append(p.request_id)

@@ -82,3 +82,24 @@ def test_stitch_predictions():
     assert out.shape == (2, 30)
     m, k, i = 1, 2, int(np.where(masks[1, 2])[0][0])
     assert out[m, i] == pytest.approx(preds[m, k, i])
+
+
+@pytest.mark.parametrize("m", [1, 7])
+@pytest.mark.parametrize("k", [2, 5])
+def test_stitch_gather_is_bitwise_the_masked_sum(m, k):
+    """The fold-index gather against the masked sum over folds it
+    replaced, on random fold draws, with and without a nuisance axis."""
+    n, n_nuis = 61, 3
+    rng = np.random.default_rng(10 * m + k)
+    masks = draw_fold_masks(n, k, m, seed=int(rng.integers(1 << 30)))
+    preds = rng.normal(size=(m, k, n_nuis, n)).astype(np.float32)
+
+    def masked_sum(p):
+        return np.einsum("mkn,mkn->mn", masks.astype(p.dtype), p)
+    out = stitch_predictions(masks, preds)
+    assert out.shape == (m, n_nuis, n) and out.dtype == np.float32
+    for l in range(n_nuis):
+        ref = masked_sum(preds[:, :, l])
+        one = stitch_predictions(masks, preds[:, :, l])
+        assert np.array_equal(one.view(np.uint32), ref.view(np.uint32))
+        assert np.array_equal(out[:, l].view(np.uint32), ref.view(np.uint32))

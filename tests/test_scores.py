@@ -2,9 +2,11 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from repro.core.aggregation import aggregate_thetas
 from repro.core.scores import (
-    SPECS, irm_score, plr_score, score_se,
+    SPECS, evaluate_score, irm_score, plr_score, score_se,
     solve_theta,
 )
 
@@ -103,3 +105,70 @@ def test_all_specs_have_consistent_nuisance_counts():
     assert SPECS["pliv"].n_nuisance == 3
     assert SPECS["irm"].n_nuisance == 3
     assert SPECS["iivm"].n_nuisance == 5
+
+
+def _host_fixture(model, m=3, n=257, theta=0.7, seed=1):
+    """Host float32 arrays for every role and nuisance of ``model``:
+    binary d/z where the model needs them, propensities in (0, 1), and
+    predictions near the truth so theta stays away from 0."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=n).astype(np.float32)
+    p = (1 / (1 + np.exp(-x))).astype(np.float32)
+    binary = model in ("irm", "iivm")
+    z = (rng.random(n) < p).astype(np.float32) if model == "iivm" \
+        else (x + rng.normal(size=n)).astype(np.float32)
+    d = (rng.random(n) < p).astype(np.float32) if binary \
+        else (0.5 * x + 0.5 * z + rng.normal(size=n)).astype(np.float32)
+    y = (np.tanh(x) + theta * d + rng.normal(size=n)).astype(np.float32)
+    data = {"y": y[None], "d": d[None], "z": z[None]}
+
+    def near(v):
+        return (v + 0.1 * rng.normal(size=(m, n))).astype(np.float32)
+    truth = {"ml_l": theta * d + np.tanh(x), "ml_m": 0.5 * x,
+             "ml_r": 0.5 * x, "ml_g0": np.tanh(x), "ml_g1": np.tanh(x) + theta}
+    if model == "pliv":
+        truth["ml_m"] = x
+    if binary:
+        truth["ml_m"] = p
+        truth["ml_r0"], truth["ml_r1"] = 0.8 * p, 0.8 * p + 0.2
+    names = [ns[0] for ns in SPECS[model].nuisances]
+    preds = {k: near(truth[k]) for k in names}
+    if binary:                                  # keep clipped propensities
+        for k in ("ml_m", "ml_r0", "ml_r1"):
+            if k in preds:
+                preds[k] = np.clip(preds[k], 0.05, 0.95)
+    return data, preds
+
+
+def _close(host, dev, rel=2e-6):
+    assert isinstance(host, np.ndarray) and host.dtype == np.float32
+    dev = np.asarray(dev)
+    scale = float(np.max(np.abs(dev)))
+    np.testing.assert_allclose(host, dev, rtol=rel, atol=rel * scale)
+
+
+@pytest.mark.parametrize("model,score", [
+    ("plr", "partialling out"), ("plr", "IV-type"), ("pliv", "default"),
+    ("irm", "ATE"), ("irm", "ATTE"), ("iivm", "default")])
+def test_host_inputs_compute_in_numpy_like_the_jnp_path(model, score):
+    data, preds = _host_fixture(model)
+    pa, pb = evaluate_score(model, data, preds, score)
+    jd = {k: jnp.asarray(v) for k, v in data.items()}
+    jp = {k: jnp.asarray(v) for k, v in preds.items()}
+    ja, jb = evaluate_score(model, jd, jp, score)
+    assert isinstance(ja, jax.Array)
+    _close(pa, ja)
+    _close(pb, jb)
+    th, jth = solve_theta(pa, pb), solve_theta(ja, jb)
+    _close(th, jth)
+    _close(score_se(pa, pb, th), score_se(ja, jb, jth))
+
+
+@pytest.mark.parametrize("method", ["median", "mean"])
+def test_host_aggregation_matches_the_jnp_path(method):
+    rng = np.random.default_rng(4)
+    thetas = (0.5 + 0.05 * rng.normal(size=8)).astype(np.float32)
+    ses = (0.1 + 0.01 * rng.random(8)).astype(np.float32)
+    host = aggregate_thetas(thetas, ses, method)
+    dev = aggregate_thetas(jnp.asarray(thetas), jnp.asarray(ses), method)
+    np.testing.assert_allclose(host, dev, rtol=2e-6)

@@ -1,9 +1,12 @@
 """DMLSession: many estimation requests fused into shared waves on one
 warm backend, each returning the theta it would get running alone."""
+import jax
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import DMLData, DMLPlan, DMLSession, estimate
+from repro.core.session import assemble_result
 from repro.data import make_irm_data, make_plr_data
 from repro.serverless import PoolConfig
 
@@ -126,3 +129,35 @@ def test_session_empty_run_and_billing_split():
     for r in res:
         assert r.report.bill.n_invocations == 2 * 2
     assert sess.run() == []                       # queue drained
+
+
+def test_assembly_touches_no_device_array_without_a_bootstrap():
+    """A finished plr request assembles in numpy on the host: no explicit
+    transfer under the guard, ``on_host=1`` on its span, and the result the
+    session returned.  With a bootstrap it still assembles, with
+    ``on_host=0``, and the guard catches its device draws."""
+    data = DMLData.from_dict(make_plr_data(n_obs=120, dim_x=4, seed=2))
+    sess = DMLSession(backend="inline", pool=PoolConfig(n_workers=2))
+    rid = sess.submit(_plr_plan(5), data)
+    sess.run()
+    req, served = sess.request(rid), sess.result(rid)
+    obs.clear()
+    with jax.transfer_guard("disallow_explicit"), obs.recording():
+        res = assemble_result(_plr_plan(5), data, req, request_id=rid)
+    (sp,) = [s for s in obs.spans() if s.name == "session.assemble"]
+    assert sp.args["on_host"] == 1 and sp.rid == rid
+    assert (res.theta, res.se) == (served.theta, served.se)
+    assert res.thetas.dtype == res.psi[0].dtype == np.float32
+    assert np.array_equal(res.thetas, served.thetas)
+
+    boot = _plr_plan(5, n_boot=50)
+    with jax.transfer_guard("disallow_explicit"), \
+            pytest.raises(Exception, match="transfer"):
+        assemble_result(boot, data, req)
+    obs.clear()
+    with obs.recording():
+        res = assemble_result(boot, data, req)
+    (sp,) = [s for s in obs.spans() if s.name == "session.assemble"]
+    assert sp.args["on_host"] == 0 and res.boot_ci is not None
+    assert res.theta == served.theta
+    obs.clear()
