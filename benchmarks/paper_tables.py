@@ -562,7 +562,7 @@ def topology_drain(n_hosts: int = 2, n_requests_per_family: int = 1,
         "waves_per_host": {h.host_id: h.waves for h in t.hosts},
         "placements_last_drain": len(t.placements),
         "resident_placements_last_drain":
-            sum(1 for _, _, s in t.placements if s > 0),
+            sum(1 for *_, s in t.placements if s > 0),
         "autoscale_decisions": len(decisions),
         "autoscale_priced_by": sorted({d.priced_by for d in decisions}),
         "autoscale_hosts": sorted({d.host for d in decisions}),

@@ -219,11 +219,12 @@ def four_chip_phase() -> None:
     check(np.isfinite(theta_1) and np.isfinite(ref[0].se),
           "non-finite one-chip theta or SE")
 
-    # the topology routes whole buckets to hosts: three companion
-    # requests of other tall shapes (hence other buckets) give all four
+    # the topology places each request's invocations in a bucket as one
+    # unit, so one request runs on one host: three companion requests
+    # on other data of the same shape (the same bucket) give all four
     # hosts work; only the first is compared with the one-chip theta
     companions = [DMLData.from_dict(make_plr_data(
-        n_obs=131072 - 8 * i, dim_x=12, seed=i)) for i in (1, 2, 3)]
+        n_obs=131072, dim_x=12, seed=i)) for i in (1, 2, 3)]
     all_devs = set(jax.devices())
     for name, pool, extra in (("sharded", None, []),
                               ("topology", PoolConfig(n_hosts=4),
@@ -255,10 +256,10 @@ def four_chip_phase() -> None:
             check(mesh_devs == all_devs, "the mesh does not span the chips")
         else:
             hosts = sess.backend.topology.hosts
-            placed = sorted(h for _, h, _ in info.topology.placements)
+            placed = sorted(h for _, _, h, _ in info.topology.placements)
             page_devs = [sorted(d.id for d in _devices_of(
                 list(h.pool._pages.values()))) for h in hosts]
-            log(f"[tall] topology: bucket placements (host ids) {placed}; "
+            log(f"[tall] topology: unit placements (host ids) {placed}; "
                 f"lead device per host {[h.device.id for h in hosts]}; "
                 f"devices holding each host's pages {page_devs}")
             used = {d for devs in page_devs for d in devs}

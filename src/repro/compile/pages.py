@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.analysis.registry import warm_cache
 from repro.core.crossfit import pow2_bucket
 
@@ -244,7 +245,8 @@ class PagePool:
         ``(1, n_pad, p_pad)`` so a singleton launch can consume it
         directly with zero copies; a local miss tries a device-to-device
         fetch from a peer pool (directory) before paying the
-        host->device transfer."""
+        host->device transfer, inside a ``pages.fetch`` span whose
+        ``source`` says which (``d2d`` or ``h2d``)."""
         page = self._pages.get(pkey)
         nbytes = n_pad * p_pad * 4
         if page is not None:
@@ -253,18 +255,21 @@ class PagePool:
             self.stats.bytes_saved += nbytes
             return page
         self.stats.misses += 1
-        peer = self.directory.fetch(pkey, self.host_id) \
-            if self.directory is not None else None
-        if peer is not None:
-            page = self._put(peer)                  # d2d cross-host copy
-            self.stats.cross_host_fetches += 1
-            self.stats.bytes_d2d += nbytes
-        else:
-            x = np.asarray(req.x, np.float32)
-            host = np.zeros((1, n_pad, p_pad), np.float32)
-            host[0, :x.shape[0], :x.shape[1]] = x
-            page = self._put(host)                  # the one h2d copy
-            self.stats.bytes_h2d += nbytes
+        with obs.span("pages.fetch", bytes=nbytes) as sp:
+            peer = self.directory.fetch(pkey, self.host_id) \
+                if self.directory is not None else None
+            if peer is not None:
+                sp.set(source="d2d")
+                page = self._put(peer)              # d2d cross-host copy
+                self.stats.cross_host_fetches += 1
+                self.stats.bytes_d2d += nbytes
+            else:
+                sp.set(source="h2d")
+                x = np.asarray(req.x, np.float32)
+                host = np.zeros((1, n_pad, p_pad), np.float32)
+                host[0, :x.shape[0], :x.shape[1]] = x
+                page = self._put(host)              # the one h2d copy
+                self.stats.bytes_h2d += nbytes
         self._pages[pkey] = page
         self._nbytes[pkey] = nbytes
         self._page_bytes += nbytes

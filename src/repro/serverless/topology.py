@@ -1,5 +1,5 @@
-"""The topology layer (ISSUE 4 tentpole): per-mesh drain streams with
-locality-aware bucket placement.
+"""The topology layer: per-mesh drain streams with locality-aware
+placement of each request's work.
 
 The drain engine so far ran one stream over one host mesh.  This module
 models the *cluster*: a ``Topology`` of host meshes — real pods split out
@@ -8,11 +8,15 @@ N simulated hosts over this process's devices — each owning a per-host
 device-resident ``PagePool`` (all pools sharing one ``PageDirectory``)
 and one drain stream.  ``TopologyBackend`` is the scheduler over them:
 
-  * **placement** — every megabatch bucket is routed to a host by
-    ``sharding/policy.py::place_bucket``, scored against each host's
+  * **placement** — the unit of placement is a request's pending
+    invocations in one megabatch bucket (one page: the request's data
+    at the bucket's shape).  Every new unit is routed to a host by
+    ``sharding/policy.py::place_unit``, scored against each host's
     page residency (stack-cached > pages-resident > cold, ties to the
     least-loaded host).  Steady-state traffic therefore re-lands on the
-    host already holding its pages: zero transfers of any kind.
+    host already holding its pages: zero transfers of any kind; and a
+    bucket of many requests on fresh data — a Monte-Carlo study's
+    replications — spreads over every host by load.
   * **per-mesh streams** — one ``step()`` advances ONE host's stream by
     one wave (round-robin cursor), so the session's event loop
     interleaves all hosts exactly as it interleaves waves today;
@@ -22,11 +26,11 @@ and one drain stream.  ``TopologyBackend`` is the scheduler over them:
     blocking, and results are booked by later steps' non-blocking
     harvest — so one mesh's device execution overlaps every other
     host's placement, stealing, and booking.
-  * **work-stealing** — a host whose queue drained steals the
-    least-local bucket from the most-loaded host
-    (``policy.steal_choice``); the stolen bucket's pages arrive
+  * **work-stealing** — a host whose queue drained steals not-yet-
+    dispatched units from the most-loaded host, least local first
+    (``policy.steal_choice``); a stolen unit's page arrives
     device-to-device from the holder (a *cross-host transfer*, counted
-    by the directory) and stay resident, so a re-stolen bucket is free.
+    by the directory) and stays resident, so a re-stolen unit is free.
   * **autoscaling** — a ``TopologyAutoscaler`` sizes each host's wave
     independently, pricing cold candidates with the compiler's
     per-bucket roofline FLOP estimates
@@ -43,7 +47,8 @@ and one drain stream.  ``TopologyBackend`` is the scheduler over them:
     (sharding/gram.py), chunk-paging tall N, and stamps the
     ``executed`` axis back on the decision.  Tall-N Gram buckets
     (``n_pad > DEVICE_PAGE_ROWS``) are routed — and stolen — only by
-    hosts whose data axis can stream them.  Decisions land on
+    hosts whose data axis can stream them: eligibility stays a property
+    of the bucket.  Decisions land on
     ``BackendRunInfo.axis_plans`` like autoscale decisions.
   * **fault tolerance** (ISSUE 10) — chaos pools draw identity-keyed
     failure/straggler verdicts at booking (serverless/chaos.py) exactly
@@ -51,12 +56,12 @@ and one drain stream.  ``TopologyBackend`` is the scheduler over them:
     the least-loaded *other* live host through the shared
     bitwise-reference cache; and ``kill_host`` simulates losing a mesh
     mid-drain — its page pool is invalidated (directory detach), its
-    in-flight buckets are abandoned (LOST), and their still-RUNNING
-    ledger rows resurface through the pending view to be re-routed
-    onto the survivors, whose pools re-materialize any orphaned pages
-    on first touch.
+    in-flight buckets are abandoned (LOST), its units are unassigned,
+    and their still-RUNNING ledger rows resurface through the pending
+    view to be re-routed onto the survivors, whose pools
+    re-materialize any orphaned pages on first touch.
 
-Determinism: placement and stealing only decide *where* a bucket's
+Determinism: placement and stealing only decide *where* a unit's
 fixed-shape program runs; per-task PRNG streams are fixed at compile
 time, so buckets the planner keeps on the task@1 layout (the whole
 serving mix) are bitwise-identical to the single-host inline path
@@ -73,6 +78,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.compile.pages import PageDirectory, PagePool, PageStats
 from repro.serverless.autoscale import TopologyAutoscaler
 from repro.serverless.backends import (
@@ -83,7 +89,11 @@ from repro.serverless.chaos import chaos_plan
 from repro.serverless.dispatch import (
     DispatchQueue, DispatchStats, PendingBucket,
 )
-from repro.sharding.policy import place_bucket, steal_choice
+from repro.sharding.policy import place_unit, steal_choice
+
+# the unit of placement: (bucket key, request slot) — one request's
+# pending invocations in one bucket
+Unit = Tuple[object, int]
 
 
 # ---------------------------------------------------------------------------
@@ -173,6 +183,20 @@ class Topology:
         return out
 
 
+class ClusterPages:
+    """The per-host pools seen as the one ``pages`` a backend exposes:
+    ``stats`` is the cluster-wide sum, as a single-stream backend's
+    ``pages.stats`` is its one pool's.  Telemetry reads it; the drain
+    itself stages through each host's own pool."""
+
+    def __init__(self, topology: Topology):
+        self._topology = topology
+
+    @property
+    def stats(self) -> PageStats:
+        return self._topology.page_stats()
+
+
 # ---------------------------------------------------------------------------
 # telemetry
 # ---------------------------------------------------------------------------
@@ -183,8 +207,8 @@ class HostLaneInfo:
     n_devices: int
     waves: int = 0
     invocations: int = 0
-    buckets_placed: int = 0             # routed here at admission
-    steals: int = 0                     # buckets this host stole
+    units_placed: int = 0               # routed here at admission
+    steals: int = 0                     # units this host stole
 
 
 @dataclass
@@ -193,9 +217,10 @@ class TopologyInfo:
     ``last_run_info.topology``)."""
     n_hosts: int
     hosts: List[HostLaneInfo]
-    steals: int = 0
-    placements: List[Tuple[object, int, float]] = field(
-        default_factory=list)           # (bucket key, host, score)
+    steals: int = 0                     # units stolen
+    placements: List[Tuple[object, int, int, float]] = field(
+        default_factory=list)           # (bucket key, request slot, host,
+                                        #  score)
     host_losses: int = 0                # hosts killed mid-drain
     lost_buckets: int = 0               # in-flight buckets abandoned
 
@@ -203,10 +228,11 @@ class TopologyInfo:
 @dataclass
 class TopologyDrainState(DrainState):
     """One continuous drain over all host streams: the shared bucket
-    plan plus the live bucket→host assignment, the round-robin cursor
-    the event loop steps with, and one in-flight dispatch queue per
-    host mesh (the per-host streams are the dispatch unit)."""
-    assignment: Dict[object, int] = field(default_factory=dict)
+    plan plus the live unit→host assignment (units with pending,
+    not-yet-dispatched invocations only), the round-robin cursor the
+    event loop steps with, and one in-flight dispatch queue per host
+    mesh (the per-host streams are the dispatch unit)."""
+    assignment: Dict[Unit, int] = field(default_factory=dict)
     cursor: int = 0
     queues: Dict[int, DispatchQueue] = field(default_factory=dict)
 
@@ -221,7 +247,8 @@ class TopologyDrainState(DrainState):
 # the backend
 # ---------------------------------------------------------------------------
 class TopologyBackend(_StreamBackend):
-    """Per-mesh drain streams with page-locality routing.
+    """Per-mesh drain streams with page-locality routing of units (a
+    request's pending invocations in one bucket).
 
     One ``step(state)`` advances one host stream by one wave: the
     session's event loop therefore steps all streams round-robin, and a
@@ -251,7 +278,7 @@ class TopologyBackend(_StreamBackend):
         self._host_compilers: Dict[int, object] = {}
         self.autoscaler = TopologyAutoscaler(self.pool, len(topology)) \
             if self.pool.autoscale else None
-        self.pages = None               # per-host pools live on the topology
+        self.pages = ClusterPages(topology)
 
     @property
     def _programs(self) -> Dict:
@@ -278,36 +305,31 @@ class TopologyBackend(_StreamBackend):
         return state
 
     # admit() is inherited: routing happens lazily in step() (one pass
-    # over all unassigned buckets), so batch admission stays linear
+    # over all unassigned units), so batch admission stays linear
 
     # ---- placement ----------------------------------------------------
-    def _bucket_pkeys(self, state, key, entries) -> Tuple:
-        """The bucket's page keys, one per request with pending entries
-        (canonical blocks launch one request per program, so each page's
-        singleton stack is the unit the policy probes)."""
-        order: Dict[int, None] = {}
-        for ri, _ in entries:
-            order.setdefault(ri)
-        return tuple(
-            PagePool.page_key(state.requests[ri], key.n_pad, key.p_pad)
-            for ri in order)
-
-    def _loads(self, state, groups) -> List[int]:
-        """Pending invocations currently assigned to each host."""
-        loads = [0] * len(self.topology)
+    @staticmethod
+    def _units(groups) -> Dict[Unit, List]:
+        """The pending view regrouped into units, in bucket then
+        request order."""
+        units: Dict[Unit, List] = {}
         for key, entries in groups.items():
-            h = state.assignment.get(key)
-            if h is not None:
-                loads[h] += len(entries)
-        return loads
+            for e in entries:
+                units.setdefault((key, e[0]), []).append(e)
+        return units
+
+    def _pkey(self, state, unit: Unit):
+        """A unit's one page: its request's data at its bucket's shape."""
+        key, ri = unit
+        return PagePool.page_key(state.requests[ri], key.n_pad, key.p_pad)
 
     def _eligible_hosts(self, key) -> List[int]:
-        """The hosts a bucket may be routed to.  Tall-N Gram buckets
-        (``n_pad > DEVICE_PAGE_ROWS``: no single device holds the page,
-        so the drain must chunk-stream them data-parallel, ISSUE 9) go
-        only to hosts whose mesh can stream them — the largest data-axis
-        size that divides ``n_pad``; every other bucket runs anywhere.
-        Dead hosts are never eligible."""
+        """The hosts a bucket's units may be routed to.  Tall-N Gram
+        buckets (``n_pad > DEVICE_PAGE_ROWS``: no single device holds
+        the page, so the drain must chunk-stream them data-parallel)
+        go only to hosts whose mesh can stream them — the
+        largest data-axis size that divides ``n_pad``; every other
+        bucket runs anywhere.  Dead hosts are never eligible."""
         hosts = [h.host_id for h in self.topology.alive()]
         from repro.compile.program import bucket_family
         from repro.launch.roofline import DEVICE_PAGE_ROWS, GRAM_FAMILIES
@@ -326,49 +348,79 @@ class TopologyBackend(_StreamBackend):
         best = max(axis_m(h) for h in ok)
         return [h for h in ok if axis_m(h) == best]
 
-    def _route(self, state: TopologyDrainState, groups) -> None:
-        """Assign every not-yet-routed bucket to its best host among the
-        bucket's eligible set (loads maintained incrementally)."""
-        pools = [h.pool for h in self.topology.hosts]
-        loads = self._loads(state, groups)
-        for key, entries in groups.items():
-            if key in state.assignment:
-                continue
-            elig = self._eligible_hosts(key)
-            placed = place_bucket(self._bucket_pkeys(state, key, entries),
-                                  [pools[h] for h in elig],
-                                  [loads[h] for h in elig])
-            host = elig[placed.host]
-            state.assignment[key] = host
-            loads[host] += len(entries)
+    def _route(self, state: TopologyDrainState,
+               units: Dict[Unit, List]) -> Dict[int, List[Unit]]:
+        """Keep the assignment of every unit still pending, place every
+        new one on its best host among its bucket's eligible set (loads
+        maintained incrementally), and return each host's units in
+        queue order.  Units whose invocations all went in flight drop
+        out of the assignment; a retry that resurfaces them is placed
+        again, by residency back where it ran."""
+        with obs.span("topology.route") as sp:
+            old = state.assignment
+            assignment: Dict[Unit, int] = {}
+            loads = [0] * len(self.topology)
+            fresh: List[Unit] = []
+            for u, ents in units.items():
+                h = old.get(u)
+                if h is None:
+                    fresh.append(u)
+                else:
+                    assignment[u] = h
+                    loads[h] += len(ents)
+            pools = [h.pool for h in self.topology.hosts]
             info = state.info.topology
-            info.hosts[host].buckets_placed += 1
-            info.placements.append((key, host, placed.score))
+            elig_of: Dict[object, List[int]] = {}
+            cold = 0
+            for u in fresh:
+                key = u[0]
+                elig = elig_of.get(key)
+                if elig is None:
+                    elig = elig_of[key] = self._eligible_hosts(key)
+                placed = place_unit(self._pkey(state, u),
+                                    [pools[h] for h in elig],
+                                    [loads[h] for h in elig])
+                host = elig[placed.host]
+                assignment[u] = host
+                loads[host] += len(units[u])
+                cold += placed.score == 0
+                info.hosts[host].units_placed += 1
+                info.placements.append((key, u[1], host, placed.score))
+            state.assignment = assignment
+            sp.set(units=len(fresh), cold=cold)
+            by_host: Dict[int, List[Unit]] = {}
+            for u in units:
+                by_host.setdefault(assignment[u], []).append(u)
+            return by_host
 
-    def _try_steal(self, state: TopologyDrainState, groups,
-                   thief: int) -> List:
-        """An idle host takes the least-local bucket from the most
-        loaded host; the migration is recorded and the assignment
-        flipped so the thief finishes the bucket."""
-        queues: Dict[int, List] = {}
-        for key in groups:
-            h = state.assignment[key]
-            # a host can only steal buckets it is eligible to stream
-            # (tall-N Gram buckets stay on streaming-capable meshes)
-            if h != thief and thief in self._eligible_hosts(key):
-                queues.setdefault(h, []).append(key)
-        pools = [h.pool for h in self.topology.hosts]
-        pick = steal_choice(
-            queues, pools,
-            lambda k: self._bucket_pkeys(state, k, groups[k]))
-        if pick is None:
-            return []
-        _, key = pick
-        state.assignment[key] = thief
-        info = state.info.topology
-        info.steals += 1
-        info.hosts[thief].steals += 1
-        return [key]
+    def _try_steal(self, state: TopologyDrainState,
+                   units: Dict[Unit, List],
+                   by_host: Dict[int, List[Unit]], thief: int) -> List:
+        """An idle host takes the least-local units of the most loaded
+        host, up to half its queue; the migration is recorded and the
+        assignment flipped so the thief runs them.  Only units in the
+        pending view are candidates, so no in-flight entry moves."""
+        with obs.span("topology.steal", thief=thief) as sp:
+            # tall-N Gram buckets stay on streaming-capable meshes
+            runnable = {k for k in {u[0] for u in units}
+                        if thief in self._eligible_hosts(k)}
+            queues = {h: [u for u in us if u[0] in runnable]
+                      for h, us in by_host.items() if h != thief}
+            pick = steal_choice(
+                queues, [h.pool for h in self.topology.hosts],
+                lambda u: self._pkey(state, u), lambda u: len(units[u]))
+            if pick is None:
+                sp.set(donor=-1, invocations=0)
+                return []
+            donor, stolen = pick
+            for u in stolen:
+                state.assignment[u] = thief
+            info = state.info.topology
+            info.steals += len(stolen)
+            info.hosts[thief].steals += len(stolen)
+            sp.set(donor=donor,
+                   invocations=sum(len(units[u]) for u in stolen))
+            return stolen
 
     # ---- per-bucket axis planning (ISSUE 8) ---------------------------
     def _host_compiler(self, host_id: int):
@@ -414,7 +466,8 @@ class TopologyBackend(_StreamBackend):
         return self.compiler, 1, None
 
     # ---- the per-host wave --------------------------------------------
-    def _wave_capacity(self, state, host_id: int, mine, groups) -> int:
+    def _wave_capacity(self, state, host_id: int, mine: List[Unit],
+                       units: Dict[Unit, List]) -> int:
         pool = self.pool
         if pool.worker_schedule is not None:   # legacy static ramp, per
             sched = pool.worker_schedule       # host stream (wave parity)
@@ -423,11 +476,14 @@ class TopologyBackend(_StreamBackend):
             return max(1, w * pool.lanes_per_worker())
         if self.autoscaler is None:
             return max(1, pool.n_workers * pool.lanes_per_worker())
-        depth = sum(len(groups[k]) for k in mine)
+        queued: Dict[object, List] = {}
+        for u in mine:
+            queued.setdefault(u[0], []).extend(units[u])
+        depth = sum(len(ents) for ents in queued.values())
         tasks = sum(
             state.requests[ri].grid.tasks_per_invocation(
                 state.requests[ri].scaling)
-            for k in mine for ri, _ in groups[k])
+            for ents in queued.values() for ri, _ in ents)
         decision = self.autoscaler.decide(
             host_id, depth,
             tasks_per_invocation=max(1, tasks // max(depth, 1)),
@@ -436,7 +492,7 @@ class TopologyBackend(_StreamBackend):
             # occupancy, not queue depth — never provisioned for twice
             in_flight=state.queues[host_id].in_flight,
             roofline_inv_s=lambda: roofline_pending_inv_s(
-                state.requests, {k: groups[k] for k in mine}))
+                state.requests, queued))
         state.info.autoscale.append(decision)
         return max(1, decision.n_workers * pool.lanes_per_worker())
 
@@ -453,53 +509,57 @@ class TopologyBackend(_StreamBackend):
         self._checkpoint(state)
 
     def _host_wave(self, state: TopologyDrainState, host_id: int,
-                   mine: List, groups) -> None:
-        """Dispatch one wave of this host's buckets WITHOUT waiting —
-        the launches land in the host's in-flight queue and are booked
-        by a later step's harvest, so every other host's placement,
-        stealing, and booking overlaps this mesh's execution."""
-        host = self.topology.hosts[host_id]
-        # a zero byte budget means "pool off" (PoolConfig contract):
-        # fall back to host page stacking instead of churning an
-        # always-evicting device pool
-        host_pages = host.pool if host.pool.byte_budget > 0 else None
-        lane = state.info.topology.hosts[host_id]
-        q = state.queues[host_id]
-        book = lambda pb, res, el: self._book_harvest(state, pb, res, el)
-        capacity = self._wave_capacity(state, host_id, mine, groups)
-        # fill the wave bucket-by-bucket, truncating the last bucket to
-        # the remaining capacity; each selection takes at least one
-        # invocation, so a wave always makes progress
-        selected: List[Tuple[object, List]] = []
-        taken = 0
-        for key in mine:
-            if taken >= capacity and selected:
-                break
-            ents = groups[key][:max(capacity - taken, 1)]
-            selected.append((key, ents))
-            taken += len(ents)
-        for key, ents in selected:
-            running: Dict[int, List[int]] = {}
-            for ri, inv in ents:
-                running.setdefault(ri, []).append(inv)
-            for ri, invs in running.items():
-                state.requests[ri].ledger.mark_running(invs)
-            decision = self._plan_host_axis(state, key, ents, host_id)
-            compiler, b_align, axis_mesh = self._bucket_compiler(
-                host_id, decision)
-            opts = dict(self._dispatch_opts())
-            # fusion follows the *chosen* cache, not the shared one: a
-            # host's sharded-fused cache fuses, a partition-only cache
-            # would not (compile/program.py gate)
-            opts["fuse"] = self.pool.fuse and (
-                compiler.partition is None
-                or compiler.partition_fused is not None)
-            bd = _compile().dispatch_bucket(
-                state.plan, compiler, key, ents, pages=host_pages,
-                b_align=b_align, axis_decision=decision, mesh=axis_mesh,
-                **opts)
-            self._push_bucket(state, q, bd, book, host=host_id)
-            state.seen_buckets.add(key)
+                   mine: List[Unit], units: Dict[Unit, List]) -> None:
+        """Dispatch one wave of this host's units WITHOUT waiting — the
+        launches land in the host's in-flight queue and are booked by a
+        later step's harvest, so every other host's placement, stealing,
+        and booking overlaps this mesh's execution."""
+        with obs.span("topology.wave", host=host_id) as sp:
+            host = self.topology.hosts[host_id]
+            # a zero byte budget means "pool off" (PoolConfig contract):
+            # fall back to host page stacking instead of churning an
+            # always-evicting device pool
+            host_pages = host.pool if host.pool.byte_budget > 0 else None
+            lane = state.info.topology.hosts[host_id]
+            q = state.queues[host_id]
+            book = lambda pb, res, el: self._book_harvest(state, pb, res,
+                                                          el)
+            capacity = self._wave_capacity(state, host_id, mine, units)
+            # fill the wave unit-by-unit, truncating the last unit to the
+            # remaining capacity; each selection takes at least one
+            # invocation, so a wave always makes progress.  A bucket's
+            # selected units dispatch together, so they fuse
+            selected: Dict[object, List] = {}
+            taken = 0
+            for u in mine:
+                if taken >= capacity and selected:
+                    break
+                ents = units[u][:max(capacity - taken, 1)]
+                selected.setdefault(u[0], []).extend(ents)
+                taken += len(ents)
+            for key, ents in selected.items():
+                running: Dict[int, List[int]] = {}
+                for ri, inv in ents:
+                    running.setdefault(ri, []).append(inv)
+                for ri, invs in running.items():
+                    state.requests[ri].ledger.mark_running(invs)
+                decision = self._plan_host_axis(state, key, ents, host_id)
+                compiler, b_align, axis_mesh = self._bucket_compiler(
+                    host_id, decision)
+                opts = dict(self._dispatch_opts())
+                # fusion follows the *chosen* cache, not the shared one: a
+                # host's sharded-fused cache fuses, a partition-only cache
+                # would not (compile/program.py gate)
+                opts["fuse"] = self.pool.fuse and (
+                    compiler.partition is None
+                    or compiler.partition_fused is not None)
+                bd = _compile().dispatch_bucket(
+                    state.plan, compiler, key, ents, pages=host_pages,
+                    b_align=b_align, axis_decision=decision, mesh=axis_mesh,
+                    **opts)
+                self._push_bucket(state, q, bd, book, host=host_id)
+                state.seen_buckets.add(key)
+            sp.set(invocations=taken)
         lane.waves += 1
         lane.invocations += taken
         state.info.waves += 1
@@ -539,7 +599,7 @@ class TopologyBackend(_StreamBackend):
         abandoned (sole abandon performer — their ledger rows stay
         RUNNING, so once the dead queue stops shadowing them the
         pending view resurfaces exactly the orphaned invocations), and
-        its bucket assignments are cleared so ``_route`` re-places them
+        its unit assignments are cleared so ``_route`` re-places them
         on the survivors, whose pools re-materialize any orphaned pages
         on first touch.  Returns the number of abandoned buckets."""
         topo = self.topology
@@ -548,9 +608,8 @@ class TopologyBackend(_StreamBackend):
         topo.kill(host_id)
         q = state.queues.pop(host_id, None)
         orphans = q.abandon() if q is not None else []
-        for key in [k for k, h in state.assignment.items()
-                    if h == host_id]:
-            del state.assignment[key]
+        for u in [u for u, h in state.assignment.items() if h == host_id]:
+            del state.assignment[u]
         info = state.info.topology
         info.host_losses += 1
         info.lost_buckets += len(orphans)
@@ -606,15 +665,16 @@ class TopologyBackend(_StreamBackend):
                 time.sleep(min(gate_wait, 0.05))
                 return True
             return False
-        self._route(state, groups)      # retries may resurface buckets
+        units = self._units(groups)
+        by_host = self._route(state, units)   # retries may resurface units
         for off in range(n):
             h = ids[(state.cursor + off) % n]
-            mine = [k for k in groups if state.assignment[k] == h]
+            mine = by_host.get(h, [])
             if not mine and self.pool.steal:
-                mine = self._try_steal(state, groups, h)
+                mine = self._try_steal(state, units, by_host, h)
             if not mine:
                 continue
-            self._host_wave(state, h, mine, groups)
+            self._host_wave(state, h, mine, units)
             state.cursor = (state.cursor + off + 1) % n
             return True
         return False

@@ -1,13 +1,14 @@
 """Named sharding-policy variants for §Perf hillclimbing, plus the
-topology layer's bucket→host placement policy (ISSUE 4).
+topology layer's placement policy: which host runs each unit of work.
 
 A variant = (rules transform, model-build overrides).  The dry-run CLI takes
 ``--variant NAME`` so a hypothesis is one flag away from its measurement; the
 baseline tables always use ``default``.
 
-Placement: ``place_bucket`` scores one megabatch bucket against every
-host's page-pool residency — stack-cached beats pages-resident beats
-cold — and ``steal_choice`` picks what an idle host takes from the most
+Placement: the unit is a request's pending invocations in one megabatch
+bucket.  ``place_unit`` scores a unit's page against every host's
+page-pool residency — stack-cached beats pages-resident beats cold —
+and ``steal_choice`` picks the units an idle host takes from the most
 loaded one.  Both are pure functions of the observed pools/queues, so a
 drain's routing is reproducible; and because per-task PRNG is fixed at
 compile time, no placement they produce can move an estimate.
@@ -89,16 +90,13 @@ def megabatch_specs(batch_axis: str = "data",
 
 
 # ---------------------------------------------------------------------------
-# Bucket -> host placement (topology layer)
+# Unit -> host placement (topology layer)
 # ---------------------------------------------------------------------------
 @dataclass(frozen=True)
-class BucketPlacement:
-    """One routing decision plus the residency evidence it came from."""
+class UnitPlacement:
+    """One routing decision and the residency evidence it came from."""
     host: int
-    score: float                        # mean page points in [0, 2]
-    resident: int                       # pages of this bucket already held
-    total: int                          # pages the bucket needs
-    stacked: int                        # pages whose launch stack is cached
+    score: float                        # page points of the unit's page
 
 
 def _page_points(pool, pk) -> float:
@@ -114,57 +112,59 @@ def _page_points(pool, pk) -> float:
     return 0.0
 
 
-def place_bucket(pkeys: Sequence, pools: Sequence,
-                 loads: Sequence[int]) -> BucketPlacement:
-    """Route one bucket to the host best positioned to run it.
+def place_unit(pkey, pools: Sequence,
+               loads: Sequence[int]) -> UnitPlacement:
+    """Route one unit — a request's pending invocations in one bucket —
+    to the host best positioned to run it.
 
-    ``pkeys`` are the bucket's page keys (one per request in it),
-    ``pools`` the per-host PagePools, ``loads`` each host's currently
-    queued invocation count.  Score = mean per-page locality points
+    ``pkey`` is the unit's page key (the request's data at the bucket's
+    shape), ``pools`` the per-host PagePools, ``loads`` each host's
+    queued invocation count.  Score = the page's locality points
     (stack-cached > resident > cold); ties break to the least-loaded
     host, then the lowest host id — fully deterministic.
     """
-    lane_keys = tuple(dict.fromkeys(pkeys))       # dedup, keep order
-    total = max(len(lane_keys), 1)
     best = None
     for hid, pool in enumerate(pools):
-        resident = sum(1 for pk in lane_keys if pool.resident(pk))
-        stacked = sum(1 for pk in lane_keys if pool.stack_cached((pk,)))
-        score = sum(_page_points(pool, pk) for pk in lane_keys) / total
+        score = _page_points(pool, pkey)
         rank = (-score, loads[hid], hid)
-        cand = BucketPlacement(host=hid, score=score, resident=resident,
-                               total=total, stacked=stacked)
         if best is None or rank < best[0]:
-            best = (rank, cand)
+            best = (rank, UnitPlacement(host=hid, score=score))
     return best[1]
 
 
 def steal_choice(queues: Dict[int, List], pools: Sequence,
-                 pkeys_of: Callable[[object], Sequence]) \
-        -> Optional[Tuple[int, object]]:
+                 pkey_of: Callable[[object], object],
+                 size_of: Callable[[object], int]) \
+        -> Optional[Tuple[int, List]]:
     """What an idle host steals: from the donor with the most queued
-    buckets (only if it has more than one — never strand a host's last
-    bucket mid-flight), take the bucket *least* local to the donor, so
-    the migrated residency costs the donor the least.  Returns
-    ``(donor_host, bucket_key)`` or None when no steal is worthwhile.
+    invocations among those holding more than one unit (a host's last
+    unit is never taken — the thief would only trade places with it),
+    the units *least* local to the donor, so the migrated residency
+    costs the donor the least, until the thief holds half the donor's
+    queue.  Returns ``(donor_host, units)`` or None when no steal is
+    worthwhile.  ``queues`` maps a host to its not-yet-dispatched units
+    in queue order; ``size_of`` is a unit's invocation count.
     """
+    load = {hid: sum(size_of(u) for u in units)
+            for hid, units in queues.items()}
     donor = None
-    for hid, keys in sorted(queues.items()):
-        if len(keys) > 1 and (donor is None
-                              or len(keys) > len(queues[donor])):
+    for hid, units in sorted(queues.items()):
+        if len(units) > 1 and (donor is None or load[hid] > load[donor]):
             donor = hid
     if donor is None:
         return None
     pool = pools[donor]
-
-    def locality(key):
-        lane_keys = tuple(dict.fromkeys(pkeys_of(key)))
-        return sum(_page_points(pool, pk) for pk in lane_keys) \
-            / max(len(lane_keys), 1)
-
-    # min() is stable: the first enqueued among equally-cold buckets wins
-    victim = min(queues[donor], key=locality)
-    return donor, victim
+    # sorted() is stable: the first enqueued among equally-cold units
+    # goes first
+    order = sorted(queues[donor],
+                   key=lambda u: _page_points(pool, pkey_of(u)))
+    taken, stolen = 0, []
+    for u in order[:-1]:
+        stolen.append(u)
+        taken += size_of(u)
+        if 2 * taken >= load[donor]:
+            break
+    return donor, stolen
 
 
 def apply_variant(arch_name: str, shape_kind: str, d_model: int,

@@ -228,5 +228,5 @@ def test_topology_routes_tall_buckets_to_streaming_hosts(monkeypatch):
     assert all(d.executed == "data" for d in state.info.axis_plans
                if d.axis == "data")
     # every placement respected the bucket's eligible-host set
-    for key, host, _ in state.info.topology.placements:
+    for key, _, host, _ in state.info.topology.placements:
         assert host in backend._eligible_hosts(key)

@@ -17,9 +17,12 @@ TREE = {
     "session.poll": {"session.admit", "backend.step", "session.harvest"},
     "session.admit": {"session.compile_request", "planner.admit"},
     "backend.step": {"planner.fill", "program.dispatch",
-                     "dispatch.harvest"},
+                     "dispatch.harvest", "topology.route",
+                     "topology.steal", "topology.wave"},
+    "topology.wave": {"program.dispatch"},
     "program.dispatch": {"program.stage", "program.build",
                          "program.launch"},
+    "program.stage": {"pages.fetch"},
     "dispatch.harvest": {"program.harvest", "ledger.book"},
     "program.harvest": {"program.wait"},
     "session.harvest": {"session.assemble"},
@@ -186,6 +189,11 @@ def test_every_backend_steps_inside_spans(backend):
     for name in ("backend.step", "planner.fill", "program.launch",
                  "ledger.book", "session.assemble"):
         assert by.get(name), name
-    assert all(spans[s.parent].name == "backend.step"
+    # the topology backend dispatches inside one host's wave
+    dispatcher = "topology.wave" if backend == "topology" \
+        else "backend.step"
+    assert all(spans[s.parent].name == dispatcher
                for s in by["program.dispatch"])
+    assert all(spans[s.parent].name == "backend.step"
+               for s in by.get("topology.wave", []))
     assert sorted(s.rid for s in by["session.assemble"]) == rids

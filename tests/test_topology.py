@@ -1,5 +1,6 @@
-"""Topology-aware drain (ISSUE 4 tentpole): bucket→host placement
-follows page residency, idle hosts steal work, per-mesh streams step
+"""Topology-aware drain: the placement of each request's share of a
+bucket follows page residency, one bucket of fresh-data requests
+spreads over every host, idle hosts steal work, per-mesh streams step
 round-robin from the session's event loop, the autoscaler prices each
 host's waves with roofline FLOP estimates, and the whole thing is
 bitwise-identical to the single-host inline drain for every learner
@@ -10,10 +11,15 @@ CI additionally runs this file under
 multihost-smoke job), where each simulated host's page pool pins pages
 to a distinct device; on a single-device run the hosts share the device
 but keep disjoint pools, so every assertion below still holds."""
+import importlib.util
+from collections import Counter
+from pathlib import Path
+
 import jax
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.compile import PagePool, plan_buckets
 from repro.core import DMLData, DMLPlan, DMLSession
 from repro.core.session import compile_request
@@ -21,7 +27,11 @@ from repro.data import make_irm_data, make_plr_data
 from repro.serverless import (
     InlineBackend, PoolConfig, Topology, TopologyBackend,
 )
-from repro.sharding.policy import place_bucket, steal_choice
+from repro.sharding.policy import place_unit, steal_choice
+
+# the chip benchmark's plain float64 reference, independent of repro
+_REF_PATH = Path(__file__).resolve().parents[1] / "benchmarks" / "chip" \
+    / "references" / "plr.py"
 
 
 def _plr(n_obs, seed, *, learner="ridge", learner_params=None, n_rep=2,
@@ -93,7 +103,8 @@ def test_routing_follows_page_residency():
     for plan, data in cases:
         sess.submit(plan, data)
     sess.run()                                      # warmup: cold placement
-    cold = {key: host for key, host, _ in sess.topology_info.placements}
+    cold = {(key, ri): host
+            for key, ri, host, _ in sess.topology_info.placements}
     topo = sess.backend.topology
     warm0 = topo.page_stats().snapshot()
     fetches0 = topo.directory.fetches
@@ -101,7 +112,7 @@ def test_routing_follows_page_residency():
         for plan, data in cases:
             sess.submit(plan, data)
         sess.run()
-        warm = {key: host for key, host, _
+        warm = {(key, ri): host for key, ri, host, _
                 in sess.topology_info.placements}
         assert warm == cold                         # residency-stable routes
     d = topo.page_stats().delta(warm0)
@@ -109,7 +120,7 @@ def test_routing_follows_page_residency():
     assert d.hit_rate == 1.0
     assert topo.directory.fetches == fetches0      # no cross-host traffic
     # warm placements scored resident (>0), cold ones didn't
-    assert all(s > 0 for _, _, s in sess.topology_info.placements)
+    assert all(s > 0 for *_, s in sess.topology_info.placements)
 
 
 def test_place_bucket_scoring_and_determinism():
@@ -129,13 +140,13 @@ def test_place_bucket_scoring_and_determinism():
     cold = FakePool()
     resident = FakePool(pages=[pk])
     stacked = FakePool(pages=[pk], stacks=[(pk,)])
-    p = place_bucket([pk], [cold, resident, stacked], loads=[0, 0, 0])
-    assert p.host == 2 and p.stacked == 1 and p.score == 2.0
-    p = place_bucket([pk], [cold, resident], loads=[0, 100])
+    p = place_unit(pk, [cold, resident, stacked], loads=[0, 0, 0])
+    assert p.host == 2 and p.score == 2.0
+    p = place_unit(pk, [cold, resident], loads=[0, 100])
     assert p.host == 1 and p.score == 1.0   # residency outweighs load
-    p = place_bucket([pk], [cold, cold], loads=[5, 3])
-    assert p.host == 1                  # cold tie -> least loaded
-    p = place_bucket([pk], [cold, cold], loads=[3, 3])
+    p = place_unit(pk, [cold, cold], loads=[5, 3])
+    assert p.host == 1 and p.score == 0.0   # cold tie -> least loaded
+    p = place_unit(pk, [cold, cold], loads=[3, 3])
     assert p.host == 0                  # full tie -> lowest id
 
 
@@ -150,13 +161,22 @@ def test_steal_choice_picks_least_local_from_most_loaded():
         def stack_cached(self, pkeys):
             return False
 
-    pools = [FakePool(pages=["a"]), FakePool()]
+    pools = [FakePool(pages=["a"]), FakePool(), FakePool()]
+    one = lambda u: 1
     queues = {0: ["ka", "kb", "kc"]}
     pick = steal_choice(queues, pools,
-                        lambda k: ["a"] if k == "ka" else [k])
-    assert pick == (0, "kb")            # kb/kc cold on donor; kb first
-    assert steal_choice({0: ["ka"]}, pools, lambda k: [k]) is None
-    assert steal_choice({}, pools, lambda k: [k]) is None
+                        lambda u: "a" if u == "ka" else u, one)
+    # kb/kc cold on the donor go first, until the thief holds half
+    assert pick == (0, ["kb", "kc"])
+    # never the donor's last unit, however large
+    assert steal_choice({0: ["ka"]}, pools, lambda u: u,
+                        lambda u: 9) is None
+    assert steal_choice({}, pools, lambda u: u, one) is None
+    # the donor is the host with the most queued invocations, not units
+    sizes = {"a1": 1, "a2": 1, "a3": 1, "b1": 4, "b2": 4}
+    pick = steal_choice({1: ["a1", "a2", "a3"], 2: ["b1", "b2"]}, pools,
+                        lambda u: u, sizes.__getitem__)
+    assert pick == (2, ["b1"])
 
 
 # ---------------------------------------------------------------------------
@@ -193,7 +213,7 @@ def test_work_stealing_triggers_on_idle_host():
     info = backend.run_requests(reqs)
     t = info.topology
     # every bucket was *placed* on the resident host...
-    assert all(host == 0 for _, host, _ in t.placements)
+    assert all(host == 0 for _, _, host, _ in t.placements)
     # ...so the idle second host stole some of the queue
     assert t.steals >= 1
     assert t.hosts[1].steals >= 1 and t.hosts[1].waves >= 1
@@ -348,3 +368,181 @@ def test_roofline_task_models_scale_sanely():
         assert 0 < small < big
     assert invocation_roofline_s("ridge", {}, 6, 128, 8) == \
         2 * invocation_roofline_s("ridge", {}, 3, 128, 8)
+
+
+# ---------------------------------------------------------------------------
+# one bucket of fresh-data requests over four hosts (the coverage study)
+# ---------------------------------------------------------------------------
+# θ and SE against the float64 reference, in units of SE_ref and
+# relative: float32 Gram sums and solves at N=64 land within ~1e-6 of
+# float64; the tolerance leaves room for BLAS summation order, while a
+# wrong fold, a wrong nuisance or a dropped repetition moves θ by a
+# sizeable fraction of its SE
+REF_TOL = 1e-4
+
+
+def _reference():
+    spec = importlib.util.spec_from_file_location("plr_reference", _REF_PATH)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _study(n_requests=16, n_obs=64, dim_x=6, n_folds=5):
+    """A coverage study's replications: one PLR ridge request per fresh
+    dataset, all in one bucket (M=1 at the ``n_rep`` scaling level, as
+    the plr_mc500 deployment runs them)."""
+    cases = []
+    for i in range(n_requests):
+        data = DMLData.from_dict(make_plr_data(n_obs=n_obs, dim_x=dim_x,
+                                               theta=0.5, seed=1000 + i))
+        plan = DMLPlan.for_model("plr", learner="ridge",
+                                 learner_params={"reg": 1.0},
+                                 n_folds=n_folds, n_rep=1, seed=2000 + i,
+                                 scaling="n_rep")
+        cases.append((plan, data))
+    return cases
+
+
+def _busiest_share(spans) -> float:
+    """Largest share of the dispatched invocations one host's
+    ``topology.wave`` spans took."""
+    per_host = Counter()
+    for s in spans:
+        if s.name == "topology.wave":
+            per_host[s.args["host"]] += s.args["invocations"]
+    return max(per_host.values()) / sum(per_host.values())
+
+
+def _wave_results(cases):
+    sess = DMLSession(backend="wave")
+    rids = [sess.submit(p, d) for p, d in cases]
+    sess.run()
+    return [sess.result(r) for r in rids]
+
+
+def test_one_bucket_spreads_over_four_hosts():
+    """Sixteen fresh-data requests share one bucket; each is its own
+    unit, so cold placement spreads them over all four hosts.  θ and SE
+    match the float64 reference within REF_TOL and the wave backend
+    bitwise."""
+    cases = _study()
+    sess = DMLSession(backend="topology", pool=PoolConfig(n_hosts=4))
+    rids = [sess.submit(p, d) for p, d in cases]
+    with obs.recording():
+        obs.clear()
+        sess.run()
+        spans = obs.spans()
+    t = sess.topology_info
+    assert len(sess.last_run_info.axis_plans) == 1     # one bucket
+    assert all(h.invocations > 0 for h in t.hosts)
+    assert all(h.units_placed == 4 for h in t.hosts)
+    assert _busiest_share(spans) <= 0.375
+    assert max(h.invocations for h in t.hosts) \
+        / sum(h.invocations for h in t.hosts) == _busiest_share(spans)
+    routes = [s.args for s in spans if s.name == "topology.route"]
+    assert sum(a["units"] for a in routes) == len(cases)
+    assert sum(a["cold"] for a in routes) == len(cases)  # fresh data
+    fetches = [s.args for s in spans if s.name == "pages.fetch"]
+    assert [a["source"] for a in fetches] == ["h2d"] * len(cases)
+    assert sum(a["bytes"] for a in fetches) \
+        == sess.backend.pages.stats.bytes_h2d
+
+    ref = _reference()
+    wave = _wave_results(cases)
+    for rid, (plan, data), w in zip(rids, cases, wave):
+        res = sess.result(rid)
+        assert res.theta == w.theta and res.se == w.se
+        np.testing.assert_array_equal(np.asarray(res.thetas),
+                                      np.asarray(w.thetas))
+        masks = ref.fold_masks(data.n_obs, plan.resampling.n_folds, 1,
+                               plan.resampling.seed)
+        r = ref.reference(np.asarray(data.x), np.asarray(data.y),
+                          np.asarray(data.d), masks, 1.0)
+        assert abs(res.theta - r["theta"]) <= REF_TOL * r["se"]
+        assert abs(res.se - r["se"]) <= REF_TOL * r["se"]
+
+
+def test_steal_moves_requests_out_of_single_bucket_donor():
+    """Every request of one bucket is resident on host 0, so all are
+    placed there; the idle host 1 steals some of them — a single-bucket
+    donor still gives up work — and their pages cross device-to-device.
+    Stealing moves no estimate."""
+    cases = _study(n_requests=8)
+    backend = TopologyBackend(PoolConfig(n_hosts=2, n_workers=1,
+                                         memory_mb=256))
+    _seed_host0_residency(backend, cases)
+    reqs = [compile_request(p, d) for p, d in cases]
+    with obs.recording():
+        obs.clear()
+        info = backend.run_requests(reqs)
+        spans = obs.spans()
+    t = info.topology
+    assert len({key for key, *_ in t.placements}) == 1
+    assert all(host == 0 for _, _, host, _ in t.placements)
+    assert t.steals >= 1 and t.hosts[1].steals == t.steals
+    assert t.hosts[1].invocations > 0
+    steals = [s.args for s in spans
+              if s.name == "topology.steal" and s.args["donor"] >= 0]
+    assert steals and all(a["donor"] == 0 and a["thief"] == 1
+                          for a in steals)
+    # host 1 placed nothing: it ran exactly what it stole
+    assert sum(a["invocations"] for a in steals) == t.hosts[1].invocations
+    d2d = [s.args for s in spans
+           if s.name == "pages.fetch" and s.args["source"] == "d2d"]
+    assert len(d2d) == backend.topology.directory.fetches >= 1
+    assert sum(a["bytes"] for a in d2d) \
+        == backend.pages.stats.bytes_d2d > 0
+    for req, (plan, data) in zip(reqs, cases):
+        ref = compile_request(plan, data)
+        InlineBackend().run_requests([ref])
+        np.testing.assert_array_equal(req.gathered_preds(),
+                                      ref.gathered_preds())
+
+
+def test_kill_host_replaces_its_units():
+    """Losing host 0 with work in flight: its units leave the
+    assignment, are placed again on the survivors, and every request of
+    the bucket completes bitwise as on the wave backend."""
+    cases = _study()
+    sess = DMLSession(backend="topology",
+                      pool=PoolConfig(n_hosts=4, n_workers=1,
+                                      memory_mb=256))
+    rids = [sess.submit(p, d) for p, d in cases]
+    backend = sess.backend
+    killed_at = None
+    for _ in range(400):
+        sess.poll()
+        state = sess._state
+        if state is None:
+            break
+        q = state.queues.get(0)
+        if q is not None and q.in_flight > 0:
+            assert backend.kill_host(state, 0) > 0
+            assert 0 not in state.assignment.values()
+            killed_at = len(state.info.topology.placements)
+            break
+    assert killed_at is not None, "host 0 never held work in flight"
+    sess.run()
+    t = sess.topology_info
+    replaced = t.placements[killed_at:]
+    assert replaced and all(host != 0 for _, _, host, _ in replaced)
+    for rid, w in zip(rids, _wave_results(cases)):
+        res = sess.result(rid)
+        assert res.theta == w.theta and res.se == w.se
+
+
+def test_topology_pages_stats_are_cluster_wide():
+    """``backend.pages.stats`` on the topology backend is the sum over
+    the host pools, as on a single-stream backend it is its one pool's,
+    and the drain's ``last_run_info.pages`` agrees with it."""
+    cases = _study(n_requests=8)
+    sess = DMLSession(backend="topology", pool=PoolConfig(n_hosts=4))
+    for plan, data in cases:
+        sess.submit(plan, data)
+    sess.run()
+    stats = sess.backend.pages.stats
+    per_host = [h.pool.stats for h in sess.backend.topology.hosts]
+    assert stats.bytes_h2d == sum(s.bytes_h2d for s in per_host) > 0
+    assert stats.bytes_h2d == 8 * 64 * 8 * 4      # one page a dataset
+    assert sess.last_run_info.pages == stats
