@@ -24,10 +24,11 @@ The protocol (one bucket slice's life):
     the synchronous ``run_bucket`` wrapper) may harvest;
   * only the two booking functions may write ledger results — booking
     anywhere else would bypass billing, retry, and finalization;
-  * schedulers must view pending work through
-    ``pending_by_bucket(exclude=<in-flight>)`` so an invocation whose
-    launch is on device is never dispatched twice (the one allowlisted
-    exception is a pricing thunk that runs while the queue is empty);
+  * the plan's pending view takes dispatched invocations out and brings
+    them back only when told what is still in flight: every
+    ``sync(exclude=<in-flight>)`` and ``pending_by_bucket(exclude=...)``
+    names that set, so an invocation whose launch is on device is never
+    dispatched twice;
   * a hedge race is settled by exactly ONE performer
     (``HedgePair.settle``): the winning leg books, the loser is
     cancelled and discarded through the same harvest-once flag, so no
@@ -134,12 +135,9 @@ ABANDON_PERFORMERS: FrozenSet[Tuple[str, str]] = frozenset({
     ("serverless/topology.py", "TopologyBackend.kill_host"),
 })
 
-#: call sites allowed to view pending work WITHOUT excluding in-flight
-#: entries — only the wave autoscaler's roofline pricing thunk, which
-#: runs strictly between harvest_all and the next dispatch (queue empty)
-PENDING_VIEW_EXEMPT: FrozenSet[Tuple[str, str]] = frozenset({
-    ("serverless/backends.py", "WaveBackend._wave_workers"),
-})
+#: the calls that bring dispatched invocations back into the pending
+#: view: each must name the caller's in-flight entries
+_PENDING_SYNCS = ("sync", "pending_by_bucket")
 
 #: files whose dispatch_bucket calls must be preceded by mark_running in
 #: the same function (the compiler's own synchronous run_bucket wrapper
@@ -194,18 +192,19 @@ def _check_file(rel: str, tree: ast.Module) -> List[Finding]:
                     "kill_host may abandon a queue; anywhere else drops "
                     "in-flight work without re-dispatch bookkeeping"))
 
-    # pending_by_bucket(exclude=...) — never re-dispatch in-flight work
+    # a pending-view sync names the in-flight entries — never
+    # re-dispatch in-flight work
     for qual, fn in astutil.iter_functions(tree):
         for lineno, callee, node in astutil.calls_in(fn):
-            if _last(callee) != "pending_by_bucket":
+            if "." not in callee or _last(callee) not in _PENDING_SYNCS:
                 continue
             has_exclude = any(kw.arg == "exclude" for kw in node.keywords) \
                 or len(node.args) >= 1
-            if not has_exclude and (rel, qual) not in PENDING_VIEW_EXEMPT:
+            if not has_exclude:
                 findings.append(Finding(
                     "protocol", "pending-view-excludes-in-flight",
                     f"{rel}:{lineno}",
-                    f"{qual} calls pending_by_bucket() without "
+                    f"{qual} calls {_last(callee)}() without "
                     "exclude= — dispatched-but-unharvested invocations "
                     "would be dispatched twice"))
 

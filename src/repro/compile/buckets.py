@@ -33,8 +33,9 @@ program cache live in program.py.
 """
 from __future__ import annotations
 
+import bisect
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -55,9 +56,199 @@ class BucketKey:
 
 
 @dataclass
+class _Slot:
+    """One live request of a ``PendingView``: its ledger, and the bucket
+    of each of its invocations (an index into ``keys``)."""
+    ledger: object
+    kidx: List[int]
+    keys: List[BucketKey]
+
+
+class PendingView:
+    """The dispatchable invocations of a live drain, kept as the drain
+    runs instead of rebuilt from every ledger at every step.
+
+    A request slot enters at admission with its not-DONE invocations,
+    grouped by bucket in ascending order, and leaves when its ledger
+    completes (``retire``).  ``take`` moves dispatched invocations out;
+    ``sync`` brings back each one that is neither in flight nor held by a
+    retry gate and is still owed: a failed booking, a hedge's discarded
+    loser, a lost host's orphans, a matured gate.  So a read costs what
+    the live requests hold, never what the drain has completed.
+
+    Order is the drain's contract: buckets by their first (request slot,
+    invocation), entries by slot then invocation.  That is the order a
+    scan over every request's ledger gives, so the waves, launches and
+    padding do not depend on how the view is kept.
+    """
+
+    def __init__(self):
+        self._slots: Dict[int, _Slot] = {}
+        # bucket -> slot -> its dispatchable invocations, ascending;
+        # slots in admission (= ascending) order
+        self._holders: Dict[BucketKey, Dict[int, List[int]]] = {}
+        self._count: Dict[BucketKey, int] = {}
+        self._out: set = set()          # taken, not yet back or finished
+        self._gated: List[Entry] = []   # owed but gated at the last sync
+        self.scanned = 0                # lists and entries the last read saw
+
+    @property
+    def live(self) -> int:
+        """Request slots that still owe invocations."""
+        return len(self._slots)
+
+    @property
+    def depth(self) -> int:
+        """Dispatchable invocations over every live slot."""
+        return sum(self._count.values())
+
+    def admit(self, ri: int, ledger, kidx: np.ndarray,
+              keys: List[BucketKey]) -> None:
+        owed = ledger.pending()
+        if not len(owed):
+            return
+        self._slots[ri] = _Slot(ledger, kidx.tolist(), keys)
+        for k, key in enumerate(keys):
+            invs = owed[kidx[owed] == k].tolist()
+            if invs:
+                self._holders.setdefault(key, {})[ri] = invs
+                self._count[key] = self._count.get(key, 0) + len(invs)
+
+    def retire(self, ri: int) -> None:
+        """Drop a slot whose ledger completed."""
+        slot = self._slots.pop(ri, None)
+        if slot is None:
+            return
+        for key in slot.keys:
+            hold = self._holders.get(key)
+            if hold is None or ri not in hold:
+                continue
+            self._count[key] -= len(hold.pop(ri))
+            if not hold:
+                del self._holders[key], self._count[key]
+
+    def take(self, ri: int, invs: Sequence[int]) -> None:
+        """Move dispatched invocations of one slot out of the view (a
+        hedge's duplicate takes nothing: its entries are out already)."""
+        slot = self._slots.get(ri)
+        if slot is None:
+            return
+        fresh = [int(i) for i in invs if (ri, int(i)) not in self._out]
+        by_key: Dict[int, List[int]] = {}
+        for inv in fresh:
+            by_key.setdefault(slot.kidx[inv], []).append(inv)
+            self._out.add((ri, inv))
+        for k, ins in by_key.items():
+            key = slot.keys[k]
+            invs_k = self._holders[key][ri]
+            n0 = len(invs_k)
+            if invs_k[:len(ins)] == ins:        # a fill takes a prefix
+                del invs_k[:len(ins)]
+            else:
+                drop = set(ins)
+                invs_k[:] = [i for i in invs_k if i not in drop]
+            self._count[key] -= n0 - len(invs_k)
+
+    def sync(self, *, exclude, gated=None) -> None:
+        """Bring back every taken invocation that is not in ``exclude``
+        (the caller's in-flight entries), not held by a ``gated`` retry
+        gate and still owed.  Leaving an in-flight entry out of
+        ``exclude`` would dispatch it twice."""
+        exclude = exclude or ()
+        gated = gated or ()
+        self._gated = []
+        for e in list(self._out):
+            if e in exclude:
+                continue
+            ri, inv = e
+            slot = self._slots.get(ri)
+            if slot is None or not slot.ledger.owes(inv):
+                self._out.remove(e)
+            elif e in gated:
+                self._gated.append(e)
+            else:
+                self._out.remove(e)
+                key = slot.keys[slot.kidx[inv]]
+                bisect.insort(self._holders[key][ri], inv)
+                self._count[key] += 1
+
+    def _order(self, extra: Sequence[Entry] = ()) -> List[BucketKey]:
+        """Buckets with dispatchable entries, by their first (slot,
+        invocation); ``extra`` entries count toward that first one."""
+        first: Dict[BucketKey, Entry] = {}
+        for key, hold in self._holders.items():
+            if not self._count[key]:
+                continue
+            for ri, invs in hold.items():
+                self.scanned += 1
+                if invs:
+                    first[key] = (ri, invs[0])
+                    break
+        for ri, inv in extra:
+            slot = self._slots[ri]
+            key = slot.keys[slot.kidx[inv]]
+            if key in first and (ri, inv) < first[key]:
+                first[key] = (ri, inv)
+        return sorted(first, key=first.__getitem__)
+
+    def _stream(self, key: BucketKey) -> Iterator[Entry]:
+        for ri, invs in self._holders[key].items():
+            self.scanned += 1
+            for inv in invs:
+                self.scanned += 1
+                yield ri, inv
+
+    def groups(self) -> Dict[BucketKey, List[Entry]]:
+        """Every dispatchable entry, by bucket.  A bucket is placed as if
+        its gated entries were still there (the backoff used to filter
+        them out of this grouping, not before it)."""
+        self.scanned = 0
+        return {key: list(self._stream(key))
+                for key in self._order(self._gated)}
+
+    def fill(self, capacity: int) -> List[Entry]:
+        """One wave of at most ``capacity`` entries, in whole-bucket units.
+
+        Round-robin admission is fair but fragments a bucket's canonical
+        tail blocks across waves: a 24-lane bucket cut 6/18 by the
+        capacity limit pads to 8 + 24 lanes instead of one 24-lane
+        launch.  So a bucket small enough to ever travel whole is taken
+        whole, in bucket order, or deferred to the next wave; only
+        buckets larger than a full wave are split, and those split
+        round-robin across each other so concurrent oversize requests
+        still share dispatch cycles.  The counts decide; only the
+        entries taken are read."""
+        with obs.span("planner.fill") as sp:
+            self.scanned = 0
+            batch: List[Entry] = []
+            oversized: List[Iterator[Entry]] = []
+            for key in self._order():
+                n = self._count[key]
+                if n > capacity:
+                    oversized.append(self._stream(key))
+                elif n <= capacity - len(batch):
+                    batch.extend(self._stream(key))
+                # else: whole-bucket sized but no room left — defer intact
+            while len(batch) < capacity and oversized:
+                for it in oversized:
+                    if len(batch) < capacity:
+                        batch.append(next(it))
+            sp.set(entries=len(batch), live=self.live,
+                   scanned=self.scanned)
+        return batch
+
+    def by_slot(self) -> Iterator[Tuple[int, int]]:
+        """(slot, dispatchable invocations) of each live slot."""
+        for ri, slot in self._slots.items():
+            yield ri, sum(len(self._holders.get(key, {}).get(ri, ()))
+                          for key in slot.keys)
+
+
+@dataclass
 class MegabatchPlan:
     """The lowered view of a stream of requests: every (request, segment)
-    mapped to its bucket, plus lazily-built padded data pages.
+    mapped to its bucket, lazily-built padded data pages, and the live
+    ``PendingView`` the schedulers read.
 
     The plan is **incremental**: ``admit()`` lowers one request at a time,
     so the continuous-admission drain engine can extend a live plan while
@@ -71,14 +262,18 @@ class MegabatchPlan:
     min_p: int = 8
     _pages: Dict[Tuple[int, int, int], np.ndarray] = field(
         default_factory=dict)
+    pending: PendingView = field(default_factory=PendingView)
 
     # ---- continuous admission -------------------------------------------
     def admit(self, req) -> int:
-        """Lower one request into the plan; returns its request index."""
+        """Lower one request into the plan and its owed invocations into
+        the pending view; returns its request index."""
         ri = len(self.requests)
         self.requests.append(req)
         n = int(req.ledger.n_obs)
         p = int(req.x.shape[1])
+        keys: List[BucketKey] = []
+        k_of_seg: List[int] = []
         for si, seg in enumerate(req.segments):
             if seg.learner is None:            # opaque callable: exact shapes
                 n_pad, p_pad = n, p
@@ -94,6 +289,12 @@ class MegabatchPlan:
             # the same batched fn — per-task PRNG streams are looked up
             # via segment_of_inv in run_bucket, never through this map
             self.seg_of.setdefault((ri, key), si)
+            if key not in keys:
+                keys.append(key)
+            k_of_seg.append(keys.index(key))
+        inv_seg = req.segment_of_inv(np.arange(req.ledger.n_invocations))
+        self.pending.admit(ri, req.ledger, np.asarray(k_of_seg)[inv_seg],
+                           keys)
         return ri
 
     # ---- planning shapes -------------------------------------------------
@@ -138,19 +339,22 @@ class MegabatchPlan:
                 groups.setdefault(key, []).append((ri, int(inv)))
         return groups
 
-    def pending_by_bucket(self, exclude=None) -> Dict[BucketKey, List[Entry]]:
-        """Every not-yet-DONE invocation of every request, bucketed.
+    def pending_by_bucket(self, exclude=None, gated=None
+                          ) -> Dict[BucketKey, List[Entry]]:
+        """Every dispatchable invocation of every live request, bucketed
+        (``PendingView.sync`` then ``groups``).
 
         ``exclude`` is the dispatched-but-unharvested entry set of the
-        caller's in-flight queue: those invocations are on device already
-        and must not be re-dispatched while their launch is pending."""
+        caller's in-flight queues: those invocations are on device already
+        and must not be re-dispatched while their launch is pending.
+        ``gated`` holds failed rows until their retry backoff matures."""
         with obs.span("planner.fill") as sp:
-            entries: List[Entry] = []
-            for ri, req in enumerate(self.requests):
-                entries.extend(e for inv in req.ledger.pending()
-                               if (e := (ri, int(inv))) not in (exclude or ()))
-            sp.set(entries=len(entries))
-            return self.group_entries(entries)
+            self.pending.sync(exclude=exclude, gated=gated)
+            groups = self.pending.groups()
+            sp.set(entries=sum(len(e) for e in groups.values()),
+                   live=self.pending.live, scanned=self.pending.scanned)
+            return groups
+
 
 def pack_tail_blocks(lane_counts: Sequence[int], b_block: int,
                      quantum: int = 8, b_align: int = 1,

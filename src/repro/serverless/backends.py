@@ -509,6 +509,16 @@ class DrainState:
     def requests(self) -> List[WorkRequest]:
         return self.plan.requests
 
+    def mark_running(self, entries) -> None:
+        """Flag dispatched (slot, invocation) entries RUNNING in their
+        ledgers and take them out of the plan's pending view."""
+        by_req: Dict[int, List[int]] = {}
+        for ri, inv in entries:
+            by_req.setdefault(ri, []).append(inv)
+        for ri, invs in by_req.items():
+            self.requests[ri].ledger.mark_running(invs)
+            self.plan.pending.take(ri, invs)
+
 
 # ---------------------------------------------------------------------------
 # helpers shared by backends
@@ -635,19 +645,22 @@ class _StreamBackend:
         if not req.ledger.complete:
             return
         state.finalized.add(ri)
+        state.plan.pending.retire(ri)
         if self.pool.simulate:
             req.report.fit_time_s = (req.report.response_time_s
                                      + self.pool.dispatch_overhead_s)
 
-    def _checkpoint(self, state: DrainState):
-        for req in state.requests:
-            req.ledger.checkpoint()      # durable sessions bind a path
-        if not self.pool.checkpoint_path:
-            return
-        for i, req in enumerate(state.requests):
-            path = self.pool.checkpoint_path if len(state.requests) == 1 \
-                else f"{self.pool.checkpoint_path}.r{i}"
-            req.ledger.save(path)
+    def _checkpoint(self, state: DrainState, booked):
+        """Persist the ledgers of the ``booked`` slots, the only ones a
+        booking changed (durable sessions bind each ledger a path;
+        ``pool.checkpoint_path`` writes one file per request)."""
+        path = self.pool.checkpoint_path
+        for ri in booked:
+            ledger = state.requests[ri].ledger
+            ledger.checkpoint()
+            if path:
+                ledger.save(path if len(state.requests) == 1
+                            else f"{path}.r{ri}")
 
     def _book_direct(self, state: DrainState, entries, results, wall: float):
         """Record one bucket launch: ledger bookings, billing, retries.
@@ -815,13 +828,9 @@ class _StreamBackend:
         winner's harvest settles the pair (HedgePair.settle, the sole
         cancel performer) and the loser is discarded unbooked."""
         sanitize.check_hedge(pb)
-        running: Dict[int, List[int]] = {}
-        for ri, inv in pb.entries:
-            running.setdefault(ri, []).append(inv)
-        for ri, invs in running.items():
-            # RUNNING -> RUNNING (legal re-mark): a checkpoint taken
-            # mid-race must still re-queue these rows on restart
-            state.requests[ri].ledger.mark_running(invs)
+        # RUNNING -> RUNNING (legal re-mark): a checkpoint taken
+        # mid-race must still re-queue these rows on restart
+        state.mark_running(pb.entries)
         bd = _compile().dispatch_bucket(
             state.plan, compiler, pb.key, list(pb.entries), pages=pages,
             **self._hedge_dispatch_kwargs(state, pb.key, pb.entries),
@@ -836,22 +845,19 @@ class _StreamBackend:
         push_q.push(hpb)
         return hpb
 
-    def _backoff_filter(self, state: DrainState,
-                        entries) -> Tuple[List, Optional[float]]:
-        """Drop entries whose retry gate has not matured; purge matured
-        gates.  Returns (dispatchable entries, seconds until the
-        earliest still-armed gate — None when nothing is gated)."""
+    def _open_gates(self, state: DrainState) -> Optional[float]:
+        """Purge matured retry gates (their rows rejoin the pending view
+        at its next sync); seconds until the earliest gate still armed,
+        None when nothing is gated."""
         if not state.retry_at:
-            return list(entries), None
+            return None
         now = time.perf_counter()
         for e, t in list(state.retry_at.items()):
             if t <= now:
                 del state.retry_at[e]
         if not state.retry_at:
-            return list(entries), None
-        out = [e for e in entries if (e[0], int(e[1])) not in state.retry_at]
-        wait = min(state.retry_at.values()) - now
-        return out, max(wait, 0.0)
+            return None
+        return max(min(state.retry_at.values()) - now, 0.0)
 
 
 class _BucketStreamBackend(_StreamBackend):
@@ -884,7 +890,7 @@ class _BucketStreamBackend(_StreamBackend):
         per_req = self._book_direct(state, pb.entries, results, elapsed)
         if per_req:     # chaos can fail a whole slice — nothing to book
             self._note_wave(state, list(per_req), elapsed)
-        self._checkpoint(state)
+        self._checkpoint(state, {ri for ri, _ in pb.entries})
 
     def _hedge_dispatch_kwargs(self, state: DrainState, bkey,
                                entries) -> Dict:
@@ -898,16 +904,9 @@ class _BucketStreamBackend(_StreamBackend):
         book = lambda pb, res, el: self._book_harvest(state, pb, res, el)
         q.harvest_ready(book)               # opportunistic booking
         self._maybe_hedge(state)
+        gate_wait = self._open_gates(state)
         groups = state.plan.pending_by_bucket(
-            exclude=q.in_flight_entries())
-        gate_wait: Optional[float] = None
-        if groups and state.retry_at:
-            filtered = {}
-            for bkey, entries in groups.items():
-                ents, gate_wait = self._backoff_filter(state, entries)
-                if ents:
-                    filtered[bkey] = ents
-            groups = filtered
+            exclude=q.in_flight_entries(), gated=state.retry_at)
         if not groups:
             if not q.empty and self._hedge_armed(state):
                 # poll instead of blocking: a held straggler leg must
@@ -927,11 +926,7 @@ class _BucketStreamBackend(_StreamBackend):
             return False
         bkey, entries = next(iter(groups.items()))
         decision = self._plan_axis(state, bkey, entries)
-        running: Dict[int, List[int]] = {}
-        for ri, inv in entries:
-            running.setdefault(ri, []).append(inv)
-        for ri, invs in running.items():
-            state.requests[ri].ledger.mark_running(invs)
+        state.mark_running(entries)
         bd = _compile().dispatch_bucket(
             state.plan, self.compiler, bkey, entries,
             b_align=self._b_align(), pages=self.pages,
@@ -1127,17 +1122,18 @@ class WaveBackend(_StreamBackend):
         return self.compiler._programs
 
     # ------------------------------------------------------------------
-    def _wave_workers(self, state: DrainState,
-                      pendings: List[np.ndarray]) -> int:
+    def _wave_workers(self, state: DrainState) -> int:
         pool = self.pool
         if pool.worker_schedule is not None:           # legacy static ramp
             return pool.worker_schedule[
                 min(state.wave, len(pool.worker_schedule) - 1)]
         if self.autoscaler is not None:
-            depth = sum(len(p) for p in pendings)
+            view = state.plan.pending
+            depth = view.depth
             tasks = sum(
-                len(p) * req.grid.tasks_per_invocation(req.scaling)
-                for p, req in zip(pendings, state.requests))
+                n * state.requests[ri].grid.tasks_per_invocation(
+                    state.requests[ri].scaling)
+                for ri, n in view.by_slot())
             # lazy thunk: the autoscaler invokes it only when no
             # higher-priority pricing signal (simulate model, EMA) exists
             decision = self.autoscaler.decide(
@@ -1145,63 +1141,13 @@ class WaveBackend(_StreamBackend):
                 tasks_per_invocation=max(1, tasks // max(depth, 1)),
                 padding_waste=self.compiler.stats.padding.waste_frac,
                 in_flight=state.queue.in_flight if state.queue else 0,
-                # pipelined waves can leave the queue non-empty here, so
-                # the pricing view excludes in-flight entries — they are
-                # occupancy, not dispatchable depth
+                # the pricing view is the synced pending view: in-flight
+                # entries are occupancy, not dispatchable depth
                 roofline_inv_s=lambda: roofline_pending_inv_s(
-                    state.requests, state.plan.pending_by_bucket(
-                        exclude=state.queue.in_flight_entries()
-                        if state.queue else None)))
+                    state.requests, view.groups()))
             state.info.autoscale.append(decision)
             return decision.n_workers
         return pool.n_workers
-
-    def _fill_bucket_coherent(self, state: DrainState,
-                              pendings: List[np.ndarray],
-                              capacity: int) -> List[_Entry]:
-        """Fill a pipelined wave in whole-bucket units.
-
-        Round-robin admission is fair but fragments a bucket's canonical
-        tail blocks across waves: a 24-lane bucket cut 6/18 by the
-        capacity limit pads to 8 + 24 lanes instead of one 24-lane
-        launch — the steady-state padding waste the asyncdrain bench
-        gates on.  So buckets small enough to ever travel whole are
-        taken whole (in round-robin first-appearance order) or deferred
-        to the next wave; only buckets larger than a full wave are
-        split, and those split round-robin across each other so
-        concurrent oversize requests still share dispatch cycles."""
-        rr: List[_Entry] = []
-        cursors = [0] * len(pendings)
-        while True:
-            progressed = False
-            for ri, p in enumerate(pendings):
-                if cursors[ri] < len(p):
-                    rr.append(_Entry(ri, int(p[cursors[ri]])))
-                    cursors[ri] += 1
-                    progressed = True
-            if not progressed:
-                break
-        groups = state.plan.group_entries([(e.req_idx, e.inv) for e in rr])
-        batch: List[_Entry] = []
-        oversized: List[List[Tuple[int, int]]] = []
-        for ents in groups.values():           # first-appearance order
-            if len(ents) > capacity:
-                oversized.append(ents)         # can never travel whole
-            elif len(ents) <= capacity - len(batch):
-                batch.extend(_Entry(ri, inv) for ri, inv in ents)
-            # else: whole-bucket sized but no room left — defer intact
-        cur = [0] * len(oversized)
-        while len(batch) < capacity:
-            progressed = False
-            for gi, ents in enumerate(oversized):
-                if cur[gi] < len(ents) and len(batch) < capacity:
-                    ri, inv = ents[cur[gi]]
-                    batch.append(_Entry(ri, inv))
-                    cur[gi] += 1
-                    progressed = True
-            if not progressed:
-                break
-        return batch
 
     @traced_step
     def step(self, state: DrainState) -> bool:
@@ -1220,31 +1166,19 @@ class WaveBackend(_StreamBackend):
         # duplicate-dispatch anything overdue
         q.harvest_ready()
         self._maybe_hedge(state)
-        # ledger.pending() includes RUNNING rows, so the wave fill
-        # must exclude every entry still in flight: on the queue OR
-        # in an unsettled wave latch — a harvested bucket leaves the
-        # queue before its wave settles (and books), and re-dispatching
-        # its rows would double-book them.  Failed rows under backoff
-        # stay out until their retry gate matures.
+        # a dispatched entry rejoins the pending view only once it is
+        # out of flight: off the queue AND out of every unsettled wave
+        # latch — a harvested bucket leaves the queue before its wave
+        # settles (and books), and re-dispatching its rows would
+        # double-book them.  Failed rows under backoff stay out until
+        # their retry gate matures.
         inflight = q.in_flight_entries()
         for latch in state.waves_inflight:
             inflight.update((e.req_idx, e.inv) for e in latch.dispatch)
-        gate_wait: Optional[float] = None
-        gated: set = set()
-        if state.retry_at:
-            now = time.perf_counter()
-            for e, t in list(state.retry_at.items()):
-                if t <= now:
-                    del state.retry_at[e]
-            if state.retry_at:
-                gated = set(state.retry_at)
-                gate_wait = max(min(state.retry_at.values()) - now, 0.0)
-        pendings = [np.asarray([i for i in req.ledger.pending()
-                                if (ri, int(i)) not in inflight
-                                and (ri, int(i)) not in gated],
-                               np.int64)
-                    for ri, req in enumerate(requests)]
-        if all(len(p) == 0 for p in pendings):
+        gate_wait = self._open_gates(state)
+        view = state.plan.pending
+        view.sync(exclude=inflight, gated=state.retry_at)
+        if not view.depth:
             if not q.empty and self._hedge_armed(state):
                 # poll instead of blocking: a held straggler leg must
                 # not stall the tail drain while its hedged duplicate
@@ -1261,14 +1195,12 @@ class WaveBackend(_StreamBackend):
                 time.sleep(min(gate_wait, 0.05))
                 return True
             return False
-        n_workers = self._wave_workers(state, pendings)
+        n_workers = self._wave_workers(state)
         capacity = max(1, n_workers * pool.lanes_per_worker())
 
         # ---- fill the wave (whole-bucket units, ISSUE 8) ----------------
-        with obs.span("planner.fill") as fill:
-            batch = self._fill_bucket_coherent(state, pendings, capacity)
-            fill.set(entries=len(batch))
-        dispatch = list(batch)
+        batch = view.fill(capacity)
+        dispatch = [_Entry(ri, inv) for ri, inv in batch]
 
         # ---- execute: one compiled launch per bucket in the wave --------
         members: List[object] = []
@@ -1278,20 +1210,13 @@ class WaveBackend(_StreamBackend):
             if tag not in members:
                 members.append(tag)
         state.info.wave_members.append(members)
-        unique: Dict[Tuple[int, int], None] = {}
-        for e in dispatch:
-            unique.setdefault((e.req_idx, e.inv))
-        running: Dict[int, List[int]] = {}
-        for ri, inv in unique:
-            running.setdefault(ri, []).append(inv)
-        for ri, invs in running.items():
-            requests[ri].ledger.mark_running(invs)
+        state.mark_running(batch)
         # dispatch every bucket of the wave without blocking — all of a
         # wave's launches execute concurrently on device while the host
         # stacks the next bucket's tensors.  The wave's buckets carry a
         # latch that settles (books + bills) when its last bucket lands
         # — possibly steps later, while wave k+1 is already filling
-        groups = state.plan.group_entries(list(unique))
+        groups = state.plan.group_entries(batch)
         ctx = _WaveLatch(dispatch=dispatch, outstanding=len(groups))
         state.waves_inflight.append(ctx)
 
@@ -1339,14 +1264,14 @@ class WaveBackend(_StreamBackend):
         pool = self.pool
         requests = state.requests
         state.waves_inflight.remove(ctx)
-        touched = []
-        for ri, req in enumerate(requests):
-            entries = [e for e in ctx.dispatch if e.req_idx == ri]
-            if not entries:
-                continue
-            self._book_request_wave(state, req, ri, entries, ctx.results,
-                                    pool, ctx.wall_of_req.get(ri, 0.0))
-            touched.append(ri)
+        by_req: Dict[int, List[_Entry]] = {}
+        for e in ctx.dispatch:
+            by_req.setdefault(e.req_idx, []).append(e)
+        touched = sorted(by_req)
+        for ri in touched:
+            self._book_request_wave(state, requests[ri], ri, by_req[ri],
+                                    ctx.results, pool,
+                                    ctx.wall_of_req.get(ri, 0.0))
         if self.autoscaler is not None and ctx.dispatch:
             total = sum(ctx.wall_of_req.values())
             if total > 0:
@@ -1356,7 +1281,7 @@ class WaveBackend(_StreamBackend):
             requests[ri].report.response_time_s += wall
             requests[ri].report.fit_time_s += wall
             self._finalize_request(state, ri)
-        self._checkpoint(state)
+        self._checkpoint(state, touched)
 
     # ------------------------------------------------------------------
     def _book_request_wave(self, state: DrainState, req: WorkRequest,

@@ -46,6 +46,10 @@ class TaskLedger:
         or RUNNING rows orphaned by a crashed drain)."""
         return np.where(self.status != DONE)[0]
 
+    def owes(self, inv: int) -> bool:
+        """Whether one invocation is still owed a result."""
+        return self.status[inv] != DONE
+
     def mark_running(self, invs) -> None:
         """Flag dispatched rows so a checkpoint taken mid-wave re-queues
         exactly the in-flight work on restart (load() resets RUNNING)."""

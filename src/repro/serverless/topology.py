@@ -506,7 +506,7 @@ class TopologyBackend(_StreamBackend):
         if per_req:                     # a fully-failed slice books nothing
             self._note_wave(state, list(per_req), elapsed)
         state.info.pages = self.topology.page_stats()
-        self._checkpoint(state)
+        self._checkpoint(state, {ri for ri, _ in pb.entries})
 
     def _host_wave(self, state: TopologyDrainState, host_id: int,
                    mine: List[Unit], units: Dict[Unit, List]) -> None:
@@ -538,11 +538,7 @@ class TopologyBackend(_StreamBackend):
                 selected.setdefault(u[0], []).extend(ents)
                 taken += len(ents)
             for key, ents in selected.items():
-                running: Dict[int, List[int]] = {}
-                for ri, inv in ents:
-                    running.setdefault(ri, []).append(inv)
-                for ri, invs in running.items():
-                    state.requests[ri].ledger.mark_running(invs)
+                state.mark_running(ents)
                 decision = self._plan_host_axis(state, key, ents, host_id)
                 compiler, b_align, axis_mesh = self._bucket_compiler(
                     host_id, decision)
@@ -628,19 +624,9 @@ class TopologyBackend(_StreamBackend):
         for q in state.queues.values():
             q.harvest_ready(book)
         self._maybe_hedge(state)
+        gate_wait = self._open_gates(state)   # failed rows in backoff
         groups = state.plan.pending_by_bucket(
-            exclude=state.in_flight_entries())
-        gate_wait = None
-        if state.retry_at:              # failed rows awaiting backoff
-            gated = {}
-            for key in list(groups):
-                ents, wait = self._backoff_filter(state, groups[key])
-                if wait is not None:
-                    gate_wait = wait if gate_wait is None \
-                        else min(gate_wait, wait)
-                if ents:
-                    gated[key] = ents
-            groups = gated
+            exclude=state.in_flight_entries(), gated=state.retry_at)
         ids = [h.host_id for h in self.topology.alive()
                if h.host_id in state.queues]
         n = len(ids)

@@ -84,12 +84,17 @@ def test_undeclared_bounded_put_fails_cache_pass(tmp_path):
 # ---------------------------------------------------------------------------
 # red: protocol pass vs seeded scheduler mutations
 # ---------------------------------------------------------------------------
-def test_unexcluded_pending_view_fails_protocol_pass(tmp_path):
-    tmp = _mutated_tree(
-        tmp_path, "serverless/backends.py",
-        "groups = state.plan.pending_by_bucket(\n"
-        "            exclude=q.in_flight_entries())",
-        "groups = state.plan.pending_by_bucket()")
+@pytest.mark.parametrize("old,new", [
+    # the bucket-stream step's one-shot sync + grouping
+    ("groups = state.plan.pending_by_bucket(\n"
+     "            exclude=q.in_flight_entries(), gated=state.retry_at)",
+     "groups = state.plan.pending_by_bucket(gated=state.retry_at)"),
+    # the wave step's sync before its capacity-bounded fill
+    ("view.sync(exclude=inflight, gated=state.retry_at)",
+     "view.sync(gated=state.retry_at)"),
+])
+def test_unexcluded_pending_view_fails_protocol_pass(tmp_path, old, new):
+    tmp = _mutated_tree(tmp_path, "serverless/backends.py", old, new)
     rules = {f.rule for f in protocol.run(tmp)}
     assert "pending-view-excludes-in-flight" in rules
 
@@ -97,8 +102,8 @@ def test_unexcluded_pending_view_fails_protocol_pass(tmp_path):
 def test_rogue_booking_site_fails_protocol_pass(tmp_path):
     tmp = _mutated_tree(
         tmp_path, "serverless/backends.py",
-        "    def _checkpoint(self, state: DrainState):",
-        "    def _checkpoint(self, state: DrainState):\n"
+        "    def _checkpoint(self, state: DrainState, booked):",
+        "    def _checkpoint(self, state: DrainState, booked):\n"
         "        state.requests[0].ledger.record_failure(0)")
     rules = {f.rule for f in protocol.run(tmp)}
     assert "booking-performer" in rules
@@ -110,8 +115,8 @@ def test_rogue_cancel_site_fails_protocol_pass(tmp_path):
     accounting) — the single-cancel-performer rule must catch it."""
     tmp = _mutated_tree(
         tmp_path, "serverless/backends.py",
-        "    def _checkpoint(self, state: DrainState):",
-        "    def _checkpoint(self, state: DrainState):\n"
+        "    def _checkpoint(self, state: DrainState, booked):",
+        "    def _checkpoint(self, state: DrainState, booked):\n"
         "        state.queue.cancel(state.queue._pending[0])")
     rules = {f.rule for f in protocol.run(tmp)}
     assert "cancel-performer" in rules
@@ -123,8 +128,8 @@ def test_rogue_abandon_site_fails_protocol_pass(tmp_path):
     that re-dispatches it."""
     tmp = _mutated_tree(
         tmp_path, "serverless/backends.py",
-        "    def _checkpoint(self, state: DrainState):",
-        "    def _checkpoint(self, state: DrainState):\n"
+        "    def _checkpoint(self, state: DrainState, booked):",
+        "    def _checkpoint(self, state: DrainState, booked):\n"
         "        state.queue.abandon()")
     rules = {f.rule for f in protocol.run(tmp)}
     assert "abandon-performer" in rules

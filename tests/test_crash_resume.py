@@ -146,3 +146,39 @@ def test_crash_mid_drain_resumes_bitwise(tmp_path):
         np.testing.assert_array_equal(
             np.asarray(second["thetas"][str(rid)]),
             np.asarray([float(t) for t in ref.thetas]))
+
+
+def test_durable_session_checkpoints_each_final_booking(tmp_path,
+                                                         monkeypatch):
+    """A durable session writes a request's ledger when a wave books it,
+    and only then: each file holds its request's final booking, written
+    once at admission and once per wave that booked it — never again for
+    waves that carried only other requests."""
+    from collections import Counter
+
+    from repro.core import DMLSession
+    from repro.serverless import PoolConfig, TaskLedger
+
+    saves = Counter()
+    save = TaskLedger.save
+
+    def counted(self, path):
+        saves[path] += 1
+        return save(self, path)
+
+    monkeypatch.setattr(TaskLedger, "save", counted)
+    ns = {}
+    exec(_CASE, ns)
+    sess = DMLSession(backend="wave", pool=PoolConfig(n_workers=1),
+                      session_dir=str(tmp_path))
+    rids = [sess.submit(plan, data) for plan, data in ns["cases"]()]
+    sess.run()
+    waves = sess.last_run_info.waves
+    for rid in rids:
+        req = sess.request(rid)
+        path = sess._ledger_path(rid)
+        on_disk = TaskLedger.load(path)
+        assert on_disk.complete
+        np.testing.assert_array_equal(on_disk.preds, req.ledger.preds)
+        assert saves[path] == 1 + req.report.waves
+        assert req.report.waves < waves     # others' waves wrote nothing
