@@ -49,12 +49,10 @@ family and program form the ``ProgramCache`` can build:
   * **data-dependent-shape** — every intermediate aval must have
     concrete integer dimensions; a data-dependent shape would make the
     compiled program's output depend on bucket composition.
-  * **morph-classified** — every family must be classified by the
-    cross-shape coalescer (ISSUE 7): in ``MORPH_BITWISE_FAMILIES``
-    (bitwise-proven B-invariant, morph freely) or
-    ``MORPH_TOLERANCE_FAMILIES`` (morph only under an explicit opt-in
-    tolerance on ``PoolConfig``), never silently unclassified — and the
-    two sets must be disjoint.
+  * **morph-classified** — every family must be in
+    ``MORPH_BITWISE_FAMILIES`` (bitwise-proven B-invariant), the only
+    set the cross-shape coalescer (ISSUE 7) morphs: a family outside it
+    would silently never coalesce.
   * **morph-structural-b-pin** — a bitwise-morphable family's program
     must trace to the identical primitive sequence at two different B
     paddings: padding a tail block up to a neighbor's canonical B may
@@ -394,29 +392,16 @@ def _data_key_marks(jaxpr) -> List[Set[str]]:
 
 
 def audit_morph_classification() -> List[Finding]:
-    """Every learner family must be placed by the cross-shape coalescer:
-    bitwise-morphable or tolerance-gated, never silently unclassified —
-    an unclassified family would quietly opt out of tail coalescing and
+    """Every learner family must be bitwise-morphable: a family outside
+    MORPH_BITWISE_FAMILIES would quietly opt out of tail coalescing and
     shrink the launch-efficiency win without any test noticing."""
-    from repro.compile.program import (MORPH_BITWISE_FAMILIES,
-                                       MORPH_TOLERANCE_FAMILIES)
-    findings: List[Finding] = []
-    both = MORPH_BITWISE_FAMILIES & MORPH_TOLERANCE_FAMILIES
-    if both:
-        findings.append(Finding(
-            "jaxpr", "morph-classified", "compile/program.py",
-            f"families {sorted(both)} are in BOTH morph sets — bitwise "
-            "and tolerance-gated are mutually exclusive contracts"))
-    for family in FAMILIES:
-        if family not in MORPH_BITWISE_FAMILIES \
-                and family not in MORPH_TOLERANCE_FAMILIES:
-            findings.append(Finding(
-                "jaxpr", "morph-classified", f"{family}/morph",
-                f"family {family!r} is in neither MORPH_BITWISE_FAMILIES "
-                "nor MORPH_TOLERANCE_FAMILIES — classify it (prove "
-                "bitwise B-invariance or register the tolerance tier) "
-                "so the coalescer's behavior is an explicit contract"))
-    return findings
+    from repro.compile.program import MORPH_BITWISE_FAMILIES
+    return [Finding(
+        "jaxpr", "morph-classified", f"{family}/morph",
+        f"family {family!r} is not in MORPH_BITWISE_FAMILIES — prove its "
+        "bitwise B-invariance (tests/test_compile.py parity gate) so the "
+        "coalescer's behavior is an explicit contract")
+        for family in FAMILIES if family not in MORPH_BITWISE_FAMILIES]
 
 
 def audit_family(family: str) -> List[Finding]:
@@ -436,8 +421,7 @@ def audit_family(family: str) -> List[Finding]:
                 "jaxpr", "morph-structural-b-pin", f"{family}/morph",
                 f"primitive sequence changes between B={_B} and "
                 f"B={2 * _B} — a B-dependent computation cannot be "
-                "bitwise-morphed; move the family to "
-                "MORPH_TOLERANCE_FAMILIES or fix the learner"))
+                "bitwise-morphed; fix the learner"))
 
     findings.extend(audit_fused_pair(single, fused, f"{family}/fused"))
     _taint_jaxpr(single.jaxpr, _data_key_marks(single.jaxpr),

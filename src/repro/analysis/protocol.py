@@ -22,7 +22,7 @@ The protocol (one bucket slice's life):
     its invocations (a checkpoint taken mid-flight must re-queue them);
   * a bucket is harvested exactly once, and only the dispatch queue (or
     the synchronous ``run_bucket`` wrapper) may harvest;
-  * only the two booking functions may write ledger results — booking
+  * only the one booking function may write ledger results — booking
     anywhere else would bypass billing, retry, and finalization;
   * the plan's pending view takes dispatched invocations out and brings
     them back only when told what is still in flight: every
@@ -101,8 +101,7 @@ AUDITED_FILES: Tuple[str, ...] = (
 
 #: the ONLY call sites allowed to write ledger results
 BOOKING_PERFORMERS: FrozenSet[Tuple[str, str]] = frozenset({
-    ("serverless/backends.py", "_StreamBackend._book_direct"),
-    ("serverless/backends.py", "WaveBackend._book_request_wave"),
+    ("serverless/backends.py", "_StreamBackend._book"),
 })
 _BOOKING_METHODS = ("record_success", "record_successes", "record_failure")
 
@@ -113,9 +112,9 @@ HARVEST_PERFORMERS: FrozenSet[Tuple[str, str]] = frozenset({
     ("serverless/dispatch.py", "DispatchQueue.harvest_ready"),
     ("serverless/dispatch.py", "DispatchQueue.harvest_next"),
     ("serverless/dispatch.py", "DispatchQueue.harvest_all"),
-    ("serverless/backends.py", "_BucketStreamBackend.step"),
-    ("serverless/backends.py", "WaveBackend.step"),
-    ("serverless/topology.py", "TopologyBackend.step"),
+    ("serverless/backends.py", "_StreamBackend.step"),
+    # the wave policy's pipeline bound (pipeline_depth unsettled waves)
+    ("serverless/backends.py", "WaveBackend._dispatch_next"),
     ("compile/program.py", "run_bucket"),
 })
 _HARVEST_METHODS = ("harvest", "harvest_ready", "harvest_next",
@@ -169,14 +168,14 @@ def _check_file(rel: str, tree: ast.Module) -> List[Finding]:
                 findings.append(Finding(
                     "protocol", "booking-performer", f"{rel}:{lineno}",
                     f"{callee}() in {qual} — ledger results may only be "
-                    "written by the declared booking functions "
+                    "written by the declared booking function "
                     f"{sorted(q for _, q in BOOKING_PERFORMERS)}"))
         if leaf in _HARVEST_METHODS and "." in callee:
             if (rel, qual) not in HARVEST_PERFORMERS:
                 findings.append(Finding(
                     "protocol", "harvest-performer", f"{rel}:{lineno}",
                     f"{callee}() in {qual} — only the dispatch queue and "
-                    "the declared scheduler steps may harvest"))
+                    "the declared drain loop may harvest"))
         if leaf == "cancel" and "." in callee:
             if (rel, qual) not in CANCEL_PERFORMERS:
                 findings.append(Finding(

@@ -173,6 +173,15 @@ class ProgramCache:
             default_persist() if persist == "auto" else persist
         self.stats = CompileStats()
 
+    @property
+    def fuses(self) -> bool:
+        """Whether this cache runs same-shape fused launches: an
+        unpartitioned cache always does, and a partitioned one when it
+        carries the sharded-fused transform (ISSUE 8: shard_map around
+        the lax.map fused body); a partition-only cache maps
+        single-block operands."""
+        return self.partition is None or self.partition_fused is not None
+
     def _disk(self, key: BucketKey):
         """(persist, fingerprint-builder inputs) when this program may be
         persisted: spec-identified learners only, never partitioned
@@ -369,9 +378,7 @@ class ProgramCache:
 # b_pad so the whole bucket rides one lax.map launch.  Packing is
 # deterministic (first-fit in block order) and bitwise-neutral by the
 # proven B-invariance + lane-content independence; families outside the
-# bitwise set may only morph via the explicit opt-in tolerance tier
-# (PoolConfig.morph_tolerance > 0 + MORPH_TOLERANCE_FAMILIES), which
-# the jaxpr auditor knows about.
+# bitwise set never morph.
 #
 # This replaces the PR-3 rule that padded *every* launch up to B_BLOCK:
 # constant-shape was sufficient for bitwise invariance but blew B-axis
@@ -399,16 +406,10 @@ B_BLOCK = 32
 # backend: the same lane content produces bit-identical floats at any
 # aligned launch B.  Enforced empirically (per-family parametrized gate,
 # tests/test_compile.py) and structurally (analysis/jaxpr_audit.py
-# morph audit); the coalescing scheduler only morphs these.
+# morph audit, which also requires every registered family to be here);
+# the coalescing scheduler only morphs these.
 MORPH_BITWISE_FAMILIES = frozenset(
     {"ols", "ridge", "lasso", "logistic", "kernel_ridge", "mlp"})
-
-# Opt-in tolerance tier: families whose morphed launches are only
-# float-tolerance-equal to canonical launches.  Morphing them requires
-# PoolConfig.morph_tolerance > 0 — an explicit user opt-out of bitwise
-# reproducibility, which the jaxpr auditor reports.  Empty today: every
-# registered family passes the bitwise gate on this backend.
-MORPH_TOLERANCE_FAMILIES = frozenset()
 
 
 def bucket_family(key: BucketKey) -> Optional[str]:
@@ -420,16 +421,10 @@ def bucket_family(key: BucketKey) -> Optional[str]:
     return None
 
 
-def morph_allowed(key: BucketKey, morph_tolerance: float = 0.0) -> bool:
+def morph_allowed(key: BucketKey) -> bool:
     """May this bucket's tail blocks be coalesced/morphed?  Bitwise
-    families always; tolerance-tier families only under an explicit
-    ``morph_tolerance`` opt-in; opaque callables never."""
-    fam = bucket_family(key)
-    if fam is None:
-        return False
-    if fam in MORPH_BITWISE_FAMILIES:
-        return True
-    return morph_tolerance > 0.0 and fam in MORPH_TOLERANCE_FAMILIES
+    families only; any other family and opaque callables never."""
+    return bucket_family(key) in MORPH_BITWISE_FAMILIES
 
 
 @dataclass
@@ -870,8 +865,7 @@ def _dispatch_axis_bucket(plan: MegabatchPlan, cache: ProgramCache,
                           key: BucketKey, entries: Sequence[Entry],
                           blocks: List[_Block], axis: str, mesh,
                           *, b_align: int, pages: Optional[PagePool],
-                          b_block: int, coalesce: bool,
-                          morph_tolerance: float) -> BucketDispatch:
+                          b_block: int, coalesce: bool) -> BucketDispatch:
     """Lower a bucket slice through the planner's data@m/feature@m
     layout (ISSUE 9): every launch block dispatches through the in-mesh
     fit-predict program (sharding/gram.py::axis_fit_program) instead of
@@ -890,7 +884,7 @@ def _dispatch_axis_bucket(plan: MegabatchPlan, cache: ProgramCache,
     n_pad, p_pad = key.n_pad, key.p_pad
     family = bucket_family(key)
     params = tuple(key.learner[1])
-    can_morph = morph_allowed(key, morph_tolerance)
+    can_morph = morph_allowed(key)
     morph = coalesce and can_morph
     lblocks = _coalesce(blocks, b_block, b_align, morph, False)
     morphed_tasks = sum(lb.b_pad for lb in lblocks) if morph == can_morph \
@@ -956,8 +950,7 @@ def dispatch_bucket(plan: MegabatchPlan, cache: ProgramCache,
                     key: BucketKey, entries: Sequence[Entry], *,
                     b_align: int = 1, pages: Optional[PagePool] = None,
                     b_block: int = B_BLOCK, fuse: bool = True,
-                    coalesce: bool = True, morph_tolerance: float = 0.0,
-                    axis_decision=None, mesh=None,
+                    coalesce: bool = True, axis_decision=None, mesh=None,
                     ) -> BucketDispatch:
     """Launch one bucket slice WITHOUT waiting for the device.
 
@@ -968,7 +961,7 @@ def dispatch_bucket(plan: MegabatchPlan, cache: ProgramCache,
     ``lax.map`` launch.  Equal-``b_pad`` launch blocks pack into fused
     launches (a leading block axis over one union page stack; per-block
     launches when ``fuse`` is off, the block is unique at its shape, or
-    the cache is partitioned).  Returns the in-flight
+    the cache does not fuse: ``ProgramCache.fuses``).  Returns the in-flight
     ``BucketDispatch``; call ``.harvest()`` (or go through
     ``run_bucket``) for the results.
 
@@ -991,15 +984,11 @@ def dispatch_bucket(plan: MegabatchPlan, cache: ProgramCache,
         return _dispatch_axis_bucket(
             plan, cache, key, entries, blocks, axis_m[0], mesh,
             b_align=b_align, pages=pages, b_block=b_block,
-            coalesce=coalesce, morph_tolerance=morph_tolerance)
+            coalesce=coalesce)
     if axis_decision is not None:
         axis_decision.executed = "task"
-    # a partitioned cache fuses again when it carries the sharded-fused
-    # transform (ISSUE 8) — shard_map wraps the lax.map body, so the
-    # PR 5 "sharded caches never fuse" restriction is lifted
-    fuse = fuse and (cache.partition is None
-                     or cache.partition_fused is not None)
-    can_morph = morph_allowed(key, morph_tolerance)
+    fuse = fuse and cache.fuses
+    can_morph = morph_allowed(key)
     morph = coalesce and can_morph
     lblocks = _coalesce(blocks, b_block, b_align, morph, fuse)
     # the morphed-B comparator: what the coalescing scheduler burns (or
@@ -1103,7 +1092,6 @@ def run_bucket(plan: MegabatchPlan, cache: ProgramCache, key: BucketKey,
                entries: Sequence[Entry], *, b_align: int = 1,
                pages: Optional[PagePool] = None, b_block: int = B_BLOCK,
                fuse: bool = True, coalesce: bool = True,
-               morph_tolerance: float = 0.0,
                axis_decision=None, mesh=None,
                ) -> Tuple[Dict[Entry, np.ndarray], float]:
     """Synchronous wrapper: dispatch one bucket slice and block for its
@@ -1117,7 +1105,7 @@ def run_bucket(plan: MegabatchPlan, cache: ProgramCache, key: BucketKey,
     t0 = time.perf_counter()
     bd = dispatch_bucket(plan, cache, key, entries, b_align=b_align,
                          pages=pages, b_block=b_block, fuse=fuse,
-                         coalesce=coalesce, morph_tolerance=morph_tolerance,
-                         axis_decision=axis_decision, mesh=mesh)
+                         coalesce=coalesce, axis_decision=axis_decision,
+                         mesh=mesh)
     results = bd.harvest()
     return results, time.perf_counter() - t0

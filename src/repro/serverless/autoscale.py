@@ -1,8 +1,7 @@
 """Occupancy-driven, cost-aware worker autoscaling (ISSUE 3 backend
 layer; topology-aware + roofline-priced since ISSUE 4).
 
-Replaces the static ``PoolConfig.worker_schedule`` with a policy that
-sizes each wave from live signals the compiler already reports:
+Sizes each wave from live signals the compiler already reports:
 
   * **queue depth** — pending invocations across every admitted request,
   * **bucket occupancy** — how full the next wave's B buckets would be
@@ -29,7 +28,9 @@ per-candidate cost table, so the first wave of a cold drain is already
 cost-reasoned instead of unit-guessed (ROADMAP "autoscaler signals").
 
 ``TopologyAutoscaler`` sizes each host mesh's wave independently — one
-``OccupancyAutoscaler`` per host stream, each fed only its host's queue.
+``OccupancyAutoscaler`` per host stream, each fed only its host's queue
+(the drain loop's ``_StreamBackend._wave_capacity`` asks the host's own
+scaler).
 """
 from __future__ import annotations
 
@@ -186,17 +187,6 @@ class TopologyAutoscaler:
     def __init__(self, pool: "PoolConfig", n_hosts: int):
         self.scalers: Dict[int, OccupancyAutoscaler] = {
             h: OccupancyAutoscaler(pool, host=h) for h in range(n_hosts)}
-
-    def decide(self, host: int, queue_depth: int, *,
-               tasks_per_invocation: int = 1, padding_waste: float = 0.0,
-               in_flight: int = 0, roofline_inv_s=None) -> AutoscaleDecision:
-        return self.scalers[host].decide(
-            queue_depth, tasks_per_invocation=tasks_per_invocation,
-            padding_waste=padding_waste, in_flight=in_flight,
-            roofline_inv_s=roofline_inv_s)
-
-    def observe(self, host: int, duration_s: float):
-        self.scalers[host].observe(duration_s)
 
     @property
     def decisions(self) -> List[AutoscaleDecision]:

@@ -1,4 +1,5 @@
-"""The pluggable execution layer: one task grid, three thin schedulers.
+"""The pluggable execution layer: one task grid, one drain loop, four
+scheduling policies.
 
 A ``WorkRequest`` is the compiled form of one estimation request: the task
 grid, the fused arrays (targets, training weights), one or more
@@ -15,37 +16,51 @@ launch** (compile/program.py, bitwise-equal to per-block launches), and
 launches are **dispatched non-blocking**: the compiler hands back
 in-flight ``jax.Array`` handles which each drain stream queues
 (serverless/dispatch.py) and harvests only when a ledger's buckets must
-complete, so host-side booking overlaps device execution.  Each backend
-is a thin scheduler over those compiled buckets — and every backend is a
-**stream scheduler**: the unit of work is one ``step()`` over a live
-``DrainState`` whose request set can grow between steps (continuous
-admission from the session layer), with ``run_requests`` kept as the
-batch wrapper (admit everything, step until idle):
+complete, so host-side booking overlaps device execution.
+
+Every backend is a **stream scheduler** on one loop,
+``_StreamBackend.step``, over a live ``DrainState`` whose request set can
+grow between steps (continuous admission from the session layer;
+``run_requests`` is the batch wrapper: admit everything, step until
+idle).  A step books whatever landed on any dispatch queue, hedges
+overdue buckets, opens matured retry gates, and asks the backend's
+policy — its ``_dispatch_next`` hook — for the next dispatch; when the
+policy has nothing to dispatch, the step drains the in-flight tail.  The
+policies:
 
   WaveBackend     the serverless-analogue wave scheduler (paper §4):
-                  capacity-limited waves, identity-keyed fault injection
-                  + backoff retries (serverless/chaos.py), deadline-based
-                  hedged re-dispatch, elastic worker schedules or the
-                  occupancy autoscaler (serverless/autoscale.py), Lambda
-                  billing.  Waves are SHARED across requests — a wave's
-                  lanes map onto bucket slices, so one warm program
-                  serves every task of a bucket regardless of which
-                  request it came from.
-  ShardedBackend  the same bucket programs shard_map'd over the mesh's
-                  "data" axis (sharding/policy.py::megabatch_specs),
-                  pages replicated, the task-batch axis sharded.
-  InlineBackend   each bucket drained in one direct program call — the
-                  reference scheduler tests compare against.
-  TopologyBackend (serverless/topology.py) per-host-mesh drain streams:
-                  buckets routed to the host whose PagePool already
-                  holds their pages, idle hosts steal, each mesh's wave
-                  sized by its own roofline-priced autoscaler lane.
+                  capacity-limited waves filled from the pending view,
+                  sized by ``n_workers`` or the occupancy autoscaler
+                  (serverless/autoscale.py), pipelined up to
+                  ``pipeline_depth`` unsettled waves.  Waves are SHARED
+                  across requests — a wave's lanes map onto bucket
+                  slices, so one warm program serves every task of a
+                  bucket regardless of which request it came from.
+  InlineBackend   the first pending bucket group per step, one direct
+                  program call on one device — the reference scheduler
+                  tests compare against.
+  ShardedBackend  the same bucket groups with the programs shard_map'd
+                  over the mesh's "data" axis
+                  (sharding/policy.py::megabatch_specs), pages
+                  replicated; fused launches shard too, and each
+                  bucket's parallelization axis is roofline-planned.
+  TopologyBackend (serverless/topology.py) per-host-mesh dispatch
+                  queues: units routed to the host whose PagePool
+                  already holds their pages, idle hosts steal, one host
+                  wave per step round-robin, each sized by its host's
+                  autoscaler lane.
 
-All backends emit the same ``RunReport``/``TaskLedger`` artifacts, so
-fault tolerance, billing, and resume behave identically at the API layer;
-each holds a persistent spec-keyed ``ProgramCache`` so repeat traffic
-through a ``DMLSession`` never re-traces, and a device-resident
-``PagePool`` so steady-state serving re-transfers no feature pages.
+One booking function, ``_StreamBackend._book``, books every harvest on
+every backend with Lambda semantics: identity-keyed fault verdicts
+(serverless/chaos.py), straggler slowdown, simulated durations, the
+``timeout_s`` cap, backoff-gated retries, and a retry-budget abort that
+first books every sibling success.  Hedged re-dispatch, axis planning
+and wave sizing likewise have one home each.  So all backends emit the
+same ``RunReport``/``TaskLedger`` artifacts, and fault tolerance,
+billing, and resume behave identically at the API layer; each holds a
+persistent spec-keyed ``ProgramCache`` so repeat traffic through a
+``DMLSession`` never re-traces, and a device-resident ``PagePool`` so
+steady-state serving re-transfers no feature pages.
 
 Determinism contract: every task draws its PRNG stream as
 fold_in(segment seed, flat task id) at *compile* time, so predictions are
@@ -157,9 +172,6 @@ class PoolConfig:
     dispatch_overhead_s: float = 0.005  # per-wave dispatch latency
     seed: int = 0
     checkpoint_path: Optional[str] = None
-    # elasticity: optional static schedule of worker counts per wave
-    # (grow/shrink); superseded by the occupancy autoscaler below
-    worker_schedule: Optional[Sequence[int]] = None
     # occupancy-driven autoscaling (serverless/autoscale.py): derive the
     # per-wave worker count from queue depth / bucket occupancy / padding
     # waste, priced through the Lambda cost model
@@ -185,12 +197,9 @@ class PoolConfig:
     # before a push force-harvests the oldest (device-liveness bound)
     max_inflight: int = 8
     # cross-shape coalescing (compile/program.py, ISSUE 7): pack/morph
-    # tail blocks of morph-proven families into combined launches.
-    # Bitwise families coalesce whenever this is on; families in the
-    # tolerance tier additionally need morph_tolerance > 0 — an explicit
-    # opt-out of bitwise reproducibility the jaxpr auditor reports
+    # tail blocks of bitwise-proven families (MORPH_BITWISE_FAMILIES)
+    # into combined launches
     coalesce: bool = True
-    morph_tolerance: float = 0.0
     # double-buffered dispatch (ISSUE 7): waves a drain may hold
     # unsettled while filling/stacking the next one (wave k+1's host
     # work overlaps wave k's device execution).  Since ISSUE 10 chaos
@@ -425,9 +434,9 @@ class ExecutionBackend(Protocol):
     The streaming contract is three primitives: ``begin_drain()`` opens a
     ``DrainState``; ``admit(state, req)`` lowers one request into the live
     bucket plan (legal at any point, including mid-drain); ``step(state)``
-    performs one scheduling quantum — a wave (WaveBackend) or one bucket
-    slice (Inline/Sharded) — books ledgers/billing, and returns False once
-    nothing is pending.  ``run_requests`` is the batch wrapper: after it
+    performs one scheduling quantum — a wave (WaveBackend), one bucket
+    slice (Inline/Sharded) or one host wave (Topology) — books
+    ledgers/billing, and returns False once nothing is pending.  ``run_requests`` is the batch wrapper: after it
     returns, every request's ledger is complete (or an exception was
     raised), its report reflects the work performed in this call
     (appending to any prior state), and ``req.gathered_preds()`` yields
@@ -481,10 +490,12 @@ class DrainState:
     Owns the incremental ``MegabatchPlan`` (its request list is the
     admission order), the pool's fault plan (``chaos``,
     serverless/chaos.py — None for fault-free pools, whose hot path
-    then pays nothing), the retry-backoff gates, the in-flight dispatch
-    ``queue`` (non-blocking dispatch), and the cross-request
-    ``BackendRunInfo``.  The session layer holds one of these per live
-    drain and interleaves ``admit`` with ``step``.
+    then pays nothing), the retry-backoff gates, one in-flight dispatch
+    queue per stream (``queues``: key -1 on a single-stream backend, a
+    host id per live host on the topology backend; every queue feeds
+    ``info.dispatch``), and the cross-request ``BackendRunInfo``.  The
+    session layer holds one of these per live drain and interleaves
+    ``admit`` with ``step``.
     """
     plan: "MegabatchPlan"
     info: BackendRunInfo
@@ -492,10 +503,12 @@ class DrainState:
     # (req slot, invocation) -> perf_counter time before which a failed
     # row may not be re-dispatched (capped exponential backoff)
     retry_at: Dict[Tuple[int, int], float] = field(default_factory=dict)
-    wave: int = 0
     seen_buckets: set = field(default_factory=set)
     finalized: set = field(default_factory=set)
-    queue: Optional[DispatchQueue] = None    # in-flight buckets (one stream)
+    queues: Dict[int, DispatchQueue] = field(default_factory=dict)
+    # round-robin position over ``queues``: the topology's host waves
+    # and the tail drain both start from it
+    cursor: int = 0
     # pipelined waves dispatched but not yet settled (WaveBackend): each
     # settles — books ledgers, bills, finalizes — when its last bucket
     # lands; the sanitizer requires this empty at drain retirement
@@ -509,13 +522,22 @@ class DrainState:
     def requests(self) -> List[WorkRequest]:
         return self.plan.requests
 
+    def in_flight_entries(self) -> set:
+        """Every dispatched entry not yet booked: on a queue, or in a
+        pipelined wave that has not settled — a harvested bucket leaves
+        its queue before its wave books it, and re-dispatching its rows
+        would double-book them."""
+        out: set = set()
+        for q in self.queues.values():
+            q.in_flight_entries(out)
+        for latch in self.waves_inflight:
+            out.update(latch.entries)
+        return out
+
     def mark_running(self, entries) -> None:
         """Flag dispatched (slot, invocation) entries RUNNING in their
         ledgers and take them out of the plan's pending view."""
-        by_req: Dict[int, List[int]] = {}
-        for ri, inv in entries:
-            by_req.setdefault(ri, []).append(inv)
-        for ri, invs in by_req.items():
+        for ri, invs in _by_request(entries).items():
             self.requests[ri].ledger.mark_running(invs)
             self.plan.pending.take(ri, invs)
 
@@ -523,25 +545,35 @@ class DrainState:
 # ---------------------------------------------------------------------------
 # helpers shared by backends
 # ---------------------------------------------------------------------------
+def _by_request(entries) -> Dict[int, List[int]]:
+    """(slot, invocation) entries grouped by slot, in first-seen order."""
+    out: Dict[int, List[int]] = {}
+    for ri, inv in entries:
+        out.setdefault(ri, []).append(inv)
+    return out
+
+
+def _tasks_per_invocation(req: WorkRequest) -> int:
+    return req.grid.tasks_per_invocation(req.scaling)
+
+
 def roofline_pending_inv_s(requests, groups) -> Optional[float]:
     """Mean roofline-modeled invocation duration over bucketed pending
     entries (launch/roofline.py) — the autoscaler's cold-start pricing
     signal, replacing the unit-work model before any duration has been
     observed.  Opaque-callable buckets carry no analytic model and are
     skipped; returns None when nothing could be priced."""
+    from repro.compile.program import bucket_family
     from repro.launch.roofline import invocation_roofline_s
     total, n = 0.0, 0
     for key, entries in groups.items():
-        ident = key.learner
-        if not (isinstance(ident, tuple) and len(ident) == 2
-                and isinstance(ident[0], str)) or ident[0] == "opaque":
+        learner = bucket_family(key)
+        if learner is None:
             continue
-        learner, ptuple = ident
         for ri, _ in entries:
-            req = requests[ri]
             total += invocation_roofline_s(
-                learner, dict(ptuple),
-                req.grid.tasks_per_invocation(req.scaling),
+                learner, dict(key.learner[1]),
+                _tasks_per_invocation(requests[ri]),
                 key.n_pad, key.p_pad,
                 # the whole bucket typically rides one fused launch, so
                 # each invocation carries an amortized share of its
@@ -553,7 +585,7 @@ def roofline_pending_inv_s(requests, groups) -> Optional[float]:
 
 
 def traced_step(step):
-    """Run a backend's ``step`` inside its ``backend.step`` span: the
+    """Run the drain's ``step`` inside its ``backend.step`` span: the
     waves so far, the requests the live drain holds, and the buckets the
     step dispatched."""
     @functools.wraps(step)
@@ -561,53 +593,54 @@ def traced_step(step):
         with obs.span("backend.step", wave=state.info.waves,
                       drain_requests=len(state.requests)) as sp:
             stats = state.info.dispatch
-            before = stats.dispatched if stats is not None else 0
+            before = stats.dispatched
             progressed = step(self, state)
-            if stats is not None:
-                sp.set(buckets=stats.dispatched - before)
+            sp.set(buckets=stats.dispatched - before)
             return progressed
     return traced
 
 
-def _fill_rows(req: WorkRequest, inv_ids: np.ndarray, wall: float,
-               pool: PoolConfig):
-    """Record successful rows with measured billing (non-wave backends)."""
-    per = wall / max(len(inv_ids), 1)
-    for inv in inv_ids:
-        req.report.bill.add(BillingRecord(
-            invocation=int(inv), duration_s=per, memory_mb=pool.memory_mb))
-
-
 class _StreamBackend:
-    """Shared streaming machinery: drain-state lifecycle, admission,
-    completion finalization, checkpoints, and the batch wrapper."""
+    """The one drain loop and everything the backends share: drain-state
+    lifecycle, admission, booking, hedging, retry gates, axis planning,
+    wave sizing, completion finalization, checkpoints, and the batch
+    wrapper.  A backend supplies its scheduling policy as
+    ``_dispatch_next``."""
+
+    _state_cls = DrainState
+    autoscaler = None           # the wave and topology backends arm one
+
+    @property
+    def _programs(self) -> Dict:
+        return self.compiler._programs
+
+    def _streams(self) -> Sequence[int]:
+        """The dispatch streams a drain opens a queue for (-1: one)."""
+        return (-1,)
 
     def begin_drain(self) -> DrainState:
-        info = BackendRunInfo(backend=self.name)
+        info = BackendRunInfo(backend=self.name, dispatch=DispatchStats())
         info.compile = self.compiler.stats
         if self.pages is not None:
             info.pages = self.pages.stats
-        state = DrainState(plan=_compile().MegabatchPlan(), info=info)
-        state.chaos = chaos_plan(self.pool)
-        state.queue = DispatchQueue(self.pool.max_inflight)
-        info.dispatch = state.queue.stats
+        state = self._state_cls(plan=_compile().MegabatchPlan(), info=info,
+                                chaos=chaos_plan(self.pool))
+        state.queues = {h: DispatchQueue(self.pool.max_inflight,
+                                         stats=info.dispatch)
+                        for h in self._streams()}
         return state
-
-    def _fuse(self) -> bool:
-        """Same-shape block fusion for this stream's program cache.
-        Partitioned caches fuse only when they carry the sharded-fused
-        transform (ISSUE 8: shard_map around the lax.map fused body);
-        a partition-only cache still maps single-block operands."""
-        return self.pool.fuse and (
-            self.compiler.partition is None
-            or self.compiler.partition_fused is not None)
 
     def _dispatch_opts(self) -> Dict:
         """The launch-scheduling knobs every dispatch_bucket call takes:
-        fusion plus the cross-shape coalescing pair (coalesce gates the
-        scheduler, morph_tolerance opts tolerance-tier families in)."""
-        return {"fuse": self._fuse(), "coalesce": self.pool.coalesce,
-                "morph_tolerance": self.pool.morph_tolerance}
+        same-shape fusion (which the chosen cache gates,
+        ``ProgramCache.fuses``) and cross-shape coalescing."""
+        return {"fuse": self.pool.fuse, "coalesce": self.pool.coalesce}
+
+    def _dispatch_kwargs(self, state: DrainState, key, entries) -> Dict:
+        """Per-bucket dispatch_bucket kwargs of this backend's own
+        program cache (the sharded backend's alignment, axis decision
+        and mesh); a hedge leg replicates them."""
+        return {}
 
     def admit(self, state: DrainState, req: WorkRequest) -> int:
         """Lower one request into the live plan.  The admission slot is
@@ -629,6 +662,244 @@ class _StreamBackend:
             pass
         self._finish(state)
         return state.info
+
+    # ---- the drain loop ------------------------------------------------
+    @traced_step
+    def step(self, state: DrainState) -> bool:
+        """One scheduling quantum; False once nothing is pending or in
+        flight.
+
+        Books whatever landed on any stream (non-blocking), hedges
+        overdue buckets, opens matured retry gates, and asks the
+        backend's policy for its next dispatch.  When the policy has
+        nothing to dispatch, drains the in-flight tail: with hedging
+        armed it polls, since a blocking harvest would sleep out a held
+        straggler's hold — the very tail its hedged duplicate exists to
+        beat; else it blocks on the first stream from ``cursor`` that
+        holds work; failing that, it waits out the earliest retry gate
+        instead of spinning."""
+        queues = state.queues
+        for q in queues.values():
+            q.harvest_ready()
+        self._maybe_hedge(state)
+        gate_wait = self._open_gates(state)
+        if self._dispatch_next(state):
+            return True
+        if self._hedge_armed(state) \
+                and any(not q.empty for q in queues.values()):
+            self._maybe_hedge(state)
+            if not sum(q.harvest_ready() for q in queues.values()):
+                time.sleep(0.001)
+            return True
+        ids = list(queues)
+        for off in range(len(ids)):
+            i = (state.cursor + off) % len(ids)
+            if queues[ids[i]].harvest_next():
+                state.cursor = (i + 1) % len(ids)
+                return True
+        if gate_wait is not None:
+            time.sleep(min(gate_wait, 0.05))
+            return True
+        return False
+
+    def _dispatch_next(self, state: DrainState) -> bool:
+        """The backend's policy: dispatch its next unit of work (a bucket
+        slice, a wave, a host wave) without waiting and return True, or
+        return False when nothing is dispatchable."""
+        raise NotImplementedError
+
+    def _push_bucket(self, state: DrainState, host: int, bd, book) -> None:
+        """Wrap one dispatched bucket with its fault-tolerance context
+        (deadline, straggler hold) and enqueue it on stream ``host``."""
+        hold = self._hold_for(state, bd.entries)
+        pb = PendingBucket(
+            dispatch=bd, host=host,
+            deadline_s=self._deadline_for(state, bd.key, bd.entries),
+            not_ready_before=(time.perf_counter() + hold) if hold else 0.0)
+        state.queues[host].push(pb, book)
+
+    # ---- shared decisions ------------------------------------------------
+    def _scaler(self, host: int) -> Optional[OccupancyAutoscaler]:
+        """The occupancy autoscaler of stream ``host``, None if unarmed."""
+        return self.autoscaler
+
+    def _wave_capacity(self, state: DrainState, host: int, load) -> int:
+        """Lanes of the next wave on stream ``host``: the stream's
+        occupancy autoscaler prices the wave when the pool arms one, else
+        the pool's ``n_workers`` do.  ``load()`` gives the stream's
+        dispatchable invocations, their tasks, and a thunk of them by
+        bucket (cold-start roofline pricing); only a decision calls it."""
+        pool = self.pool
+        scaler = self._scaler(host)
+        workers = pool.n_workers
+        if scaler is not None:
+            depth, tasks, groups = load()
+            decision = scaler.decide(
+                depth, tasks_per_invocation=max(1, tasks // max(depth, 1)),
+                padding_waste=self.compiler.stats.padding.waste_frac,
+                # dispatched-but-unharvested work on this stream is
+                # occupancy, not depth — never provisioned for twice
+                in_flight=state.queues[host].in_flight,
+                roofline_inv_s=lambda: roofline_pending_inv_s(
+                    state.requests, groups()))
+            state.info.autoscale.append(decision)
+            workers = decision.n_workers
+        return max(1, workers * pool.lanes_per_worker())
+
+    def _plan_axis(self, state: DrainState, key, entries, n_devices: int):
+        """Price the bucket's parallelization-axis candidates on an
+        ``n_devices`` mesh once per drain, log the decision on
+        ``info.axis_plans``, and return it (memoized) so the dispatch
+        executes the planned layout (ISSUE 8/9)."""
+        memo_key = (key, n_devices)
+        if memo_key not in state.axis_planned:
+            from repro.compile.buckets import plan_bucket_axis
+            decision = plan_bucket_axis(key, n_tasks=len(entries),
+                                        n_devices=n_devices)
+            state.axis_planned[memo_key] = decision
+            if decision is not None:
+                state.info.axis_plans.append(decision)
+        return state.axis_planned[memo_key]
+
+    # ---- booking ---------------------------------------------------------
+    def _book(self, state: DrainState, ri: int, invs: List[int],
+              results: Dict, wall: float) -> Optional[int]:
+        """Book one request's share of a harvest: ledger rows, bills,
+        fault verdicts, retries.  ``wall`` is the measured wall billed
+        to these invocations; predictions were already computed by the
+        bucket launches (``results``), so chaos can only reorder or
+        repeat work, never change an estimate.
+
+        A fault-free pool consults nothing and batch-books (no draws, no
+        per-invocation loop) unless the wall tripped the timeout cap.
+        Otherwise each invocation gets its verdict from the drain's
+        identity-keyed fault plan (serverless/chaos.py) — legal in any
+        booking order, which is what lets chaos pools ride the pipelined
+        bucket-coherent fill at all: simulated or measured duration,
+        times ``straggler_slowdown`` for a straggler, failed when the
+        verdict says so or the duration passes ``timeout_s``.  A failure
+        arms a backoff gate in ``state.retry_at``; a row already DONE
+        (a re-dispatch lost the race) is skipped.  Returns the
+        invocation whose retry budget ran out, if any, instead of
+        raising, so the caller books every sibling first."""
+        pool = self.pool
+        req = state.requests[ri]
+        ledger, report = req.ledger, req.report
+        per = wall / max(len(invs), 1)
+        report.wave_sizes.append(len(invs))
+        report.waves += 1
+        plan = state.chaos
+        if plan is None and per <= pool.timeout_s:
+            sanitize.check_booking(ledger, invs, "record_successes")
+            ledger.record_successes(
+                invs, np.stack([results[(ri, inv)] for inv in invs]))
+            for inv in invs:
+                report.bill.add(BillingRecord(
+                    invocation=int(inv), duration_s=per,
+                    memory_mb=pool.memory_mb))
+            return None
+        if plan is None:
+            durs = np.full(len(invs), per)
+            failed = durs > pool.timeout_s                # lambda cap
+        else:
+            atts = ledger.attempts[np.asarray(invs, np.int64)]
+            verdicts = [plan.verdict(ri, int(inv), int(att))
+                        for inv, att in zip(invs, atts)]
+            if pool.simulate:
+                base = pool.base_work_s * _tasks_per_invocation(req) \
+                    / speedup_of(pool.memory_mb)
+                durs = base * np.array([v.noise for v in verdicts])
+            else:
+                durs = np.full(len(invs), per)
+            is_strag = np.array([v.straggler for v in verdicts], bool)
+            durs = np.where(is_strag, durs * pool.straggler_slowdown, durs)
+            report.stragglers += int(is_strag.sum())
+            # injected failures fire on attempt 0 only (retries converge)
+            failed = np.array([v.failed for v in verdicts], bool)
+            failed |= durs > pool.timeout_s               # lambda cap
+        now = time.perf_counter()
+        exhausted = None
+        for i, inv in enumerate(invs):
+            if ledger.status[inv] == DONE:   # a re-dispatch lost the race
+                continue
+            if failed[i]:
+                if ledger.attempts[inv] >= pool.max_retries:
+                    exhausted = int(inv)
+                    continue
+                sanitize.check_booking(ledger, inv, "record_failure")
+                ledger.record_failure(inv)
+                report.failures += 1
+                if plan is not None:
+                    state.retry_at[(ri, int(inv))] = \
+                        now + plan.backoff_s(int(ledger.attempts[inv]))
+                continue
+            sanitize.check_booking(ledger, inv, "record_success")
+            ledger.record_success(int(inv), results[(ri, inv)])
+            report.bill.add(BillingRecord(
+                invocation=int(inv), duration_s=float(durs[i]),
+                memory_mb=pool.memory_mb,
+                retry=int(ledger.attempts[inv])))
+        if pool.simulate:
+            # response time = slowest invocation in flight this wave
+            report.response_time_s += float(np.max(durs)) \
+                + pool.dispatch_overhead_s
+        return exhausted
+
+    def _settle(self, state: DrainState, host: int, by_req: Dict,
+                results: Dict, walls: Dict, waited: Optional[float] = None):
+        """Book each request's share of one harvest (``walls[ri]``, its
+        billed wall), feed stream ``host``'s autoscaler the mean wall of
+        an invocation, charge each request ``waited`` (else its share)
+        as response and fit time, finalize what completed, and
+        checkpoint what was booked.  A spent retry budget aborts the
+        drain only once every request's successes are booked: completed
+        work is durable, an abort never discards it."""
+        touched = sorted(by_req)
+        spent = [inv for inv in [self._book(state, ri, by_req[ri], results,
+                                            walls[ri]) for ri in touched]
+                 if inv is not None]
+        if spent:
+            raise RuntimeError(f"invocation {spent[0]} exceeded retry budget")
+        scaler = self._scaler(host)
+        if scaler is not None:
+            scaler.observe(sum(walls[ri] for ri in touched)
+                           / sum(len(invs) for invs in by_req.values()))
+        requests = state.requests
+        for ri in touched:
+            wall = walls[ri] if waited is None else waited
+            report = requests[ri].report
+            report.response_time_s += wall
+            report.fit_time_s += wall
+            self._finalize_request(state, ri)
+        if self.pages is not None:
+            state.info.pages = self.pages.stats
+        self._checkpoint(state, touched)
+
+    def _book_harvest(self, state: DrainState, pb: PendingBucket,
+                      results: Dict, elapsed: float):
+        """Booking continuation of one bucket slice (inline, sharded,
+        topology): each request is billed its lanes' share of the
+        bucket's elapsed wall and charged the whole of it as response
+        time, since it waited for the bucket to land."""
+        by_req = _by_request(pb.entries)
+        per = elapsed / max(len(pb.entries), 1)
+        self._note_members(state, by_req)
+        self._settle(state, pb.host, by_req, results,
+                     {ri: per * len(invs) for ri, invs in by_req.items()},
+                     waited=elapsed)
+
+    @staticmethod
+    def _note_members(state: DrainState, ris) -> None:
+        """Log one wave's member list: its requests' tags (the slot when
+        untagged), deduplicated, in first-seen order."""
+        requests = state.requests
+        members = []
+        for ri in ris:
+            tag = requests[ri].tag
+            tag = ri if tag is None else tag
+            if tag not in members:
+                members.append(tag)
+        state.info.wave_members.append(members)
 
     # ------------------------------------------------------------------
     def _finish(self, state: DrainState):
@@ -662,82 +933,6 @@ class _StreamBackend:
                 ledger.save(path if len(state.requests) == 1
                             else f"{path}.r{ri}")
 
-    def _book_direct(self, state: DrainState, entries, results, wall: float):
-        """Record one bucket launch: ledger bookings, billing, retries.
-
-        Fault-free pools (``state.chaos is None``) batch-book everything
-        with zero per-invocation work — the hot path is unchanged.
-        Chaos pools consult the fault plan per entry: a failed verdict
-        books a failure (retry-budget checked) and arms a backoff gate
-        in ``state.retry_at`` so the row re-enters the pending view only
-        once its gate matures; survivors book normally.  Verdicts are
-        identity-keyed, so this booking is legal in ANY order — the
-        bucket-coherent fill and pipeline stay on under chaos."""
-        n_launch = max(len(entries), 1)
-        plan = state.chaos
-        exhausted: Optional[int] = None
-        if plan is not None:
-            now = time.perf_counter()
-            ok: List[Tuple[int, int]] = []
-            for ri, inv in entries:
-                req = state.requests[ri]
-                ledger = req.ledger
-                if ledger.status[inv] == DONE:
-                    continue             # lost a re-dispatch race (resume)
-                att = int(ledger.attempts[inv])
-                v = plan.verdict(ri, inv, att)
-                if v.straggler:
-                    req.report.stragglers += 1
-                if v.failed:
-                    if att >= self.pool.max_retries:
-                        # defer the abort: sibling successes in this
-                        # slice still book (the ledger philosophy —
-                        # completed work is durable, an abort never
-                        # discards it)
-                        exhausted = inv
-                        continue
-                    sanitize.check_booking(ledger, inv, "record_failure")
-                    ledger.record_failure(inv)
-                    req.report.failures += 1
-                    state.retry_at[(ri, inv)] = \
-                        now + plan.backoff_s(int(ledger.attempts[inv]))
-                    continue
-                ok.append((ri, inv))
-            entries = ok
-        per_req: Dict[int, List[int]] = {}
-        for ri, inv in entries:
-            per_req.setdefault(ri, []).append(inv)
-        for ri, invs in per_req.items():
-            req = state.requests[ri]
-            sanitize.check_booking(req.ledger, invs, "record_successes")
-            req.ledger.record_successes(
-                invs, np.stack([results[(ri, inv)] for inv in invs]))
-            _fill_rows(req, np.asarray(invs),
-                       wall * len(invs) / n_launch, self.pool)
-            req.report.waves += 1
-            req.report.wave_sizes.append(len(invs))
-        if exhausted is not None:
-            raise RuntimeError(
-                f"invocation {exhausted} exceeded retry budget")
-        return per_req
-
-    def _note_wave(self, state: DrainState, ris, step_wall: float):
-        """Close out one direct-scheduler wave: the tag-deduped member
-        list, per-request wall-time accounting, and early finalization
-        (shared by the bucket-stream and topology schedulers; the wave
-        backend has its own fault-aware variant)."""
-        members = []
-        for ri in ris:
-            tag = state.requests[ri].tag
-            tag = ri if tag is None else tag
-            if tag not in members:
-                members.append(tag)
-        state.info.wave_members.append(members)
-        for ri in ris:
-            state.requests[ri].report.fit_time_s += step_wall
-            state.requests[ri].report.response_time_s += step_wall
-            self._finalize_request(state, ri)
-
     # ---- fault-tolerant dispatch (ISSUE 10) --------------------------
     def _hedge_armed(self, state: DrainState) -> bool:
         """Hedged re-dispatch is on when the pool says so, else exactly
@@ -756,17 +951,15 @@ class _StreamBackend:
         pool = self.pool
         if pool.hedge_after_s is not None:
             return pool.hedge_after_s
-        ident = bkey.learner
-        if not (isinstance(ident, tuple) and len(ident) == 2
-                and isinstance(ident[0], str)) or ident[0] == "opaque":
+        from repro.compile.program import bucket_family
+        from repro.launch.roofline import bucket_deadline_s
+        learner = bucket_family(bkey)
+        if learner is None:
             # no analytic model: the Lambda cap is the only deadline
             return pool.timeout_s
-        from repro.launch.roofline import bucket_deadline_s
-        learner, ptuple = ident
-        ri = entries[0][0]
-        req = state.requests[ri]
-        d = bucket_deadline_s(learner, dict(ptuple),
-                              req.grid.tasks_per_invocation(req.scaling),
+        d = bucket_deadline_s(learner, dict(bkey.learner[1]),
+                              _tasks_per_invocation(
+                                  state.requests[entries[0][0]]),
                               bkey.n_pad, bkey.p_pad, len(entries),
                               n_workers=len(entries))
         return min(d, pool.timeout_s)
@@ -786,55 +979,49 @@ class _StreamBackend:
                 return hold
         return 0.0
 
-    def _push_bucket(self, state: DrainState, q: DispatchQueue, bd,
-                     book, host: int = -1) -> PendingBucket:
-        """Wrap one dispatched bucket with its fault-tolerance context
-        (deadline, straggler hold) and enqueue it."""
-        hold = self._hold_for(state, bd.entries)
-        pb = PendingBucket(
-            dispatch=bd, host=host,
-            deadline_s=self._deadline_for(state, bd.key, bd.entries),
-            not_ready_before=(time.perf_counter() + hold) if hold else 0.0)
-        q.push(pb, book)
-        return pb
-
-    def _hedge_dispatch_kwargs(self, state: DrainState, bkey,
-                               entries) -> Dict:
-        """Extra dispatch_bucket kwargs a hedge must replicate so both
-        legs run the identical compiled program (bitwise race)."""
-        return {}
+    def _hedge_dispatch(self, state: DrainState, key, entries,
+                        host: int) -> Tuple[object, object, Dict]:
+        """(program cache, page pool, dispatch kwargs) the duplicate leg
+        of an overdue bucket runs with on stream ``host``: the same
+        compiled program as the original leg, so the race is bitwise."""
+        return self.compiler, self.pages, \
+            self._dispatch_kwargs(state, key, entries)
 
     def _maybe_hedge(self, state: DrainState) -> int:
-        """Duplicate-dispatch every overdue in-flight bucket (single
-        stream: the duplicate lands on the same queue — the topology
-        backend overrides placement to a different host)."""
-        q = state.queue
-        if q is None or not self._hedge_armed(state):
+        """Duplicate-dispatch every overdue in-flight bucket onto the
+        least-loaded *other* live stream — its own when it is the only
+        one; returns the number hedged."""
+        if not self._hedge_armed(state):
             return 0
+        queues = state.queues
         n = 0
-        for pb in q.overdue():
-            self._hedge_bucket(state, pb, q, q,
-                               compiler=self.compiler, pages=self.pages,
-                               host=pb.host)
-            n += 1
+        for host, q in queues.items():
+            for pb in q.overdue():
+                others = [h for h in queues if h != host] or [host]
+                self._hedge_bucket(
+                    state, pb, q,
+                    min(others, key=lambda h: queues[h].in_flight))
+                n += 1
         return n
 
     def _hedge_bucket(self, state: DrainState, pb: PendingBucket,
-                      owner_q: DispatchQueue, push_q: DispatchQueue, *,
-                      compiler, pages, host: int = -1) -> PendingBucket:
-        """Launch the duplicate leg of an overdue bucket and wire the
-        race: same key, same entries, same per-task fold_in keys — so
-        whichever leg lands first books bitwise-identical results.  The
-        winner's harvest settles the pair (HedgePair.settle, the sole
-        cancel performer) and the loser is discarded unbooked."""
+                      owner_q: DispatchQueue, host: int) -> None:
+        """Launch the duplicate leg of an overdue bucket on stream
+        ``host`` and wire the race: same key, same entries, same
+        per-task fold_in keys — so whichever leg lands first books
+        bitwise-identical results.  The winner's harvest settles the
+        pair (HedgePair.settle, the sole cancel performer) and the loser
+        is discarded unbooked."""
         sanitize.check_hedge(pb)
         # RUNNING -> RUNNING (legal re-mark): a checkpoint taken
         # mid-race must still re-queue these rows on restart
         state.mark_running(pb.entries)
+        compiler, pages, kwargs = self._hedge_dispatch(
+            state, pb.key, pb.entries, host)
         bd = _compile().dispatch_bucket(
             state.plan, compiler, pb.key, list(pb.entries), pages=pages,
-            **self._hedge_dispatch_kwargs(state, pb.key, pb.entries),
-            **self._dispatch_opts())
+            **kwargs, **self._dispatch_opts())
+        push_q = state.queues[host]
         pair = HedgePair()
         hpb = PendingBucket(dispatch=bd, host=host, book=pb.book,
                             is_hedge=True, pair=pair)
@@ -843,7 +1030,6 @@ class _StreamBackend:
         pb.pair = pair
         push_q.stats.hedges += 1
         push_q.push(hpb)
-        return hpb
 
     def _open_gates(self, state: DrainState) -> Optional[float]:
         """Purge matured retry gates (their rows rejoin the pending view
@@ -861,78 +1047,25 @@ class _StreamBackend:
 
 
 class _BucketStreamBackend(_StreamBackend):
-    """Inline/Sharded stepping: one pending bucket slice dispatched per
-    step, harvested on a later step (non-blocking dispatch) — the step
-    that dispatches bucket k+1 books bucket k's results while the device
-    executes, so host booking overlaps device execution."""
+    """Inline/Sharded policy: the first pending bucket group per step,
+    dispatched whole and harvested on a later step (non-blocking
+    dispatch) — the step that dispatches bucket k+1 books bucket k's
+    results while the device executes."""
 
-    def _b_align(self) -> int:
-        return 1
-
-    def _plan_axis(self, state: DrainState, bkey, entries):
-        """Parallelization-axis planning hook (ISSUE 8): single-device
-        streams have nothing to shard, so the default plans nothing; the
-        mesh-owning backends price candidates, log AxisDecisions, and
-        return the (memoized) decision so ``step`` can hand it to
-        ``dispatch_bucket`` for in-mesh execution (ISSUE 9)."""
-        return None
-
-    def _axis_mesh(self):
-        """The mesh ``dispatch_bucket`` lowers data/feature AxisDecisions
-        onto.  None (the default) keeps every bucket on the task axis —
-        the bitwise reference path."""
-        return None
-
-    def _book_harvest(self, state: DrainState, pb: PendingBucket,
-                      results: Dict, elapsed: float):
-        """Booking callback the queue fires at harvest: ledgers, bills,
-        wave accounting, early finalization, checkpoint."""
-        per_req = self._book_direct(state, pb.entries, results, elapsed)
-        if per_req:     # chaos can fail a whole slice — nothing to book
-            self._note_wave(state, list(per_req), elapsed)
-        self._checkpoint(state, {ri for ri, _ in pb.entries})
-
-    def _hedge_dispatch_kwargs(self, state: DrainState, bkey,
-                               entries) -> Dict:
-        return {"b_align": self._b_align(),
-                "axis_decision": self._plan_axis(state, bkey, entries),
-                "mesh": self._axis_mesh()}
-
-    @traced_step
-    def step(self, state: DrainState) -> bool:
-        q = state.queue
-        book = lambda pb, res, el: self._book_harvest(state, pb, res, el)
-        q.harvest_ready(book)               # opportunistic booking
-        self._maybe_hedge(state)
-        gate_wait = self._open_gates(state)
+    def _dispatch_next(self, state: DrainState) -> bool:
         groups = state.plan.pending_by_bucket(
-            exclude=q.in_flight_entries(), gated=state.retry_at)
+            exclude=state.in_flight_entries(), gated=state.retry_at)
         if not groups:
-            if not q.empty and self._hedge_armed(state):
-                # poll instead of blocking: a held straggler leg must
-                # not stall the tail drain while its hedged duplicate
-                # can land first and win the race
-                self._maybe_hedge(state)
-                if q.harvest_ready(book) == 0:
-                    time.sleep(0.001)
-                return True
-            if q.harvest_next(book):        # drain the in-flight tail
-                return True
-            if gate_wait is not None:
-                # every pending row is backoff-gated: wait the earliest
-                # gate out instead of spinning (or stalling the drain)
-                time.sleep(min(gate_wait, 0.05))
-                return True
             return False
         bkey, entries = next(iter(groups.items()))
-        decision = self._plan_axis(state, bkey, entries)
+        kwargs = self._dispatch_kwargs(state, bkey, entries)
         state.mark_running(entries)
         bd = _compile().dispatch_bucket(
-            state.plan, self.compiler, bkey, entries,
-            b_align=self._b_align(), pages=self.pages,
-            axis_decision=decision, mesh=self._axis_mesh(),
-            **self._dispatch_opts())
-        self._push_bucket(state, q, bd, book)
+            state.plan, self.compiler, bkey, entries, pages=self.pages,
+            **kwargs, **self._dispatch_opts())
+        self._push_bucket(
+            state, -1, bd,
+            lambda pb, res, el: self._book_harvest(state, pb, res, el))
         state.seen_buckets.add(bkey)
         state.info.buckets = len(state.seen_buckets)
         state.info.waves += 1
@@ -943,8 +1076,9 @@ class _BucketStreamBackend(_StreamBackend):
 # InlineBackend — direct bucket drain, the reference scheduler
 # ---------------------------------------------------------------------------
 class InlineBackend(_BucketStreamBackend):
-    """Every pending bucket in one direct program call.  No faults, no
-    capacity limit: the oracle the other schedulers must agree with."""
+    """Every pending bucket in one direct program call.  No capacity
+    limit, single-device task layout: the oracle the other schedulers
+    must agree with."""
     name = "inline"
 
     def __init__(self, pool: Optional[PoolConfig] = None):
@@ -952,10 +1086,6 @@ class InlineBackend(_BucketStreamBackend):
         self.compiler = _compile().ProgramCache()
         self.pages = _compile().PagePool(self.pool.page_pool_bytes) \
             if self.pool.page_pool_bytes else None
-
-    @property
-    def _programs(self) -> Dict:
-        return self.compiler._programs
 
 
 # ---------------------------------------------------------------------------
@@ -1017,22 +1147,13 @@ class ShardedBackend(_BucketStreamBackend):
     def _n_shards(self) -> int:
         return int(self.mesh.shape["data"])
 
-    def _b_align(self) -> int:
-        return self._n_shards()
-
-    def _plan_axis(self, state: DrainState, bkey, entries):
-        """Price the bucket's parallelization-axis candidates on this
-        mesh, log the decision (once per bucket per drain), and return
-        it so the drain executes the planned layout (ISSUE 9)."""
-        memo_key = (bkey, self._n_shards())
-        if memo_key not in state.axis_planned:
-            from repro.compile.buckets import plan_bucket_axis
-            decision = plan_bucket_axis(
-                bkey, n_tasks=len(entries), n_devices=self._n_shards())
-            state.axis_planned[memo_key] = decision
-            if decision is not None:
-                state.info.axis_plans.append(decision)
-        return state.axis_planned[memo_key]
+    def _dispatch_kwargs(self, state: DrainState, key, entries) -> Dict:
+        """The mesh's task alignment and the bucket's planned axis, which
+        data/feature decisions execute in-mesh (ISSUE 9)."""
+        return {"b_align": self._n_shards(),
+                "axis_decision": self._plan_axis(state, key, entries,
+                                                 self._n_shards()),
+                "mesh": self._axis_mesh()}
 
     def _axis_mesh(self):
         """Data/feature AxisDecisions lower onto this backend's mesh."""
@@ -1044,35 +1165,23 @@ class ShardedBackend(_BucketStreamBackend):
             self._compiler = make_sharded_compiler(self.mesh)
         return self._compiler
 
-    @property
-    def _programs(self) -> Dict:
-        return self.compiler._programs
-
 
 # ---------------------------------------------------------------------------
 # WaveBackend — the serverless-analogue scheduler, multi-request
 # ---------------------------------------------------------------------------
-@dataclass
-class _Entry:
-    """One dispatched lane: (request, invocation, speculative?)."""
-    req_idx: int
-    inv: int
-    speculative: bool = False
-
-
 @dataclass(eq=False)            # identity equality: removed by list.remove
 class _WaveLatch:
     """One pipelined wave awaiting settlement (ISSUE 7 double-buffered
     dispatch).
 
-    A fault-free wave no longer barriers at the end of its step — its
-    buckets stay in flight while the next wave is filled and stacked.
-    The latch accumulates the wave's results and frontier-attributed
-    wall shares as each bucket's booking continuation fires, and the
-    wave **settles** (ledgers booked, bills recorded, requests
-    finalized, checkpoint written) the moment its last bucket lands.
+    A wave does not barrier at the end of its step — its buckets stay in
+    flight while the next wave is filled and stacked.  The latch
+    accumulates the wave's results and frontier-attributed wall shares
+    as each bucket's booking continuation fires, and the wave
+    **settles** (ledgers booked, bills recorded, requests finalized,
+    checkpoint written) the moment its last bucket lands.
     """
-    dispatch: List[_Entry]
+    entries: List[Tuple[int, int]]      # the wave's (slot, invocation)s
     outstanding: int                    # buckets still in flight
     results: Dict = field(default_factory=dict)
     wall_of_req: Dict = field(default_factory=dict)
@@ -1081,27 +1190,16 @@ class _WaveLatch:
 class WaveBackend(_StreamBackend):
     """The paper's wave scheduler (§4) generalized to a request stream.
 
-    One *invocation* = the paper's lambda call; each ``step`` dispatches
-    one wave of up to ``n_workers * lanes_per_worker`` invocations drawn
-    round-robin from every admitted request's pending set, so concurrent
-    estimations share dispatch cycles (fused waves).  A wave's lanes are
-    then grouped by megabatch bucket and executed as one compiled program
-    launch per bucket — one warm "worker program" serves every task of a
-    bucket regardless of which request it came from.  Per wave the
-    scheduler:
-
-      * books fault verdicts from the drain's identity-keyed fault plan
-        (serverless/chaos.py) and re-queues failures with capped
-        exponential backoff (Lambda retry, injected failures
-        first-attempt-only so retries converge),
-      * hedges overdue in-flight buckets with a duplicate dispatch
-        (deadline from launch/roofline.py::bucket_deadline_s, capped by
-        timeout_s) — first-landing wins, the losing leg is cancelled
-        and never booked nor billed,
-      * re-sizes the pool — static ``worker_schedule`` if given, else the
-        occupancy autoscaler (queue depth x padding waste priced through
-        the Lambda cost model) when ``pool.autoscale`` is set,
-      * checkpoints every participating ledger.
+    One *invocation* = the paper's lambda call; each step fills one wave
+    of up to ``n_workers * lanes_per_worker`` invocations (or the
+    occupancy autoscaler's pick, ``pool.autoscale``) from every admitted
+    request's pending set, so concurrent estimations share dispatch
+    cycles (fused waves).  A wave's lanes are grouped by megabatch
+    bucket and executed as one compiled program launch per bucket — one
+    warm "worker program" serves every task of a bucket regardless of
+    which request it came from.  Up to ``pool.pipeline_depth`` waves
+    stay unsettled while the next one fills; each books when its last
+    bucket lands.
 
     Billing: measured (a request's share of its buckets' program wall
     time divided over its lanes) or modeled via the Lambda memory/vCPU
@@ -1117,130 +1215,56 @@ class WaveBackend(_StreamBackend):
         self.autoscaler = OccupancyAutoscaler(self.pool) \
             if self.pool.autoscale else None
 
-    @property
-    def _programs(self) -> Dict:
-        return self.compiler._programs
-
-    # ------------------------------------------------------------------
-    def _wave_workers(self, state: DrainState) -> int:
-        pool = self.pool
-        if pool.worker_schedule is not None:           # legacy static ramp
-            return pool.worker_schedule[
-                min(state.wave, len(pool.worker_schedule) - 1)]
-        if self.autoscaler is not None:
-            view = state.plan.pending
-            depth = view.depth
-            tasks = sum(
-                n * state.requests[ri].grid.tasks_per_invocation(
-                    state.requests[ri].scaling)
-                for ri, n in view.by_slot())
-            # lazy thunk: the autoscaler invokes it only when no
-            # higher-priority pricing signal (simulate model, EMA) exists
-            decision = self.autoscaler.decide(
-                depth,
-                tasks_per_invocation=max(1, tasks // max(depth, 1)),
-                padding_waste=self.compiler.stats.padding.waste_frac,
-                in_flight=state.queue.in_flight if state.queue else 0,
-                # the pricing view is the synced pending view: in-flight
-                # entries are occupancy, not dispatchable depth
-                roofline_inv_s=lambda: roofline_pending_inv_s(
-                    state.requests, view.groups()))
-            state.info.autoscale.append(decision)
-            return decision.n_workers
-        return pool.n_workers
-
-    @traced_step
-    def step(self, state: DrainState) -> bool:
-        """Dispatch one wave and pipeline it: the wave's buckets stay in
-        flight while the next step fills and stacks wave k+1, up to
-        ``pool.pipeline_depth`` unsettled waves — under chaos too, since
-        fault verdicts are identity-keyed (serverless/chaos.py) and so
-        immune to dispatch order.  Books via per-wave latches
-        (book-at-push); False once nothing is pending and the pipeline
-        has drained."""
-        pool = self.pool
-        requests = state.requests
-        q = state.queue
-        # opportunistic booking: settle any wave whose buckets all
-        # landed while the host was filling the previous wave; then
-        # duplicate-dispatch anything overdue
-        q.harvest_ready()
-        self._maybe_hedge(state)
-        # a dispatched entry rejoins the pending view only once it is
-        # out of flight: off the queue AND out of every unsettled wave
-        # latch — a harvested bucket leaves the queue before its wave
-        # settles (and books), and re-dispatching its rows would
-        # double-book them.  Failed rows under backoff stay out until
-        # their retry gate matures.
-        inflight = q.in_flight_entries()
-        for latch in state.waves_inflight:
-            inflight.update((e.req_idx, e.inv) for e in latch.dispatch)
-        gate_wait = self._open_gates(state)
+    def _dispatch_next(self, state: DrainState) -> bool:
+        """Fill one wave from the pending view and dispatch every bucket
+        of it without waiting — under chaos too, since fault verdicts
+        are identity-keyed (serverless/chaos.py) and so immune to
+        dispatch order — then bound the pipeline at
+        ``pool.pipeline_depth`` unsettled waves."""
         view = state.plan.pending
-        view.sync(exclude=inflight, gated=state.retry_at)
+        view.sync(exclude=state.in_flight_entries(), gated=state.retry_at)
         if not view.depth:
-            if not q.empty and self._hedge_armed(state):
-                # poll instead of blocking: a held straggler leg must
-                # not stall the tail drain while its hedged duplicate
-                # can land first and win the race
-                self._maybe_hedge(state)
-                if q.harvest_ready() == 0:
-                    time.sleep(0.001)
-                return True
-            if q.harvest_next():
-                return True         # drain the in-flight pipeline tail
-            if gate_wait is not None:
-                # everything pending is backoff-gated: wait the
-                # earliest gate out instead of stalling the drain
-                time.sleep(min(gate_wait, 0.05))
-                return True
             return False
-        n_workers = self._wave_workers(state)
-        capacity = max(1, n_workers * pool.lanes_per_worker())
+
+        def load():
+            tasks = sum(n * _tasks_per_invocation(state.requests[ri])
+                        for ri, n in view.by_slot())
+            return view.depth, tasks, view.groups
 
         # ---- fill the wave (whole-bucket units, ISSUE 8) ----------------
-        batch = view.fill(capacity)
-        dispatch = [_Entry(ri, inv) for ri, inv in batch]
-
-        # ---- execute: one compiled launch per bucket in the wave --------
-        members: List[object] = []
-        for e in dispatch:
-            tag = requests[e.req_idx].tag
-            tag = e.req_idx if tag is None else tag
-            if tag not in members:
-                members.append(tag)
-        state.info.wave_members.append(members)
+        batch = view.fill(self._wave_capacity(state, -1, load))
+        self._note_members(state, (ri for ri, _ in batch))
         state.mark_running(batch)
-        # dispatch every bucket of the wave without blocking — all of a
-        # wave's launches execute concurrently on device while the host
-        # stacks the next bucket's tensors.  The wave's buckets carry a
-        # latch that settles (books + bills) when its last bucket lands
-        # — possibly steps later, while wave k+1 is already filling
+        # ---- execute: one compiled launch per bucket in the wave --------
+        # every bucket dispatches without blocking, so a wave's launches
+        # run on device while the host stacks the next bucket; the latch
+        # settles the wave when its last bucket lands — possibly steps
+        # later, while wave k+1 is already filling
         groups = state.plan.group_entries(batch)
-        ctx = _WaveLatch(dispatch=dispatch, outstanding=len(groups))
-        state.waves_inflight.append(ctx)
+        latch = _WaveLatch(entries=batch, outstanding=len(groups))
+        state.waves_inflight.append(latch)
 
         def book(pb, res, elapsed):
-            ctx.results.update(res)
+            latch.results.update(res)
             per = elapsed / max(len(pb.entries), 1)
             for ri, _ in pb.entries:
-                ctx.wall_of_req[ri] = ctx.wall_of_req.get(ri, 0.0) + per
-            ctx.outstanding -= 1
-            if ctx.outstanding == 0:
-                self._settle_wave(state, ctx)
+                latch.wall_of_req[ri] = latch.wall_of_req.get(ri, 0.0) + per
+            latch.outstanding -= 1
+            if latch.outstanding == 0:
+                self._settle_wave(state, latch)
 
         for bkey, ents in groups.items():
             state.seen_buckets.add(bkey)
             bd = _compile().dispatch_bucket(state.plan, self.compiler,
                                             bkey, ents, pages=self.pages,
                                             **self._dispatch_opts())
-            self._push_bucket(state, q, bd, book)
-        state.wave += 1
+            self._push_bucket(state, -1, bd, book)
         state.info.buckets = len(state.seen_buckets)
-        state.info.waves = state.wave
-        # bound the pipeline: block-harvest oldest buckets until at
-        # most pipeline_depth waves remain unsettled
-        depth = max(1, pool.pipeline_depth)
+        state.info.waves += 1
+        # bound the pipeline: harvest the oldest buckets until at most
+        # pipeline_depth waves remain unsettled
+        q = state.queues[-1]
+        depth = max(1, self.pool.pipeline_depth)
         if self._hedge_armed(state):
             # poll, don't block: a blocked harvest picks the held
             # straggler and sleeps out the very hold the hedged
@@ -1255,129 +1279,15 @@ class WaveBackend(_StreamBackend):
                 pass
         return True
 
-    def _settle_wave(self, state: DrainState, ctx: _WaveLatch):
-        """Book one pipelined wave the moment its last bucket lands:
-        ledgers, bills, per-request wall attribution, finalization,
-        checkpoint.  Wall time uses the queue's NON-overlapping
-        attribution frontier, so concurrent waves' billed spans sum to
-        the true elapsed wall instead of double-charging overlap."""
-        pool = self.pool
-        requests = state.requests
-        state.waves_inflight.remove(ctx)
-        by_req: Dict[int, List[_Entry]] = {}
-        for e in ctx.dispatch:
-            by_req.setdefault(e.req_idx, []).append(e)
-        touched = sorted(by_req)
-        for ri in touched:
-            self._book_request_wave(state, requests[ri], ri, by_req[ri],
-                                    ctx.results, pool,
-                                    ctx.wall_of_req.get(ri, 0.0))
-        if self.autoscaler is not None and ctx.dispatch:
-            total = sum(ctx.wall_of_req.values())
-            if total > 0:
-                self.autoscaler.observe(total / len(ctx.dispatch))
-        for ri in touched:
-            wall = ctx.wall_of_req.get(ri, 0.0)
-            requests[ri].report.response_time_s += wall
-            requests[ri].report.fit_time_s += wall
-            self._finalize_request(state, ri)
-        self._checkpoint(state, touched)
-
-    # ------------------------------------------------------------------
-    def _book_request_wave(self, state: DrainState, req: WorkRequest,
-                           ri: int, entries: List[_Entry], results: Dict,
-                           pool: PoolConfig, wall: float):
-        """Book one request's share of a wave: billing, fault verdicts,
-        retries.  Predictions were already computed by the wave's bucket
-        launches (``results``) — chaos can only reorder or repeat work,
-        never change an estimate.
-
-        Fault verdicts come from the drain's identity-keyed fault plan
-        (serverless/chaos.py).  A fault-free pool consults nothing and
-        batch-books (no draws, no per-invocation loop), keeping the warm
-        serving path free of per-wave RNG cost; a chaos pool sees the
-        same fault schedule whatever order waves, hedges, retries, or
-        resumes book in — which is what lets chaos pools ride the
-        pipelined bucket-coherent fill at all."""
-        tpi = req.grid.tasks_per_invocation(req.scaling)
-        n_obs = req.ledger.n_obs
-        ledger, report = req.ledger, req.report
-        inv_arr = np.array([e.inv for e in entries], np.int64)
-
-        preds_rows = np.empty((len(entries), tpi, n_obs), np.float32)
-        for i, e in enumerate(entries):
-            preds_rows[i] = results[(ri, e.inv)]
-
-        plan = state.chaos
-        if plan is None:
-            # fault-free fast path: batch-book everything (no draws, no
-            # per-invocation loop) unless the measured wall tripped the
-            # timeout cap — then fall through to the general machinery
-            per = wall / max(len(entries), 1)
-            if per <= pool.timeout_s:
-                sanitize.check_booking(ledger, inv_arr,
-                                       "record_successes")
-                ledger.record_successes(inv_arr, preds_rows)
-                for i, e in enumerate(entries):
-                    report.bill.add(BillingRecord(
-                        invocation=int(e.inv), duration_s=per,
-                        memory_mb=pool.memory_mb))
-                report.wave_sizes.append(len(entries))
-                report.waves += 1
-                return
-            durs = np.full(len(entries), per)
-            failed = durs > pool.timeout_s                # lambda cap
-        else:
-            # --- per-invocation verdicts and durations -------------------
-            atts = ledger.attempts[inv_arr]
-            verdicts = [plan.verdict(ri, int(e.inv), int(atts[i]))
-                        for i, e in enumerate(entries)]
-            if pool.simulate:
-                base = pool.base_work_s * tpi / speedup_of(pool.memory_mb)
-                durs = base * np.array([v.noise for v in verdicts])
-            else:
-                durs = np.full(len(entries), wall / max(len(entries), 1))
-            is_strag = np.array([v.straggler for v in verdicts], bool)
-            durs = np.where(is_strag, durs * pool.straggler_slowdown, durs)
-            report.stragglers += int(is_strag.sum())
-            # injected failures fire on attempt 0 only (retries converge)
-            failed = np.array([v.failed for v in verdicts], bool)
-            failed |= durs > pool.timeout_s               # lambda cap
-
-        now = time.perf_counter()
-        exhausted = None
-        for i, e in enumerate(entries):
-            if ledger.status[e.inv] == DONE:   # duplicate lost the race
-                continue
-            if failed[i]:
-                if ledger.attempts[e.inv] >= pool.max_retries:
-                    # defer the abort until the wave's sibling
-                    # successes are booked (completed work is durable)
-                    exhausted = int(e.inv)
-                    continue
-                sanitize.check_booking(ledger, e.inv, "record_failure")
-                ledger.record_failure(e.inv)
-                report.failures += 1
-                if plan is not None:
-                    state.retry_at[(ri, int(e.inv))] = \
-                        now + plan.backoff_s(int(ledger.attempts[e.inv]))
-                continue
-            sanitize.check_booking(ledger, e.inv, "record_success")
-            ledger.record_success(int(e.inv), preds_rows[i])
-            report.bill.add(BillingRecord(
-                invocation=int(e.inv), duration_s=float(durs[i]),
-                memory_mb=pool.memory_mb,
-                retry=int(ledger.attempts[e.inv]),
-                speculative=e.speculative))
-        report.wave_sizes.append(len(entries))
-        report.waves += 1
-        if pool.simulate:
-            # response time = slowest invocation in flight this wave
-            report.response_time_s += float(np.max(durs)) \
-                + pool.dispatch_overhead_s
-        if exhausted is not None:
-            raise RuntimeError(
-                f"invocation {exhausted} exceeded retry budget")
+    def _settle_wave(self, state: DrainState, latch: _WaveLatch):
+        """Book one pipelined wave the moment its last bucket lands.
+        Each request is billed and charged its share of the queue's
+        NON-overlapping attribution frontier, so concurrent waves'
+        billed spans sum to the true elapsed wall instead of
+        double-charging overlap."""
+        state.waves_inflight.remove(latch)
+        self._settle(state, -1, _by_request(latch.entries), latch.results,
+                     latch.wall_of_req)
 
 
 # ---------------------------------------------------------------------------

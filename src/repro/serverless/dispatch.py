@@ -214,11 +214,12 @@ class DispatchQueue:
     def empty(self) -> bool:
         return not self._pending
 
-    def in_flight_entries(self) -> Set[Entry]:
+    def in_flight_entries(self, into: Optional[Set[Entry]] = None
+                          ) -> Set[Entry]:
         """Dispatched-but-unharvested (request, invocation) pairs — the
-        set schedulers must exclude from their pending view, and the
-        autoscalers must count as occupancy rather than queue depth."""
-        out: Set[Entry] = set()
+        set schedulers must exclude from their pending view — added to
+        ``into`` (a new set when None) and returned."""
+        out: Set[Entry] = set() if into is None else into
         for pb in self._pending:
             out.update(pb.entries)
         return out

@@ -163,18 +163,13 @@ def check_drained(state, where: str) -> None:
     behind is work billed but never booked."""
     if not enabled():
         return
-    n = 0
-    q = getattr(state, "queue", None)
-    if q is not None:
-        n += len(q)
-    for hq in getattr(state, "queues", {}).values():
-        n += len(hq)
+    n = sum(len(q) for q in state.queues.values())
     if n:
         raise ProtocolError(
             f"{where}: drain retiring with {n} bucket(s) still in "
             "flight — every dispatched bucket must be harvested and "
             "booked before the state is dropped")
-    waves = getattr(state, "waves_inflight", None)
+    waves = state.waves_inflight
     if waves:
         raise ProtocolError(
             f"{where}: drain retiring with {len(waves)} pipelined "

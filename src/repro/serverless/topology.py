@@ -17,8 +17,10 @@ and one drain stream.  ``TopologyBackend`` is the scheduler over them:
     host already holding its pages: zero transfers of any kind; and a
     bucket of many requests on fresh data — a Monte-Carlo study's
     replications — spreads over every host by load.
-  * **per-mesh streams** — one ``step()`` advances ONE host's stream by
-    one wave (round-robin cursor), so the session's event loop
+  * **per-mesh streams** — one ``step()`` of the shared drain loop
+    (``_StreamBackend.step``) advances ONE host's stream by one wave
+    (this backend's ``_dispatch_next``, round-robin from the drain's
+    cursor), so the session's event loop
     interleaves all hosts exactly as it interleaves waves today;
     ledgers complete out of order across hosts as they do within one.
     Since ISSUE 5 each host stream owns an in-flight **dispatch queue**
@@ -72,7 +74,6 @@ hosts (see the B_BLOCK caveat in compile/program.py).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -82,12 +83,8 @@ from repro import obs
 from repro.compile.pages import PageDirectory, PagePool, PageStats
 from repro.serverless.autoscale import TopologyAutoscaler
 from repro.serverless.backends import (
-    BackendRunInfo, DrainState, PoolConfig, _compile, _StreamBackend,
-    make_sharded_compiler, roofline_pending_inv_s, traced_step,
-)
-from repro.serverless.chaos import chaos_plan
-from repro.serverless.dispatch import (
-    DispatchQueue, DispatchStats, PendingBucket,
+    DrainState, PoolConfig, _compile, _StreamBackend,
+    _tasks_per_invocation, make_sharded_compiler,
 )
 from repro.sharding.policy import place_unit, steal_choice
 
@@ -229,18 +226,11 @@ class TopologyInfo:
 class TopologyDrainState(DrainState):
     """One continuous drain over all host streams: the shared bucket
     plan plus the live unit→host assignment (units with pending,
-    not-yet-dispatched invocations only), the round-robin cursor the
-    event loop steps with, and one in-flight dispatch queue per host
-    mesh (the per-host streams are the dispatch unit)."""
+    not-yet-dispatched invocations only).  ``queues`` holds one
+    in-flight dispatch queue per live host mesh (the per-host streams
+    are the dispatch unit); ``cursor`` is the host the next step tries
+    first."""
     assignment: Dict[Unit, int] = field(default_factory=dict)
-    cursor: int = 0
-    queues: Dict[int, DispatchQueue] = field(default_factory=dict)
-
-    def in_flight_entries(self) -> set:
-        out = set()
-        for q in self.queues.values():
-            out |= q.in_flight_entries()
-        return out
 
 
 # ---------------------------------------------------------------------------
@@ -280,31 +270,32 @@ class TopologyBackend(_StreamBackend):
             if self.pool.autoscale else None
         self.pages = ClusterPages(topology)
 
-    @property
-    def _programs(self) -> Dict:
-        return self.compiler._programs
-
     # ---- drain lifecycle ---------------------------------------------
+    _state_cls = TopologyDrainState
+
+    def _streams(self) -> List[int]:
+        return [h.host_id for h in self.topology.alive()]
+
     def begin_drain(self) -> TopologyDrainState:
-        info = BackendRunInfo(backend=self.name)
-        info.compile = self.compiler.stats
-        info.pages = self.topology.page_stats()
-        info.topology = TopologyInfo(
+        state = super().begin_drain()
+        state.info.topology = TopologyInfo(
             n_hosts=len(self.topology),
             hosts=[HostLaneInfo(h.host_id, h.n_devices)
                    for h in self.topology.hosts])
-        state = TopologyDrainState(plan=_compile().MegabatchPlan(),
-                                   info=info)
-        state.chaos = chaos_plan(self.pool)
-        # one in-flight queue per host mesh, all feeding one stats block
-        info.dispatch = DispatchStats()
-        state.queues = {
-            h.host_id: DispatchQueue(self.pool.max_inflight,
-                                     stats=info.dispatch)
-            for h in self.topology.hosts}
         return state
 
-    # admit() is inherited: routing happens lazily in step() (one pass
+    def _scaler(self, host: int):
+        return None if self.autoscaler is None \
+            else self.autoscaler.scalers[host]
+
+    def _host_pages(self, host_id: int) -> Optional[PagePool]:
+        """A host's page pool; None when its byte budget is zero ("pool
+        off", the PoolConfig contract): host page stacking instead of
+        churning an always-evicting device pool."""
+        pool = self.topology.hosts[host_id].pool
+        return pool if pool.byte_budget > 0 else None
+
+    # admit() is inherited: routing happens lazily at dispatch (one pass
     # over all unassigned units), so batch admission stays linear
 
     # ---- placement ----------------------------------------------------
@@ -434,21 +425,6 @@ class TopologyBackend(_StreamBackend):
             self._host_compilers[host_id] = cache
         return cache
 
-    def _plan_host_axis(self, state, key, entries, host_id: int):
-        """Price the bucket's axis candidates on the owning host's mesh
-        (once per (bucket, mesh size) per drain) and log the decision."""
-        host = self.topology.hosts[host_id]
-        memo_key = (key, host.n_devices)
-        if memo_key in state.axis_planned:
-            return state.axis_planned[memo_key]
-        from repro.compile.buckets import plan_bucket_axis
-        decision = plan_bucket_axis(key, n_tasks=len(entries),
-                                    n_devices=host.n_devices)
-        state.axis_planned[memo_key] = decision
-        if decision is not None:
-            state.info.axis_plans.append(decision)
-        return decision
-
     def _bucket_compiler(self, host_id: int, decision):
         """(program cache, b_align, axis mesh) one bucket dispatches
         through on this host: the host-mesh sharded-fused cache when
@@ -466,47 +442,30 @@ class TopologyBackend(_StreamBackend):
         return self.compiler, 1, None
 
     # ---- the per-host wave --------------------------------------------
-    def _wave_capacity(self, state, host_id: int, mine: List[Unit],
-                       units: Dict[Unit, List]) -> int:
-        pool = self.pool
-        if pool.worker_schedule is not None:   # legacy static ramp, per
-            sched = pool.worker_schedule       # host stream (wave parity)
-            waves_done = state.info.topology.hosts[host_id].waves
-            w = sched[min(waves_done, len(sched) - 1)]
-            return max(1, w * pool.lanes_per_worker())
-        if self.autoscaler is None:
-            return max(1, pool.n_workers * pool.lanes_per_worker())
-        queued: Dict[object, List] = {}
-        for u in mine:
-            queued.setdefault(u[0], []).extend(units[u])
-        depth = sum(len(ents) for ents in queued.values())
-        tasks = sum(
-            state.requests[ri].grid.tasks_per_invocation(
-                state.requests[ri].scaling)
-            for ents in queued.values() for ri, _ in ents)
-        decision = self.autoscaler.decide(
-            host_id, depth,
-            tasks_per_invocation=max(1, tasks // max(depth, 1)),
-            padding_waste=self.compiler.stats.padding.waste_frac,
-            # dispatched-but-unharvested work on this host's stream is
-            # occupancy, not queue depth — never provisioned for twice
-            in_flight=state.queues[host_id].in_flight,
-            roofline_inv_s=lambda: roofline_pending_inv_s(
-                state.requests, queued))
-        state.info.autoscale.append(decision)
-        return max(1, decision.n_workers * pool.lanes_per_worker())
-
-    def _book_harvest(self, state: TopologyDrainState, pb: PendingBucket,
-                      results: Dict, elapsed: float):
-        """Booking callback fired at harvest: ledgers, bills, autoscaler
-        EMA for the launching host, wave close-out, checkpoint."""
-        per_req = self._book_direct(state, pb.entries, results, elapsed)
-        if self.autoscaler is not None and pb.entries:
-            self.autoscaler.observe(pb.host, elapsed / len(pb.entries))
-        if per_req:                     # a fully-failed slice books nothing
-            self._note_wave(state, list(per_req), elapsed)
-        state.info.pages = self.topology.page_stats()
-        self._checkpoint(state, {ri for ri, _ in pb.entries})
+    def _dispatch_next(self, state: TopologyDrainState) -> bool:
+        """Route every new unit, then advance ONE host stream by one
+        wave, trying hosts round-robin from ``cursor``; a host with
+        nothing routed to it steals first.  So the session's event loop
+        interleaves every host's dispatch with every other host's
+        harvest and booking."""
+        groups = state.plan.pending_by_bucket(
+            exclude=state.in_flight_entries(), gated=state.retry_at)
+        if not groups:
+            return False
+        units = self._units(groups)
+        by_host = self._route(state, units)   # retries may resurface units
+        ids = list(state.queues)
+        for off in range(len(ids)):
+            h = ids[(state.cursor + off) % len(ids)]
+            mine = by_host.get(h, [])
+            if not mine and self.pool.steal:
+                mine = self._try_steal(state, units, by_host, h)
+            if not mine:
+                continue
+            self._host_wave(state, h, mine, units)
+            state.cursor = (state.cursor + off + 1) % len(ids)
+            return True
+        return False
 
     def _host_wave(self, state: TopologyDrainState, host_id: int,
                    mine: List[Unit], units: Dict[Unit, List]) -> None:
@@ -516,15 +475,21 @@ class TopologyBackend(_StreamBackend):
         and booking overlaps this mesh's execution."""
         with obs.span("topology.wave", host=host_id) as sp:
             host = self.topology.hosts[host_id]
-            # a zero byte budget means "pool off" (PoolConfig contract):
-            # fall back to host page stacking instead of churning an
-            # always-evicting device pool
-            host_pages = host.pool if host.pool.byte_budget > 0 else None
+            host_pages = self._host_pages(host_id)
             lane = state.info.topology.hosts[host_id]
-            q = state.queues[host_id]
             book = lambda pb, res, el: self._book_harvest(state, pb, res,
                                                           el)
-            capacity = self._wave_capacity(state, host_id, mine, units)
+
+            def load():
+                queued: Dict[object, List] = {}
+                for u in mine:
+                    queued.setdefault(u[0], []).extend(units[u])
+                depth = sum(len(ents) for ents in queued.values())
+                tasks = sum(_tasks_per_invocation(state.requests[ri])
+                            for ents in queued.values() for ri, _ in ents)
+                return depth, tasks, lambda: queued
+
+            capacity = self._wave_capacity(state, host_id, load)
             # fill the wave unit-by-unit, truncating the last unit to the
             # remaining capacity; each selection takes at least one
             # invocation, so a wave always makes progress.  A bucket's
@@ -539,21 +504,14 @@ class TopologyBackend(_StreamBackend):
                 taken += len(ents)
             for key, ents in selected.items():
                 state.mark_running(ents)
-                decision = self._plan_host_axis(state, key, ents, host_id)
+                decision = self._plan_axis(state, key, ents, host.n_devices)
                 compiler, b_align, axis_mesh = self._bucket_compiler(
                     host_id, decision)
-                opts = dict(self._dispatch_opts())
-                # fusion follows the *chosen* cache, not the shared one: a
-                # host's sharded-fused cache fuses, a partition-only cache
-                # would not (compile/program.py gate)
-                opts["fuse"] = self.pool.fuse and (
-                    compiler.partition is None
-                    or compiler.partition_fused is not None)
                 bd = _compile().dispatch_bucket(
                     state.plan, compiler, key, ents, pages=host_pages,
                     b_align=b_align, axis_decision=decision, mesh=axis_mesh,
-                    **opts)
-                self._push_bucket(state, q, bd, book, host=host_id)
+                    **self._dispatch_opts())
+                self._push_bucket(state, host_id, bd, book)
                 state.seen_buckets.add(key)
             sp.set(invocations=taken)
         lane.waves += 1
@@ -563,31 +521,13 @@ class TopologyBackend(_StreamBackend):
         state.info.pages = self.topology.page_stats()
 
     # ---- fault tolerance ----------------------------------------------
-    def _maybe_hedge(self, state: TopologyDrainState) -> int:
-        """Cross-host hedging: the duplicate leg of an overdue bucket
-        lands on the least-loaded *other* live host and dispatches
-        through the shared single-device cache — the bitwise-reference
-        path — so whichever leg wins the booked rows are identical
-        regardless of either host's axis plan."""
-        if not self._hedge_armed(state):
-            return 0
-        ids = [h.host_id for h in self.topology.alive()
-               if h.host_id in state.queues]
-        n = 0
-        for hid in ids:
-            q = state.queues[hid]
-            for pb in q.overdue():
-                others = [i for i in ids if i != hid] or [hid]
-                target = min(
-                    others, key=lambda i: state.queues[i].in_flight)
-                tpool = self.topology.hosts[target].pool
-                self._hedge_bucket(
-                    state, pb, q, state.queues[target],
-                    compiler=self.compiler,
-                    pages=tpool if tpool.byte_budget > 0 else None,
-                    host=target)
-                n += 1
-        return n
+    def _hedge_dispatch(self, state: TopologyDrainState, key, entries,
+                        host: int):
+        """A hedge leg runs on the shared single-device cache — the
+        bitwise-reference path — over the target host's pool, so
+        whichever leg wins, the booked rows are identical regardless of
+        either host's axis plan."""
+        return self.compiler, self._host_pages(host), {}
 
     def kill_host(self, state: TopologyDrainState, host_id: int) -> int:
         """Lose one host mid-drain (the chaos suite's host-loss fault):
@@ -611,56 +551,3 @@ class TopologyBackend(_StreamBackend):
         info.lost_buckets += len(orphans)
         state.info.pages = topo.page_stats()
         return len(orphans)
-
-    # ---- the stream scheduler -----------------------------------------
-    @traced_step
-    def step(self, state: TopologyDrainState) -> bool:
-        """Advance ONE host stream by one wave (round-robin); False once
-        no host has pending or in-flight work.  Every step first books
-        any landed buckets on any host (non-blocking) and hedges any
-        overdue ones, so harvest is interleaved with — and overlapped
-        by — dispatch on other hosts."""
-        book = lambda pb, res, el: self._book_harvest(state, pb, res, el)
-        for q in state.queues.values():
-            q.harvest_ready(book)
-        self._maybe_hedge(state)
-        gate_wait = self._open_gates(state)   # failed rows in backoff
-        groups = state.plan.pending_by_bucket(
-            exclude=state.in_flight_entries(), gated=state.retry_at)
-        ids = [h.host_id for h in self.topology.alive()
-               if h.host_id in state.queues]
-        n = len(ids)
-        if not groups:
-            if not n:
-                return False
-            # nothing dispatchable: drain in-flight work.  A blocking
-            # harvest would sleep out a held straggler's hold and
-            # defeat the hedge race, so with hedging armed poll instead
-            if self._hedge_armed(state) \
-                    and any(not state.queues[h].empty for h in ids):
-                if not sum(state.queues[h].harvest_ready(book)
-                           for h in ids):
-                    time.sleep(0.001)
-                return True
-            for off in range(n):
-                h = ids[(state.cursor + off) % n]
-                if state.queues[h].harvest_next(book):
-                    state.cursor = (state.cursor + off + 1) % n
-                    return True
-            if gate_wait is not None:   # only backoff gates remain
-                time.sleep(min(gate_wait, 0.05))
-                return True
-            return False
-        units = self._units(groups)
-        by_host = self._route(state, units)   # retries may resurface units
-        for off in range(n):
-            h = ids[(state.cursor + off) % n]
-            mine = by_host.get(h, [])
-            if not mine and self.pool.steal:
-                mine = self._try_steal(state, units, by_host, h)
-            if not mine:
-                continue
-            self._host_wave(state, h, mine, units)
-            state.cursor = (state.cursor + off + 1) % n
-            return True
-        return False

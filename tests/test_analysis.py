@@ -84,17 +84,25 @@ def test_undeclared_bounded_put_fails_cache_pass(tmp_path):
 # ---------------------------------------------------------------------------
 # red: protocol pass vs seeded scheduler mutations
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("old,new", [
-    # the bucket-stream step's one-shot sync + grouping
-    ("groups = state.plan.pending_by_bucket(\n"
-     "            exclude=q.in_flight_entries(), gated=state.retry_at)",
+@pytest.mark.parametrize("rel,old,new", [
+    # the bucket-stream policy's one-shot sync + grouping
+    ("serverless/backends.py",
+     "groups = state.plan.pending_by_bucket(\n"
+     "            exclude=state.in_flight_entries(), gated=state.retry_at)",
      "groups = state.plan.pending_by_bucket(gated=state.retry_at)"),
-    # the wave step's sync before its capacity-bounded fill
-    ("view.sync(exclude=inflight, gated=state.retry_at)",
+    # the wave policy's sync before its capacity-bounded fill
+    ("serverless/backends.py",
+     "view.sync(exclude=state.in_flight_entries(), gated=state.retry_at)",
      "view.sync(gated=state.retry_at)"),
+    # the topology policy's grouping before routing
+    ("serverless/topology.py",
+     "groups = state.plan.pending_by_bucket(\n"
+     "            exclude=state.in_flight_entries(), gated=state.retry_at)",
+     "groups = state.plan.pending_by_bucket(gated=state.retry_at)"),
 ])
-def test_unexcluded_pending_view_fails_protocol_pass(tmp_path, old, new):
-    tmp = _mutated_tree(tmp_path, "serverless/backends.py", old, new)
+def test_unexcluded_pending_view_fails_protocol_pass(tmp_path, rel, old,
+                                                     new):
+    tmp = _mutated_tree(tmp_path, rel, old, new)
     rules = {f.rule for f in protocol.run(tmp)}
     assert "pending-view-excludes-in-flight" in rules
 
@@ -117,7 +125,7 @@ def test_rogue_cancel_site_fails_protocol_pass(tmp_path):
         tmp_path, "serverless/backends.py",
         "    def _checkpoint(self, state: DrainState, booked):",
         "    def _checkpoint(self, state: DrainState, booked):\n"
-        "        state.queue.cancel(state.queue._pending[0])")
+        "        state.queues[-1].cancel(state.queues[-1]._pending[0])")
     rules = {f.rule for f in protocol.run(tmp)}
     assert "cancel-performer" in rules
 
@@ -130,7 +138,7 @@ def test_rogue_abandon_site_fails_protocol_pass(tmp_path):
         tmp_path, "serverless/backends.py",
         "    def _checkpoint(self, state: DrainState, booked):",
         "    def _checkpoint(self, state: DrainState, booked):\n"
-        "        state.queue.abandon()")
+        "        state.queues[-1].abandon()")
     rules = {f.rule for f in protocol.run(tmp)}
     assert "abandon-performer" in rules
 
@@ -339,15 +347,16 @@ def test_sanitizer_trips_on_lost_bucket(monkeypatch):
     monkeypatch.setenv("REPRO_SANITIZE", "1")
 
     class _State:
-        queue = DispatchQueue()
-        queues = {}
+        queues = {-1: DispatchQueue(), 0: DispatchQueue()}
+        waves_inflight = []
 
     _, bd = _dispatched_bucket()
-    _State.queue._pending.append(PendingBucket(dispatch=bd))
-    with pytest.raises(ProtocolError, match="in\\s?flight"):
-        check_drained(_State, "test retire")
-    _State.queue._pending.clear()
-    check_drained(_State, "test retire")     # empty queue passes
+    for q in _State.queues.values():          # each stream is checked
+        q._pending.append(PendingBucket(dispatch=bd))
+        with pytest.raises(ProtocolError, match="in\\s?flight"):
+            check_drained(_State, "test retire")
+        q._pending.clear()
+    check_drained(_State, "test retire")     # empty queues pass
 
 
 def test_sanitizer_trips_on_double_hedge(monkeypatch):

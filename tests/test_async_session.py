@@ -253,7 +253,7 @@ def test_inflight_entries_excluded_from_pending_and_harvested_later(
         backend.admit(state, compile_request(*_plr(n, seed=seed)))
 
     assert backend.step(state)                    # dispatch bucket 1
-    inflight = state.queue.in_flight_entries()
+    inflight = state.in_flight_entries()
     assert inflight                               # really in flight
     done_before = sum(r.ledger.n_done for r in state.requests)
     groups = state.plan.pending_by_bucket(exclude=inflight)
@@ -262,7 +262,7 @@ def test_inflight_entries_excluded_from_pending_and_harvested_later(
 
     while backend.step(state):                    # dispatch 2, harvest both
         pass
-    assert state.queue.empty
+    assert all(q.empty for q in state.queues.values())
     assert all(r.ledger.complete for r in state.requests)
     assert sum(r.ledger.n_done for r in state.requests) > done_before
     d = state.info.dispatch
@@ -307,7 +307,7 @@ def test_dispatch_queue_inflight_cap_forces_harvest():
     for i, n in enumerate((100, 300, 600)):       # three buckets
         backend.admit(state, compile_request(*_plr(n, seed=40 + i)))
     while backend.step(state):
-        assert len(state.queue) <= 1
+        assert len(state.queues[-1]) <= 1
     assert all(r.ledger.complete for r in state.requests)
 
 

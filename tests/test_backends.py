@@ -309,13 +309,15 @@ CHAOS_POOL = dict(failure_rate=0.3, straggler_rate=0.3, max_retries=10,
 
 @pytest.mark.parametrize("scenario", [
     "wave_depth2", "wave_backoff", "wave_hedges", "wave_resumed_running",
-    "inline_backoff", "topology_kill_host"])
+    "inline_backoff", "sharded_backoff", "topology_kill_host",
+    "topology_backoff", "topology_hedges"])
 def test_pending_view_matches_full_scan(oracle, scenario):
     """The view's wave fill and its bucketed view give the reference's
     batch, entry for entry and in order, at every step of real drains:
     several buckets a request, pipelined waves (depth 2), backoff-gated
-    retries under a chaos pool, hedges, resumed ledgers with RUNNING
-    rows, and a topology drain that loses a host."""
+    retries under a chaos pool on every backend, hedges on one stream
+    and across hosts, resumed ledgers with RUNNING rows, and a topology
+    drain that loses a host."""
     from repro.serverless import TaskLedger
     from repro.serverless.ledger import RUNNING
     cases = _mixed_cases()
@@ -329,8 +331,15 @@ def test_pending_view_matches_full_scan(oracle, scenario):
                                   hedge_after_s=0.002, **CHAOS_POOL),
         "wave_resumed_running": PoolConfig(n_workers=2, memory_mb=512),
         "inline_backoff": PoolConfig(retry_backoff_s=0.003, **CHAOS_POOL),
+        "sharded_backoff": PoolConfig(retry_backoff_s=0.003, **CHAOS_POOL),
         "topology_kill_host": PoolConfig(n_workers=1, memory_mb=512,
                                          n_hosts=2, **CHAOS_POOL),
+        "topology_backoff": PoolConfig(n_workers=1, memory_mb=512,
+                                       n_hosts=2, retry_backoff_s=0.003,
+                                       **CHAOS_POOL),
+        "topology_hedges": PoolConfig(n_workers=1, memory_mb=256,
+                                      n_hosts=2, straggler_hold_s=0.02,
+                                      hedge_after_s=0.002, **CHAOS_POOL),
     }
     backend = make_backend(scenario.split("_")[0], pools[scenario])
     reqs = [compile_request(p, d) for p, d in cases]
@@ -360,7 +369,7 @@ def test_pending_view_matches_full_scan(oracle, scenario):
         if steps == 2:                      # admission mid-drain
             for req in reqs[2:]:
                 backend.admit(state, req)
-        q = getattr(state, "queues", {}).get(0)
+        q = state.queues.get(0)
         if scenario == "topology_kill_host" and not killed \
                 and q is not None and q.in_flight > 0:
             assert backend.kill_host(state, 0) > 0
@@ -374,9 +383,9 @@ def test_pending_view_matches_full_scan(oracle, scenario):
         assert oracle["groups"] > 0
     if scenario == "topology_kill_host":
         assert killed
-    if scenario in ("wave_backoff", "inline_backoff"):
+    if scenario.endswith("_backoff"):
         assert any(r.report.failures for r in reqs)
-    if scenario == "wave_hedges":
+    if scenario.endswith("_hedges"):
         assert state.info.dispatch.hedges > 0
 
 

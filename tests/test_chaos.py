@@ -9,6 +9,8 @@ results under ANY fault schedule — any worker count, any harvest order,
 any hedge race outcome, any host loss — are bitwise-identical to the
 fault-free drain.
 """
+import time
+
 import numpy as np
 import pytest
 
@@ -185,6 +187,85 @@ def test_backoff_gates_delay_but_complete():
     assert req.report.failures > 0
     np.testing.assert_array_equal(req.gathered_preds(),
                                   ref.gathered_preds())
+
+
+@pytest.mark.parametrize("backend", ["inline", "sharded", "wave",
+                                     "topology"])
+def test_every_backend_books_the_lambda_contract(backend, monkeypatch):
+    """One booking path for every backend.  Under a chaos pool each
+    invocation is billed exactly once, at its simulated duration times
+    ``straggler_slowdown`` when its successful attempt straggled; a
+    failed row is re-dispatched only once its backoff gate matured; and
+    a retry budget that runs out aborts the drain only after every
+    success of that harvest is booked."""
+    from dataclasses import replace
+
+    from repro.serverless.backends import DrainState, _StreamBackend
+    from repro.serverless.cost import speedup_of
+    from repro.serverless.ledger import DONE, FAILED
+    cases = [_case("ridge", {"reg": 1.0}, seed=s) for s in (3, 4)]
+    pool = PoolConfig(n_workers=2, memory_mb=512, failure_rate=0.3,
+                      straggler_rate=0.4, straggler_slowdown=4.0,
+                      simulate=True, base_work_s=0.01, max_retries=10,
+                      retry_backoff_s=0.003, seed=4)
+    gates, early, settles = {}, [], []
+    open_gates, mark, settle = (_StreamBackend._open_gates,
+                                DrainState.mark_running,
+                                _StreamBackend._settle)
+
+    def open_gates_(self, state):
+        gates.update(state.retry_at)
+        return open_gates(self, state)
+
+    def mark_(self, entries):
+        now = time.perf_counter()
+        early.extend(e for e in entries
+                     if self.requests[e[0]].ledger.status[e[1]] == FAILED
+                     and not now >= gates.get(e, float("inf")))
+        return mark(self, entries)
+
+    def settle_(self, state, host, by_req, *args, **kwargs):
+        settles.append({ri: list(invs) for ri, invs in by_req.items()})
+        return settle(self, state, host, by_req, *args, **kwargs)
+
+    monkeypatch.setattr(_StreamBackend, "_open_gates", open_gates_)
+    monkeypatch.setattr(DrainState, "mark_running", mark_)
+    monkeypatch.setattr(_StreamBackend, "_settle", settle_)
+
+    reqs = [compile_request(p, d) for p, d in cases]
+    make_backend(backend, pool).run_requests(reqs)
+    plan = chaos_plan(pool)
+    stragglers = 0
+    for ri, req in enumerate(reqs):
+        assert req.ledger.complete
+        recs = req.report.bill.records
+        assert sorted(r.invocation for r in recs) == \
+            list(range(req.ledger.n_invocations))        # billed once
+        base = pool.base_work_s \
+            * req.grid.tasks_per_invocation(req.scaling) \
+            / speedup_of(pool.memory_mb)
+        for r in recs:
+            v = plan.verdict(ri, r.invocation, r.retry)
+            assert not v.failed
+            want = base * v.noise * (pool.straggler_slowdown
+                                     if v.straggler else 1.0)
+            assert r.duration_s == pytest.approx(want, rel=1e-12)
+            stragglers += v.straggler
+    assert stragglers > 0
+    assert sum(r.report.failures for r in reqs) > 0
+    assert gates and not early              # retried only past the gate
+
+    # a spent budget: every other success of the harvest that raised —
+    # the failed row's siblings and the other requests' rows — is booked
+    reqs = [compile_request(p, d) for p, d in cases]
+    with pytest.raises(RuntimeError, match="retry budget"):
+        make_backend(backend, replace(pool, max_retries=0)).run_requests(
+            reqs)
+    verdicts = {(ri, inv): plan.verdict(ri, inv, 0).failed
+                for ri, invs in settles[-1].items() for inv in invs}
+    assert any(verdicts.values()) and not all(verdicts.values())
+    for (ri, inv), failed in verdicts.items():
+        assert (reqs[ri].ledger.status[inv] == DONE) != failed
 
 
 # ---------------------------------------------------------------------------

@@ -383,20 +383,20 @@ def test_morphed_tail_launch_bitwise_parity(name, params):
 
 
 def test_morph_tolerance_gate():
-    """A family outside MORPH_BITWISE_FAMILIES only morphs under an
-    explicit opt-in tolerance (PoolConfig.morph_tolerance > 0); the
-    default 0.0 keeps it on canonical shapes."""
-    from repro.compile.program import (MORPH_TOLERANCE_FAMILIES,
-                                       morph_allowed)
+    """The morph gate is the bitwise set alone: a family outside
+    MORPH_BITWISE_FAMILIES never morphs (there is no tolerance opt-in),
+    opaque callables never do, and a bitwise-proven family always does."""
+    from repro.compile.program import MORPH_BITWISE_FAMILIES, morph_allowed
     from repro.compile.buckets import BucketKey
     # every current family is bitwise-proven, so synthesize the key of a
-    # hypothetical tolerance-tier family to pin the gate's behavior
+    # hypothetical unproven family to pin the gate's behavior
     key = BucketKey(learner=("hypothetical", ()), n_pad=8, p_pad=8)
-    assert "hypothetical" not in MORPH_TOLERANCE_FAMILIES
-    assert not morph_allowed(key, 0.0)
-    assert not morph_allowed(key, 1e-6)    # not registered: never morphs
+    assert "hypothetical" not in MORPH_BITWISE_FAMILIES
+    assert not morph_allowed(key)
+    opaque = BucketKey(learner=("opaque", 123), n_pad=8, p_pad=8)
+    assert not morph_allowed(opaque)
     ridge = BucketKey(learner=("ridge", (("reg", 1.0),)), n_pad=8, p_pad=8)
-    assert morph_allowed(ridge, 0.0)       # bitwise tier needs no opt-in
+    assert morph_allowed(ridge)
 
 
 @pytest.mark.parametrize("name,params", FUSION_FAMILIES)

@@ -94,18 +94,6 @@ def test_ledger_partial_resume():
     assert rep2.bill.n_invocations == led2.n_invocations - 3
 
 
-def test_elastic_worker_schedule():
-    """The legacy static schedule is still honored: workers leave and join
-    between waves; the run completes bitwise-identically."""
-    pool = PoolConfig(n_workers=4, memory_mb=256,
-                      worker_schedule=[4, 1, 2, 8, 8, 8, 8, 8])
-    preds, ledger, rep = _run(pool)
-    assert ledger.complete
-    assert rep.waves >= 2
-    clean, _, _ = _run(PoolConfig(n_workers=4, memory_mb=256))
-    np.testing.assert_array_equal(preds, clean)
-
-
 def test_autoscaler_replaces_static_schedule():
     """Occupancy autoscaling: the wave backend derives worker counts from
     queue depth, records its decisions, and the estimate is untouched."""

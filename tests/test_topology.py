@@ -288,24 +288,6 @@ def test_poll_steps_host_streams_round_robin():
     assert all(h.waves > 0 for h in t.hosts)
 
 
-def test_worker_schedule_honored_per_host_stream():
-    """The legacy static ramp sizes each host stream's waves by that
-    host's own wave count (parity with the wave backend's contract), and
-    the estimate is untouched."""
-    backend = TopologyBackend(PoolConfig(n_hosts=2, memory_mb=256,
-                                         worker_schedule=[1, 2, 8, 8]))
-    plan, data = _plr(100, seed=31, n_rep=4)
-    req = compile_request(plan, data)
-    info = backend.run_requests([req])
-    assert req.ledger.complete
-    busy = [h for h in info.topology.hosts if h.waves > 0]
-    assert busy and busy[0].waves >= 2          # the ramp really waved
-    ref = compile_request(plan, data)
-    InlineBackend().run_requests([ref])
-    np.testing.assert_array_equal(req.gathered_preds(),
-                                  ref.gathered_preds())
-
-
 def test_topology_from_pod_mesh():
     """A multi-pod production-style mesh splits into one host stream per
     pod, each pinned to its own device set."""
