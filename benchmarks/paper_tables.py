@@ -124,7 +124,8 @@ def _pr1_per_segment_drain(reqs) -> None:
             flat = np.concatenate([req.invocation_tasks(i) for i in inv])
             y, w = req.wave_arrays(flat)
             fn = get_learner(seg.learner, dict(seg.params))
-            preds = fn(req.x, jnp.asarray(y), jnp.asarray(w), seg.key)
+            key = jax.random.key(seg.key_ref[1])  # ("seed", s)
+            preds = fn(req.x, jnp.asarray(y), jnp.asarray(w), key)
             jax.block_until_ready(preds)
 
 

@@ -316,7 +316,7 @@ class MegabatchPlan:
         pkey = (req_idx, key.n_pad, key.p_pad)
         page = self._pages.get(pkey)
         if page is None:
-            x = np.asarray(self.requests[req_idx].x, np.float32)
+            x = self.requests[req_idx].x
             page = np.zeros((key.n_pad, key.p_pad), np.float32)
             page[:x.shape[0], :x.shape[1]] = x
             self._pages[pkey] = page

@@ -265,7 +265,7 @@ class PagePool:
                 self.stats.bytes_d2d += nbytes
             else:
                 sp.set(source="h2d")
-                x = np.asarray(req.x, np.float32)
+                x = req.x                           # float32, on the host
                 host = np.zeros((1, n_pad, p_pad), np.float32)
                 host[0, :x.shape[0], :x.shape[1]] = x
                 page = self._put(host)              # the one h2d copy

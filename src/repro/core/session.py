@@ -124,7 +124,7 @@ def compile_request(plan: DMLPlan, data: DMLData,
     # segment carries the spec the megabatch compiler buckets on —
     # hyperparameters resolved against the *data shape* here (e.g.
     # kernel_ridge's gamma), so padded bucket execution stays
-    # padding-invariant — and the base PRNG key tasks fold_in from.
+    # padding-invariant — and the seed its tasks' PRNG keys fold_in from.
     groups: List[List[int]] = []
     seen: Dict = {}
     for l, ns in enumerate(plan.nuisances):
@@ -141,7 +141,6 @@ def compile_request(plan: DMLPlan, data: DMLData,
                                 n_obs=n, dim_x=data.dim_x)
         ptuple = tuple(sorted((k, _hashable(v)) for k, v in params.items()))
         segments.append(Segment(l_ids=tuple(g),
-                                key=jax.random.key(rs.seed + g[0]),
                                 key_ref=("seed", rs.seed + g[0]),
                                 cache_key=(ns.learner, ptuple),
                                 learner=ns.learner, params=ptuple))

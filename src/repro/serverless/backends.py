@@ -79,7 +79,6 @@ from typing import (
 )
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
@@ -119,14 +118,47 @@ def _fold_key_table(base_key, ids):
         lambda i: jax.random.key_data(jax.random.fold_in(base_key, i)))(ids)
 
 
-# Content-keyed cache of computed key tables: steady serving re-compiles
-# the same (plan, data) into fresh WorkRequests every drain, and the
-# fold_in table is a pure function of (segment key contents, n_tasks) —
-# without this cache every warm drain pays one device round-trip per
-# request segment just to rebuild identical tables (the dominant
-# host-side term once launches were fused).  Bounded FIFO: serving mixes
-# cycle a small set of segment keys.
-_KEY_TABLE_CACHE: Dict[Tuple[bytes, Tuple[int, ...], int], np.ndarray] = {}
+@jax.jit
+def _seed_key_table(seed, ids):
+    """``_fold_key_table`` of ``jax.random.key(seed)`` in one call."""
+    return _fold_key_table(jax.random.key(seed), ids)
+
+
+@functools.lru_cache(maxsize=None)
+def _host_device() -> jax.Device:
+    """Where key tables build: the host's CPU device, since a table is
+    read on the host; the default device where no CPU backend is up."""
+    try:
+        return jax.local_devices(backend="cpu")[0]
+    except RuntimeError:
+        return jax.local_devices()[0]
+
+
+def _build_key_table(base_key, seed: Optional[int],
+                     n_tasks: int) -> Tuple[np.ndarray, str]:
+    """fold_in(base key, t) key data for every task t, and the platform
+    that built it.  A seed-keyed table (``base_key`` None) builds on
+    ``_host_device`` from the seed in one jitted call; the seed goes in
+    as an int64, which the call converts as ``jax.random.key(seed)``
+    converts it, so the bits are those of the key itself.  A caller's
+    key builds where the key lives."""
+    ids = np.arange(n_tasks)
+    if base_key is not None:
+        dev = next(iter(base_key.devices()))
+        return np.asarray(_fold_key_table(base_key, ids)), dev.platform
+    dev = _host_device()
+    with jax.default_device(dev):
+        table = _seed_key_table(np.int64(seed), ids)
+    return np.asarray(table), dev.platform
+
+
+# Cache of computed key tables: steady serving re-compiles the same
+# (plan, data) into fresh WorkRequests every drain, and the fold_in
+# table is a pure function of (segment key, n_tasks).  Seed-keyed
+# segments are looked up by their ``key_ref`` ("seed", s); a caller's
+# key by its data.  Bounded FIFO: serving mixes cycle a small set of
+# segment keys.
+_KEY_TABLE_CACHE: Dict[Tuple, np.ndarray] = {}
 _KEY_TABLE_CACHE_MAX = 512
 # structural cache of WorkRequest index maps (see _index_maps)
 _INDEX_MAP_CACHE: Dict[Tuple, Tuple] = {}
@@ -136,6 +168,8 @@ _INDEX_MAP_CACHE: Dict[Tuple, Tuple] = {}
             key=("base_key", "n_tasks", "key_ref"))
 def _segment_key_table(base_key, n_tasks: int,
                        key_ref: Optional[Tuple] = None) -> np.ndarray:
+    """A segment's key table, from its key or, with ``base_key`` None,
+    from the seed its ``key_ref`` ("seed", s) names."""
     if key_ref is not None:
         ck = ("ref", key_ref, int(n_tasks))
     else:
@@ -143,7 +177,10 @@ def _segment_key_table(base_key, n_tasks: int,
         ck = (kd.tobytes(), kd.shape, int(n_tasks))
     table = _KEY_TABLE_CACHE.get(ck)
     if table is None:
-        table = np.asarray(_fold_key_table(base_key, np.arange(n_tasks)))
+        seed = None if base_key is not None else int(key_ref[1])
+        with obs.span("keys.table", tasks=int(n_tasks)) as sp:
+            table, platform = _build_key_table(base_key, seed, n_tasks)
+            sp.set(platform=platform)
         bounded_put(_KEY_TABLE_CACHE, ck, table, _KEY_TABLE_CACHE_MAX)
     return table
 
@@ -268,12 +305,12 @@ class Segment:
     spec identity — requests built from equal specs share warm compiled
     programs; when absent, buckets fall back to object identity.
 
-    ``key`` seeds the segment's PRNG: task t draws fold_in(key, t), fixed
-    at compile time so no schedule can perturb the estimate.
-    ``key_ref`` is an optional hashable identity of ``key`` (e.g. the
-    seed it was built from): when present, the fold_in key-table cache
-    is looked up without materializing the key's data — the warm path
-    then performs zero device round-trips per segment.
+    The segment's PRNG key seeds its tasks: task t draws fold_in(key, t),
+    fixed at compile time so no schedule can perturb the estimate.  A
+    plan segment holds no key: ``key`` is None and ``key_ref`` ("seed",
+    s) names ``jax.random.key(s)``, so its key table builds from the seed
+    on the host.  A caller's ``key`` (raw requests) has ``key_ref`` None
+    and keys the table cache by its data.
     """
     learner_fn: Optional[Callable] = None
     l_ids: Tuple[int, ...] = ()
@@ -303,7 +340,7 @@ class WorkRequest:
     """One estimation request, compiled to arrays + a durable ledger."""
     grid: TaskGrid
     scaling: str                        # invocation granularity (§4.2)
-    x: jnp.ndarray                      # (N, P)
+    x: np.ndarray                       # (N, P) float32, on the host
     targets: np.ndarray                 # (L, N)
     train_w: np.ndarray                 # (M, K, L, N)
     segments: List[Segment]
@@ -338,9 +375,12 @@ class WorkRequest:
                 f"{ledger.tasks_per_invocation}, {ledger.n_obs}) does not "
                 f"match grid/scaling/data ({n_inv}, {tpi}, {n_obs}) — was it "
                 "saved under a different plan?")
+        # X stays on the host, a copy taken at admission (a caller may
+        # reuse its buffer): its one upload is the launch's page
+        x = np.array(x, np.float32, order="C")
         if data_key is None:
             data_key = fingerprint_array(x)
-        return cls(grid=grid, scaling=scaling, x=jnp.asarray(x),
+        return cls(grid=grid, scaling=scaling, x=x,
                    targets=np.asarray(targets), train_w=np.asarray(train_w),
                    segments=segments, ledger=ledger,
                    report=report or RunReport(), tag=tag, data_key=data_key,

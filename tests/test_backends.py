@@ -1,13 +1,17 @@
 """ExecutionBackend parity: the same (DMLPlan, DMLData, seed) must yield
 identical (M,K,L,N) predictions and theta on every backend — including the
 wave backend under fault injection, retries, and speculation."""
+import jax
 import numpy as np
 import pytest
 
-from repro.core import DMLData, DMLPlan, estimate
+from repro import obs
+from repro.compile import program
+from repro.core import DMLData, DMLPlan, DMLSession, estimate
 from repro.core.session import assemble_result, compile_request
 from repro.data import make_irm_data, make_plr_data
 from repro.serverless import PoolConfig, make_backend
+from repro.serverless import backends
 from repro.serverless.backends import (
     BACKEND_NAMES, InlineBackend, ShardedBackend, WaveBackend,
 )
@@ -179,6 +183,67 @@ def test_key_consuming_learners_identical_across_backends():
     np.testing.assert_allclose(p_sh, p_in, rtol=1e-6, atol=1e-6)
     assert r_wv.theta == r_in.theta
     assert r_sh.theta == pytest.approx(r_in.theta, abs=1e-6)
+
+
+def _parent_key_table(seed, n_tasks):
+    """A seed's key table as the key itself gives it, on the default
+    device."""
+    key = jax.random.key(seed)
+    return np.asarray(jax.vmap(
+        lambda i: jax.random.key_data(jax.random.fold_in(key, i)))(
+            np.arange(n_tasks)))
+
+
+@pytest.mark.parametrize("n_tasks", [10, 1000])
+@pytest.mark.parametrize("seed", [0, 1, 12345, 2**30 - 1, 2**31 + 5])
+def test_seed_key_table_is_the_keys_own(seed, n_tasks, monkeypatch):
+    """A plan segment's table builds from its seed on the host's CPU
+    device, bitwise equal to folding the seed's own key, inside one
+    ``keys.table`` span."""
+    monkeypatch.setattr(backends, "_KEY_TABLE_CACHE", {})
+    obs.clear()
+    with obs.recording():
+        table = backends._segment_key_table(None, n_tasks,
+                                            key_ref=("seed", seed))
+        again = backends._segment_key_table(None, n_tasks,
+                                            key_ref=("seed", seed))
+    (sp,) = [s for s in obs.spans() if s.name == "keys.table"]
+    obs.clear()
+    assert sp.args == {"tasks": n_tasks, "platform": "cpu"}
+    assert type(table) is np.ndarray and again is table
+    np.testing.assert_array_equal(table, _parent_key_table(seed, n_tasks))
+
+
+def test_seed_key_tables_give_the_parents_estimate(monkeypatch):
+    """A key-consuming learner through a session: theta and SE with the
+    tables built from the seed are bitwise those with each table folded
+    from ``jax.random.key(seed)`` on the default device, and a table of
+    another seed moves them (the learner reads its keys)."""
+    data = DMLData.from_dict(make_plr_data(n_obs=150, dim_x=4, theta=0.5,
+                                           seed=12))
+    plan = DMLPlan.for_model("plr", learner="kernel_ridge",
+                             learner_params={"reg": 1.0, "n_landmarks": 16},
+                             n_folds=3, n_rep=2, seed=33)
+
+    def run(build=None):
+        monkeypatch.setattr(backends, "_KEY_TABLE_CACHE", {})
+        monkeypatch.setattr(program, "_BLOCK_TENSOR_CACHE", {})
+        if build is not None:
+            monkeypatch.setattr(
+                backends, "_build_key_table",
+                lambda key, seed, n: (build(seed, n), "default"))
+        sess = DMLSession(backend="wave", pool=POOL)
+        sess.submit(plan, data)
+        (res,) = sess.run()
+        return res
+
+    ours = run()
+    parents = run(_parent_key_table)
+    shifted = run(lambda seed, n: _parent_key_table(seed + 1, n))
+    assert (ours.theta, ours.se) == (parents.theta, parents.se)
+    np.testing.assert_array_equal(ours.thetas, parents.thetas)
+    np.testing.assert_array_equal(ours.ses, parents.ses)
+    assert not np.array_equal(ours.thetas, shifted.thetas)
 
 
 def test_make_backend_registry():

@@ -22,7 +22,7 @@ TREE = {
     "topology.wave": {"program.dispatch"},
     "program.dispatch": {"program.stage", "program.build",
                          "program.launch"},
-    "program.stage": {"pages.fetch"},
+    "program.stage": {"pages.fetch", "keys.table"},
     "dispatch.harvest": {"program.harvest", "ledger.book"},
     "program.harvest": {"program.wait"},
     "session.harvest": {"session.assemble"},

@@ -6,7 +6,7 @@ import pytest
 
 from repro import obs
 from repro.core import DMLData, DMLPlan, DMLSession, estimate
-from repro.core.session import assemble_result
+from repro.core.session import assemble_result, compile_request
 from repro.data import make_irm_data, make_plr_data
 from repro.serverless import PoolConfig
 
@@ -129,6 +129,24 @@ def test_session_empty_run_and_billing_split():
     for r in res:
         assert r.report.bill.n_invocations == 2 * 2
     assert sess.run() == []                       # queue drained
+
+
+def test_compile_request_makes_no_device_transfer():
+    """A plan request compiles on the host: no explicit transfer under
+    the guard, X a float32 numpy copy (its one upload is the launch's
+    page), and every segment keyed by its seed, holding no device key."""
+    data = DMLData.from_dict(make_irm_data(n_obs=120, dim_x=4, seed=2))
+    plan = DMLPlan.for_model("irm", learner="ridge", n_folds=3, n_rep=2,
+                             seed=17)
+    with jax.transfer_guard("disallow_explicit"):
+        req = compile_request(plan, data)
+    assert type(req.x) is np.ndarray and req.x.dtype == np.float32
+    assert np.array_equal(req.x, data.x)
+    assert not np.shares_memory(req.x, data.x)   # a snapshot at admission
+    assert len(req.segments) == 2
+    for seg in req.segments:
+        assert seg.key is None
+        assert seg.key_ref == ("seed", 17 + seg.l_ids[0])
 
 
 def test_assembly_touches_no_device_array_without_a_bootstrap():
